@@ -73,7 +73,8 @@ def _defined_flags() -> set:
     defined = set()
     for path in list((ROOT / "src" / "repro" / "launch").glob("*.py")) \
             + list((ROOT / "benchmarks").glob("*.py")) \
-            + list((ROOT / "experiments").glob("*.py")):
+            + list((ROOT / "experiments").glob("*.py")) \
+            + list(ROOT.glob("*.py")):
         defined.update(re.findall(r"add_argument\(\s*\"(--[a-z0-9-]+)\"",
                                   path.read_text()))
     return defined

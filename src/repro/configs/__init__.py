@@ -1,6 +1,6 @@
 """Architecture configs (``--arch <id>``). Importing this package registers
 all assigned architectures plus the paper's reference model."""
-from repro.configs.base import ModelConfig, LayerSpec, ScanGroup, REGISTRY, get_config, reduced, register
+from repro.configs.base import ModelConfig, LayerSpec, ScanGroup, REGISTRY, at_depth, get_config, reduced, register
 from repro.configs.shapes import SHAPES, ShapeConfig, get_shape, cells, LONG_CONTEXT_OK, LONG_CONTEXT_SKIP
 
 # register all architectures
@@ -30,7 +30,7 @@ ASSIGNED_ARCHS = (
 )
 
 __all__ = [
-    "ModelConfig", "LayerSpec", "ScanGroup", "REGISTRY", "get_config",
+    "ModelConfig", "LayerSpec", "ScanGroup", "REGISTRY", "at_depth", "get_config",
     "reduced", "register", "SHAPES", "ShapeConfig", "get_shape", "cells",
     "LONG_CONTEXT_OK", "LONG_CONTEXT_SKIP", "ASSIGNED_ARCHS",
 ]

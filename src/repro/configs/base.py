@@ -343,6 +343,26 @@ def get_config(name: str) -> ModelConfig:
     return REGISTRY[name]
 
 
+def at_depth(cfg: ModelConfig, num_layers: int) -> ModelConfig:
+    """The published config cut to its first ``num_layers`` layers, every
+    width unchanged — the depth cut for serving one chip. The cut must
+    keep whole periods of ``scan_groups()``: each group it leaves has a
+    unit the full stack has, so every layer kind keeps its pattern."""
+    if not 0 < num_layers <= cfg.num_layers:
+        raise ValueError(f"{cfg.name}: cannot keep {num_layers} of "
+                         f"{cfg.num_layers} layers")
+    cut = dataclasses.replace(
+        cfg, num_layers=num_layers,
+        global_layers=tuple(i for i in cfg.global_layers if i < num_layers))
+    units = {g.unit for g in cfg.scan_groups()}
+    split = [g.unit for g in cut.scan_groups() if g.unit not in units]
+    if split:
+        raise ValueError(f"{cfg.name}: {num_layers} layers split a period "
+                         f"of the layer pattern (units {split})")
+    cut.validate()
+    return cut
+
+
 def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
     """A tiny same-family config for CPU smoke tests."""
     small = dict(

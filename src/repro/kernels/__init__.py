@@ -10,6 +10,21 @@ Each kernel package has:
 - ``ops.py``    — jit'd public wrapper (layout plumbing, defaults)
 - ``ref.py``    — pure-jnp oracle used by the allclose test sweeps
 
-On this CPU container kernels are validated with ``interpret=True``; the
-model's dry-run path uses the pure-XLA implementations (DESIGN.md §4).
+Pallas interpret mode is decided in one place, :func:`resolve_interpret`:
+on when JAX runs on the CPU (the test suite), off everywhere else, so a
+kernel on the chip is always the compiled kernel. The model's dry-run
+path uses the pure-XLA implementations (DESIGN.md §4).
 """
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+
+
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """Pallas interpret mode for a kernel launch: an explicit value wins
+    (tests force it), otherwise on only when JAX's platform is ``cpu``."""
+    if interpret is not None:
+        return bool(interpret)
+    return jax.default_backend() == "cpu"

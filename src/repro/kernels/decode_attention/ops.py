@@ -22,7 +22,8 @@ from repro.kernels.paged_attention.ops import ragged_paged_attention
                                              "page_size", "interpret"))
 def decode_attention(q, k_cache, v_cache, cache_pos, cur_pos, *, scale: float,
                      cap: Optional[float] = None, window: Optional[int] = None,
-                     page_size: int = 512, interpret: bool = True):
+                     page_size: int = 512,
+                     interpret: Optional[bool] = None):
     """q: (B, 1, H, D); caches: (B, C, Hkv, D); cache_pos: (B, C);
     cur_pos: scalar or (B,). -> (B, 1, H, D)."""
     B, _, H, D = q.shape

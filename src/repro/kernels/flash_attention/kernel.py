@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -2.0e38
 
 
@@ -75,7 +77,7 @@ def flash_attention_bh(q, k, v, *, scale: float, cap: Optional[float] = None,
                        window: Optional[int] = None, causal: bool = True,
                        q_block: int = 512, kv_block: int = 512,
                        kv_len: Optional[int] = None,
-                       interpret: bool = True):
+                       interpret: Optional[bool] = None):
     """q: (BH, Sq, D); k/v: (BH, Skv, D) -> (BH, Sq, D).
 
     BH folds batch x kv-head x group; D should be a multiple of 128 on real
@@ -109,5 +111,5 @@ def flash_attention_bh(q, k, v, *, scale: float, cap: Optional[float] = None,
             pltpu.VMEM((q_block,), jnp.float32),    # l
             pltpu.VMEM((q_block, D), jnp.float32),  # acc
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

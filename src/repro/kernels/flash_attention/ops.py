@@ -15,7 +15,7 @@ from repro.kernels.flash_attention.kernel import flash_attention_bh
 def flash_attention(q, k, v, *, scale: float, cap: Optional[float] = None,
                     window: Optional[int] = None, causal: bool = True,
                     q_block: int = 512, kv_block: int = 512,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """q: (B, Sq, H, D); k/v: (B, Skv, Hkv, D) -> (B, Sq, H, D)."""
     B, Sq, H, D = q.shape
     Skv, Hkv = k.shape[1], k.shape[2]

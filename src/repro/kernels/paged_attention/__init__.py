@@ -1,6 +1,6 @@
 """Ragged paged attention: one kernel for prefill-extend and batched
 decode, reading KV page-by-page straight off the paged plane."""
-from .ops import ragged_paged_attention
+from .ops import KernelGeometryError, ragged_paged_attention
 from .ref import (
     interleave_kv,
     paged_attention_rows,
@@ -10,6 +10,7 @@ from .ref import (
 )
 
 __all__ = [
+    "KernelGeometryError",
     "ragged_paged_attention",
     "ragged_paged_attention_ref",
     "paged_attention_rows",

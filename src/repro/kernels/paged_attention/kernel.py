@@ -31,11 +31,12 @@ degenerates to the ungrouped PR 6 gather — every slot up to the
 sequence's page count is pulled and masked in-register — which is the
 baseline the kernel bench meters the skip win against.
 
-Queries and outputs live whole in VMEM; each (sequence, q-tile) step
-dynamically slices its ``block_q``-row window. Steps run in ascending
-sequence order, so the garbage rows a short sequence's tiles write past
-its true length are overwritten by the next sequence (the host wrapper
-pads by ``QB * block_q`` rows and slices them off).
+Queries and outputs stay in HBM: each (sequence, q-tile) copies its
+``block_q``-row window in at its first page block and out at its last,
+so VMEM holds one tile whatever the number of rows. Steps run in
+ascending sequence order, so the garbage rows a short sequence's tiles
+write past its true length are overwritten by the next sequence (the
+host wrapper pads by ``QB * block_q`` rows and slices them off).
 """
 from __future__ import annotations
 
@@ -85,7 +86,7 @@ def _attend_page(qf, kv, kpos, qpos, m, l, acc, *, scale, cap, window,
 
 
 def _kernel(cu_ref, kvlen_ref, tbl_ref, q_ref, kv_ref, o_ref,
-            kbuf, ksem, plist, m_s, l_s, acc_s,
+            kbuf, ksem, plist, m_s, l_s, acc_s, qbuf, obuf, qsem,
             *, ps, block_q, block_kv, nbuf, n_blocks, Hkv, G, D,
             scale, cap, window, skip_blocks,
             qpos_ref=None, kvpos_ref=None, pbuf=None, psem=None):
@@ -154,23 +155,32 @@ def _kernel(cu_ref, kvlen_ref, tbl_ref, q_ref, kv_ref, o_ref,
                 if has_pos:
                     pos_copy(b, b).start()
 
-        qblk = q_ref[pl.ds(q0 + q_lo, block_q)]          # (block_q, Hq, D)
+        rows = pl.ds(q0 + q_lo, block_q)
         if has_pos:
             qpos = qpos_ref[pl.ds(q0 + q_lo, block_q)].reshape(block_q, 1)
             qpos = jnp.broadcast_to(qpos, (block_q, G)).reshape(
                 block_q * G, 1)
         else:
-            qpos = (kv_len - qlen + q_lo
-                    + jax.lax.broadcasted_iota(jnp.int32, (block_q, G), 0))
-            qpos = qpos.reshape(block_q * G, 1)
+            # row r of a head's (block_q * G, D) query block is tile row
+            # r // G; built in its final shape because Mosaic cannot
+            # reshape an int32 (block_q, G) vector into (block_q * G, 1)
+            qpos = (kv_len - qlen + q_lo + jax.lax.div(
+                jax.lax.broadcasted_iota(jnp.int32, (block_q * G, 1), 0),
+                jnp.int32(G)))
 
         @pl.when(nb * block_kv <= lo)
         def _init():
             # first page block this tile sees (blocks below lo were
-            # skipped whole): reset the accumulators
+            # skipped whole): reset the accumulators and fetch the tile's
+            # query rows, which stay in VMEM for the tile's later blocks
             m_s[...] = jnp.full_like(m_s[...], NEG_INF)
             l_s[...] = jnp.zeros_like(l_s[...])
             acc_s[...] = jnp.zeros_like(acc_s[...])
+            cp = pltpu.make_async_copy(q_ref.at[rows], qbuf, qsem.at[0])
+            cp.start()
+            cp.wait()
+
+        qblk = qbuf[...]                                 # (block_q, Hq, D)
 
         def body(i, _):
             slot = jax.lax.rem(i, nbuf)
@@ -214,8 +224,13 @@ def _kernel(cu_ref, kvlen_ref, tbl_ref, q_ref, kv_ref, o_ref,
                 l = l_s[h]
                 o = acc_s[h] / jnp.where(l == 0.0, 1.0, l)
                 outs.append(o.reshape(block_q, G, D))
-            out = jnp.concatenate(outs, axis=1).astype(o_ref.dtype)
-            o_ref[pl.ds(q0 + q_lo, block_q)] = out
+            obuf[...] = jnp.concatenate(outs, axis=1).astype(obuf.dtype)
+            # waited here so that the next sequence's tile, which may
+            # overlap the rows this tile writes past its sequence's end,
+            # lands after it
+            cp = pltpu.make_async_copy(obuf, o_ref.at[rows], qsem.at[1])
+            cp.start()
+            cp.wait()
 
 
 @functools.partial(
@@ -264,14 +279,17 @@ def ragged_paged_attention_pallas(q_pad, kv_pages, page_table, cu_q_lens,
         pltpu.VMEM((Hkv, bq * G, 1), jnp.float32),       # m_s
         pltpu.VMEM((Hkv, bq * G, 1), jnp.float32),       # l_s
         pltpu.VMEM((Hkv, bq * G, D), jnp.float32),       # acc_s
+        pltpu.VMEM((bq, Hq, D), q_pad.dtype),            # qbuf
+        pltpu.VMEM((bq, Hq, D), q_pad.dtype),            # obuf
+        pltpu.SemaphoreType.DMA((2,)),                   # qsem
     ]
-    q_spec = pl.BlockSpec((Tpad, Hq, D), lambda s, qb, nb, *_: (0, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     if has_pos:
         in_specs = [
-            q_spec,
-            pl.BlockSpec(memory_space=pltpu.ANY),        # kv_pages
+            hbm,                                         # q
+            hbm,                                         # kv_pages
             pl.BlockSpec((Tpad,), lambda s, qb, nb, *_: (0,)),   # q_pos
-            pl.BlockSpec(memory_space=pltpu.ANY),        # kv_pos_pages
+            hbm,                                         # kv_pos_pages
         ]
         args = [q_pad, kv_pages,
                 jnp.asarray(q_pos_pad, jnp.int32),
@@ -281,7 +299,7 @@ def ragged_paged_attention_pallas(q_pad, kv_pages, page_table, cu_q_lens,
             pltpu.SemaphoreType.DMA((nbuf,)),            # psem
         ]
     else:
-        in_specs = [q_spec, pl.BlockSpec(memory_space=pltpu.ANY)]
+        in_specs = [hbm, hbm]
         args = [q_pad, kv_pages]
 
     kernel = functools.partial(
@@ -292,23 +310,18 @@ def ragged_paged_attention_pallas(q_pad, kv_pages, page_table, cu_q_lens,
     def wrapped(cu_ref, kvlen_ref, tbl_ref, q_ref, kv_ref, *rest):
         if has_pos:
             qpos_ref, kvpos_ref, o_ref = rest[0], rest[1], rest[2]
-            kbuf, ksem, plist, m_s, l_s, acc_s, pbuf, psem = rest[3:]
+            scratch_refs, (pbuf, psem) = rest[3:-2], rest[-2:]
             kernel(cu_ref, kvlen_ref, tbl_ref, q_ref, kv_ref, o_ref,
-                   kbuf, ksem, plist, m_s, l_s, acc_s,
-                   qpos_ref=qpos_ref, kvpos_ref=kvpos_ref,
+                   *scratch_refs, qpos_ref=qpos_ref, kvpos_ref=kvpos_ref,
                    pbuf=pbuf, psem=psem)
         else:
-            o_ref = rest[0]
-            kbuf, ksem, plist, m_s, l_s, acc_s = rest[1:]
-            kernel(cu_ref, kvlen_ref, tbl_ref, q_ref, kv_ref, o_ref,
-                   kbuf, ksem, plist, m_s, l_s, acc_s)
+            kernel(cu_ref, kvlen_ref, tbl_ref, q_ref, kv_ref, *rest)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(S, QB, NB),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((Tpad, Hq, D),
-                               lambda s, qb, nb, *_: (0, 0, 0)),
+        out_specs=hbm,
         scratch_shapes=scratch,
     )
     return pl.pallas_call(

@@ -5,42 +5,37 @@
 autotuner's cached best config for this page geometry), pads the flat
 query block to a whole number of q-tiles (so every tile's fixed-size
 block load stays in bounds), routes to the Pallas kernel or the jnp
-reference, and slices the padding back off. ``backend="auto"`` picks
-Pallas interpret mode off TPU so CI exercises the exact kernel lowering
-on CPU; set ``REPRO_KERNEL_INTERPRET=0/1`` to force either mode without
-touching call sites.
+reference, and slices the padding back off. Interpret mode follows
+``repro.kernels.resolve_interpret``: on the CPU the exact kernel lowering
+runs in the Pallas interpreter, on the chip it is compiled.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
+
+from repro.kernels import resolve_interpret
 
 from .kernel import ragged_paged_attention_pallas
 from .ref import ragged_paged_attention_ref
 from .tune import resolve_config
 
-_INTERPRET: Optional[bool] = None
+
+class KernelGeometryError(ValueError):
+    """The compiled kernel cannot take this page geometry: Mosaic needs
+    the page slab's head-dim axis to be a multiple of 128 lanes and its
+    fused K/V head axis (``2 * Hkv``) to be 2, 4 or a multiple of 8."""
 
 
-def _default_interpret() -> bool:
-    """Process-wide default for Pallas interpret mode, resolved once:
-    the ``REPRO_KERNEL_INTERPRET`` env var (0/1/true/false) wins,
-    otherwise interpret off TPU. Cached because ``jax.default_backend()``
-    walks the backend registry and this sits on the per-step decode
-    path."""
-    global _INTERPRET
-    if _INTERPRET is None:
-        env = os.environ.get("REPRO_KERNEL_INTERPRET", "").strip().lower()
-        if env in ("1", "true", "yes", "on"):
-            _INTERPRET = True
-        elif env in ("0", "false", "no", "off"):
-            _INTERPRET = False
-        else:
-            _INTERPRET = jax.default_backend() != "tpu"
-    return _INTERPRET
+def check_tpu_geometry(fused_heads: int, head_dim: int) -> None:
+    """Raise :class:`KernelGeometryError` for a page geometry the TPU
+    compiler refuses (found by compiling for a described v5e)."""
+    if head_dim % 128 or not (fused_heads in (2, 4) or fused_heads % 8 == 0):
+        raise KernelGeometryError(
+            f"ragged_paged_attention does not compile for pages of "
+            f"{fused_heads} fused K/V heads x head_dim {head_dim}: needs "
+            f"head_dim % 128 == 0 and 2*Hkv in (2, 4) or a multiple of 8")
 
 
 def ragged_paged_attention(q, kv_pages, page_table, cu_q_lens, kv_lens, *,
@@ -75,8 +70,9 @@ def ragged_paged_attention(q, kv_pages, page_table, cu_q_lens, kv_lens, *,
             kv_pos_pages=kv_pos_pages)
     if backend not in ("auto", "pallas"):
         raise ValueError(f"unknown backend: {backend!r}")
-    if interpret is None:
-        interpret = _default_interpret()
+    interpret = resolve_interpret(interpret)
+    if not interpret:
+        check_tpu_geometry(kv_pages.shape[2], kv_pages.shape[3])
     T = q.shape[0]
     ps = kv_pages.shape[1]
     max_q = T if max_q_len is None else int(max_q_len)

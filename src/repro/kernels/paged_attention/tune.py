@@ -28,8 +28,6 @@ import time
 from dataclasses import asdict, dataclass
 from typing import Dict, Optional, Tuple
 
-DEFAULT_CACHE_PATH = os.environ.get("REPRO_KERNEL_TUNE_CACHE", "")
-
 # candidate sweep: q rows per tile x table slots per block x DMA depth
 CANDIDATE_BLOCK_Q = (8, 16, 32)
 CANDIDATE_BLOCK_KV = (4, 8, 16)
@@ -47,15 +45,12 @@ _CACHE: Dict[Tuple[int, int, str], KernelConfig] = {}
 
 
 def _arch() -> str:
-    """Accelerator generation the tuned numbers belong to. Interpret-mode
-    timings (CPU) are still self-consistent but are cached under their
-    own key so they never masquerade as TPU results."""
+    """Device kind the tuned numbers belong to (``cpu`` for interpret-mode
+    timings, so they never masquerade as TPU results). A failure to list
+    devices propagates: there is no device to tune for."""
     import jax
 
-    try:
-        return jax.devices()[0].device_kind.replace(" ", "-").lower()
-    except Exception:
-        return "cpu"
+    return jax.devices()[0].device_kind.replace(" ", "-").lower()
 
 
 def best_config(page_size: int, head_dim: int,
@@ -164,11 +159,12 @@ def autotune(page_size: int, head_dim: int, *, repeats: int = 3,
     carries arch='cpu' so TPU runs retune)."""
     import jax
 
+    from repro.kernels import resolve_interpret
+
     from .kernel import ragged_paged_attention_pallas
-    from .ops import _default_interpret
 
     q, kv, tbl, cu, kvl, max_q = _bench_case(page_size, head_dim)
-    interpret = _default_interpret()
+    interpret = resolve_interpret()
     D = q.shape[-1]
     best: Tuple[float, KernelConfig] = (float("inf"), best_config(
         page_size, head_dim))
@@ -200,11 +196,6 @@ def autotune(page_size: int, head_dim: int, *, repeats: int = 3,
         if dt < best[0]:
             best = (dt, eff)
     set_config(page_size, D, best[1])
-    path = cache_path if cache_path is not None else DEFAULT_CACHE_PATH
-    if path:
-        save_table(path)
+    if cache_path:
+        save_table(cache_path)
     return best[1]
-
-
-if DEFAULT_CACHE_PATH:
-    load_table(DEFAULT_CACHE_PATH)

@@ -11,11 +11,14 @@ shape the paper's MRM targets).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 
 def _ssd_kernel(xdt_ref, da_ref, b_ref, c_ref, y_ref, state_ref, *,
@@ -56,7 +59,8 @@ def _ssd_kernel(xdt_ref, da_ref, b_ref, c_ref, y_ref, state_ref, *,
     y_ref[0] = (y_in + y_off).astype(y_ref.dtype)
 
 
-def ssd_scan_bh(xdt, da, b, c, *, chunk: int = 256, interpret: bool = True):
+def ssd_scan_bh(xdt, da, b, c, *, chunk: int = 256,
+                interpret: Optional[bool] = None):
     """xdt: (BH, S, P) (x pre-multiplied by dt); da: (BH, S) log-decays;
     b/c: (BH, S, N). Returns y (BH, S, P). S must be divisible by chunk."""
     BH, S, P = xdt.shape
@@ -78,5 +82,5 @@ def ssd_scan_bh(xdt, da, b, c, *, chunk: int = 256, interpret: bool = True):
         out_specs=pl.BlockSpec((1, chunk, P), lambda b_, ci: (b_, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, P), xdt.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xdt, da, b, c)

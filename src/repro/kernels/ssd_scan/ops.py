@@ -5,6 +5,7 @@ folds (batch, head) into the kernel's BH axis."""
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -13,7 +14,8 @@ from repro.kernels.ssd_scan.kernel import ssd_scan_bh
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(x, dt, a, b, c, *, chunk: int = 256, interpret: bool = True):
+def ssd_scan(x, dt, a, b, c, *, chunk: int = 256,
+             interpret: Optional[bool] = None):
     """x: (B, S, H, P); dt: (B, S, H) post-softplus; a: (H,) negative;
     b/c: (B, S, G, N), H % G == 0. Returns y (B, S, H, P)."""
     B, S, H, P = x.shape

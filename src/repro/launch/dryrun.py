@@ -6,10 +6,12 @@ against the production mesh with ShapeDtypeStruct inputs (no allocation),
 then extract memory analysis, cost analysis, and trip-count-aware roofline
 terms (launch/hlo_analysis.py).
 
-The XLA_FLAGS line above MUST precede any jax import (jax locks the device
-count at first init). Do not import this module from code that needs real
-single-device semantics — the orchestrator (--all) runs each cell in its
-own subprocess for exactly this reason.
+A CPU-only tool. The XLA_FLAGS line above MUST precede any jax import
+(jax locks the device count at first init), and the orchestrator (--all)
+runs each cell in its own subprocess. Nothing on the chip path
+(``launch/serve.py``, ``chip_smoke.py``) may import this module: it would
+replace the chip's devices with 512 host ones, and its children could
+not reach a chip the parent holds.
 
 Usage:
   python -m repro.launch.dryrun --arch gemma-2b --shape train_4k --mesh single

@@ -1,9 +1,13 @@
-"""Serving driver: a reduced model computes real tokens while the MRM
-control plane meters the deployment-size memory system. With --replicas N
-a :class:`ClusterFrontend` fans requests across N engine replicas
-(fleet prefix-directory routing, shared simulated clock, aggregated fleet
-report). --shared-prefix-tokens K makes the generated traffic share a
-K-token prompt head, exercising radix prefix reuse end to end;
+"""Serving entry point: a model computes real tokens while the MRM control
+plane meters the deployment-size memory system. By default the model is
+the ``reduced()`` CPU-size sibling of --arch; --layers N serves the
+published widths instead, cut to the first N layers (``at_depth``), as on
+the chip. With --replicas N a :class:`ClusterFrontend` fans requests
+across N engine replicas (fleet prefix-directory routing, shared
+simulated clock, aggregated fleet report); replica i holds its own copy
+of the weights and its page pool on ``jax.devices()[i % devices]``.
+--shared-prefix-tokens K makes the generated traffic share a K-token
+prompt head, exercising radix prefix reuse end to end;
 --migrate-prefixes additionally lets the directory *move* a hot prefix
 (pages + compute snapshot) to a less-loaded replica at --interconnect-gbps
 instead of queueing every match on its owner.
@@ -18,14 +22,55 @@ DESIGN.md §8). Try --arch mamba2-2.7b or --arch hymba-1.5b with
       --requests 8 --max-new 16 --kv-tier mrm_rram --weight-tier mrm_rram \
       --replicas 2 --chunk-tokens 32 --kv-policy evict-lru \
       --shared-prefix-tokens 32 --radix-hot-tier auto
+
+Runs in this one process and starts no other: a chip belongs to one
+process at a time.
 """
 from __future__ import annotations
 
 import argparse
 import json
+import os
+from pathlib import Path
 
 import jax
 import numpy as np
+
+REPO_ROOT = Path(__file__).resolve().parents[3]
+
+
+def compile_cache_dir() -> str:
+    """``JAX_COMPILATION_CACHE_DIR`` when set, else the fixed
+    ``<repo>/.jax_cache`` — a fixed path, because the path is part of the
+    cache key."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(
+        REPO_ROOT / ".jax_cache")
+
+
+def configure_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache at
+    :func:`compile_cache_dir` and return that directory."""
+    path = compile_cache_dir()
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def model_config(args):
+    """(compute config, published config) for --arch: ``reduced()`` by
+    default, the published widths cut to --layers layers when given."""
+    from repro.configs import at_depth, get_config, reduced
+
+    full = get_config(args.arch)
+    cfg = at_depth(full, args.layers) if args.layers else reduced(full)
+    return cfg, full
+
+
+def replica_params(params, n: int) -> list:
+    """Replica i's weights, committed to ``jax.devices()[i % devices]``;
+    replicas that share a device share one copy."""
+    devices = jax.devices()
+    copies = [jax.device_put(params, d) for d in devices[:n]]
+    return [copies[i % len(copies)] for i in range(n)]
 
 
 def build_engine(args, cfg, full, params):
@@ -66,9 +111,26 @@ def build_engine(args, cfg, full, params):
         account_cfg=full)
 
 
-def main(argv=None):
+def build_frontend(args, engines):
+    from repro.serving import ClusterFrontend
+
+    return ClusterFrontend(engines,
+                           migrate_prefixes=args.migrate_prefixes,
+                           interconnect_gbps=args.interconnect_gbps,
+                           clock_mode=args.clock,
+                           fabric_bisection_gbps=args.fabric_gbps,
+                           replicate_threshold=args.replicate_threshold,
+                           replicate_copies=args.replicate_copies,
+                           directory_shards=args.directory_shards)
+
+
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-8b")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="serve the published widths cut to the first N "
+                         "layers (whole periods of the layer pattern); "
+                         "default: the reduced() CPU-size model")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--weight-tier", default="mrm_rram")
@@ -181,18 +243,19 @@ def main(argv=None):
                     help="hash shards the fleet prefix directory spreads "
                          "its digest keys across (load-balance counters "
                          "land in the report)")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
-    from repro.configs import get_config, reduced
+
+def main(argv=None):
+    args = parse_args(argv)
+    configure_compile_cache()
+
     from repro.models import init_params
-    from repro.serving import ClusterFrontend
 
-    full = get_config(args.arch)
-    cfg = reduced(full)
+    cfg, full = model_config(args)
     params = init_params(cfg, jax.random.key(args.seed))
-
-    engines = [build_engine(args, cfg, full, params)
-               for _ in range(max(args.replicas, 1))]
+    engines = [build_engine(args, cfg, full, p)
+               for p in replica_params(params, max(args.replicas, 1))]
     rng = np.random.default_rng(args.seed)
 
     if cfg.n_codebooks > 1:
@@ -217,14 +280,7 @@ def main(argv=None):
             eng.submit(gen_prompt(), max_new_tokens=args.max_new)
         rep = eng.run_until_idle()
     else:
-        fe = ClusterFrontend(engines,
-                             migrate_prefixes=args.migrate_prefixes,
-                             interconnect_gbps=args.interconnect_gbps,
-                             clock_mode=args.clock,
-                             fabric_bisection_gbps=args.fabric_gbps,
-                             replicate_threshold=args.replicate_threshold,
-                             replicate_copies=args.replicate_copies,
-                             directory_shards=args.directory_shards)
+        fe = build_frontend(args, engines)
         for i in range(args.requests):
             fe.submit(gen_prompt(), max_new_tokens=args.max_new,
                       session_key=f"session-{i % max(args.sessions, 1)}")

@@ -12,6 +12,7 @@ logical axes + initializer). The same definition tree serves three uses:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from dataclasses import dataclass
 from typing import Optional, Tuple
@@ -55,27 +56,33 @@ def _fan_in_std(d: ParamDef) -> float:
     return 1.0 / math.sqrt(max(fan_in, 1))
 
 
+@functools.partial(jax.jit, static_argnames=("d", "dt"))
+def _init_leaf(k, *, d: ParamDef, dt: str):
+    """One leaf, drawn in fp32 and cast in a single compiled program, so
+    the fp32 draw fuses into the cast and never exists whole on the
+    device (a stacked published-width MLP leaf would need GBs)."""
+    dt = jnp.dtype(dt)
+    if d.init == "zeros":
+        return jnp.zeros(d.shape, dt)
+    if d.init == "ones":
+        return jnp.ones(d.shape, dt)
+    if d.init == "a_log":  # mamba2: A in [1, 16), stored as log
+        u = jax.random.uniform(k, d.shape, jnp.float32, 1.0, 16.0)
+        return jnp.log(u).astype(dt)
+    if d.init == "dt_bias":  # softplus^-1 of dt ~ U[1e-3, 1e-1]
+        u = jax.random.uniform(k, d.shape, jnp.float32, 1e-3, 1e-1)
+        return jnp.log(jnp.expm1(u)).astype(dt)
+    std = _fan_in_std(d)
+    return (jax.random.normal(k, d.shape, jnp.float32) * std).astype(dt)
+
+
 def materialize(defs, key: jax.Array, default_dtype: str = "bfloat16"):
     """Initialize real parameter arrays from a def tree."""
     leaves, treedef = jax.tree.flatten(defs, is_leaf=is_def)
     keys = jax.random.split(key, max(len(leaves), 1))
-
-    def one(d: ParamDef, k):
-        dt = jnp.dtype(d.dtype or default_dtype)
-        if d.init == "zeros":
-            return jnp.zeros(d.shape, dt)
-        if d.init == "ones":
-            return jnp.ones(d.shape, dt)
-        if d.init == "a_log":  # mamba2: A in [1, 16), stored as log
-            u = jax.random.uniform(k, d.shape, jnp.float32, 1.0, 16.0)
-            return jnp.log(u).astype(dt)
-        if d.init == "dt_bias":  # softplus^-1 of dt ~ U[1e-3, 1e-1]
-            u = jax.random.uniform(k, d.shape, jnp.float32, 1e-3, 1e-1)
-            return jnp.log(jnp.expm1(u)).astype(dt)
-        std = _fan_in_std(d)
-        return (jax.random.normal(k, d.shape, jnp.float32) * std).astype(dt)
-
-    return jax.tree.unflatten(treedef, [one(d, k) for d, k in zip(leaves, keys)])
+    return jax.tree.unflatten(treedef, [
+        _init_leaf(k, d=d, dt=d.dtype or default_dtype)
+        for d, k in zip(leaves, keys)])
 
 
 def abstract(defs, default_dtype: str = "bfloat16", shardings=None):

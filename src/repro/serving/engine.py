@@ -286,12 +286,15 @@ class ComputeBackend:
             # paged compute plane (DESIGN.md §10): one pooled page array per
             # cache family, owned here, indexed by PagedKVManager pages via
             # Page.compute_page. Page 0 is the reserved null page (gathered
-            # for padded table slots; auto-masked by slot-derived positions)
+            # for padded table slots; auto-masked by slot-derived positions).
+            # The pool lives on the weights' device, so replicas placed on
+            # different devices compute there
             self.caches = None
             self.page_tokens = ecfg.page_tokens
             n0 = max(16, 1 + B * -(-ecfg.max_cache_len // ecfg.page_tokens))
-            self.paged_caches = tfm.init_paged_caches(
-                cfg, n0, ecfg.page_tokens, jnp.dtype(cfg.dtype))
+            device = next(iter(jax.tree.leaves(params)[0].devices()))
+            self.paged_caches = jax.device_put(tfm.init_paged_caches(
+                cfg, n0, ecfg.page_tokens, jnp.dtype(cfg.dtype)), device)
             self._free = list(range(n0 - 1, 0, -1))  # pop() -> lowest id
             self._paged_first_jit: Dict[tuple, callable] = {}
             self._paged_extend_jit: Dict[tuple, callable] = {}
@@ -453,6 +456,9 @@ class ComputeBackend:
                                          if self.cfg.frontend == "vision" else 0)
 
     def sample(self, logits):
+        # the head is padded past the vocabulary (vocab_pad_to); padding
+        # ids are not tokens and must never be sampled
+        logits = logits[..., :self.cfg.vocab_size]
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
     # -- StepPlan execution --------------------------------------------

@@ -1,7 +1,7 @@
 """Config registry + analytic parameter-count sanity for all 10 archs."""
 import pytest
 
-from repro.configs import ASSIGNED_ARCHS, get_config, reduced
+from repro.configs import ASSIGNED_ARCHS, at_depth, get_config, reduced
 from repro.configs.shapes import LONG_CONTEXT_OK, LONG_CONTEXT_SKIP, SHAPES, cells
 
 # published parameter counts (approx, in billions) for sanity bands
@@ -94,3 +94,30 @@ def test_kv_bytes_per_token_matches_paper_scale():
     per_layer = v2.kv_bytes_per_token() / v2.num_layers
     gqa_equiv = 2 * 16 * 128 * 2  # kv=16 heads of 128 at bf16
     assert per_layer < gqa_equiv / 5
+
+
+@pytest.mark.parametrize("arch,layers", [("qwen3-8b", 16), ("gemma2-27b", 4),
+                                         ("hymba-1.5b", 4),
+                                         ("deepseek-v2-lite-16b", 5)])
+def test_at_depth_keeps_widths_and_whole_periods(arch, layers):
+    """The chip's depth cut changes the layer count only: every width is
+    the published one, the kept layers are the published stack's first
+    ones, and each scan group is a unit of the full stack."""
+    full = get_config(arch)
+    cut = at_depth(full, layers)
+    assert cut.num_layers == layers
+    assert cut.layer_specs() == full.layer_specs()[:layers]
+    for k in ("d_model", "n_heads", "n_kv_heads", "head_dim", "d_ff",
+              "vocab_size", "n_experts", "expert_d_ff", "kv_lora_rank",
+              "ssm_state", "window_pattern"):
+        assert getattr(cut, k) == getattr(full, k), k
+    units = {g.unit for g in full.scan_groups()}
+    assert all(g.unit in units for g in cut.scan_groups())
+
+
+@pytest.mark.parametrize("arch,layers", [("gemma2-27b", 3),
+                                         ("deepseek-v2-lite-16b", 1),
+                                         ("qwen3-8b", 0), ("qwen3-8b", 37)])
+def test_at_depth_refuses_cuts_that_split_the_pattern(arch, layers):
+    with pytest.raises(ValueError):
+        at_depth(get_config(arch), layers)

@@ -798,3 +798,47 @@ def test_engine_vlm_frontend_stub():
     assert rep["finished"] == 2
     # KV accounting includes the frontend prefix tokens
     assert eng.kv.prefix_tokens_reused == 0
+
+
+def test_sampler_never_emits_vocab_padding():
+    """The LM head is padded past the vocabulary (vocab_pad_to); a padding
+    id is not a token, so greedy sampling ignores those columns even when
+    random weights make one the largest logit."""
+    from repro.serving.engine import ComputeBackend
+    cfg = reduced(get_config("qwen3-8b"), vocab_size=500, vocab_pad_to=64)
+    assert cfg.padded_vocab == 512
+    backend = ComputeBackend(cfg, None, EngineConfig(max_slots=2))
+    logits = np.zeros((2, cfg.padded_vocab), np.float32)
+    logits[:, 3] = 1.0
+    logits[:, 510] = 9.0                        # a padding column wins
+    assert np.asarray(backend.sample(logits)).tolist() == [3, 3]
+
+
+def test_compile_cache_dir_prefers_the_environment(monkeypatch):
+    """The persistent compile cache lives where JAX_COMPILATION_CACHE_DIR
+    says, and only without it at the fixed <repo>/.jax_cache."""
+    from repro.launch.serve import REPO_ROOT, compile_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/cache/from/env")
+    assert compile_cache_dir() == "/cache/from/env"
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert compile_cache_dir() == str(REPO_ROOT / ".jax_cache")
+    assert (REPO_ROOT / "src" / "repro" / "launch" / "serve.py").is_file()
+
+
+def test_replicas_sharing_a_device_share_one_weight_copy(small_engine_setup):
+    """serve.replica_params commits replica i's weights to device
+    i % devices; replicas that land on one device share one copy, and the
+    engine keeps its page pool where its weights are."""
+    from repro.launch.serve import replica_params
+    full, cfg, params = small_engine_setup
+    n = len(jax.devices()) + 1
+    placed = replica_params(params, n)
+    assert len(placed) == n and placed[-1] is placed[0]
+    for i, p in enumerate(placed):
+        dev = jax.devices()[i % len(jax.devices())]
+        assert {d for x in jax.tree.leaves(p) for d in x.devices()} == {dev}
+    eng = ServeEngine(cfg, placed[0], _mem(),
+                      EngineConfig(max_slots=2, paged_kernel=True),
+                      account_cfg=full)
+    assert {d for x in jax.tree.leaves(eng.backend.paged_caches)
+            for d in x.devices()} == {jax.devices()[0]}
