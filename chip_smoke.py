@@ -152,22 +152,6 @@ def kernel_phase(cfg, page_tokens: int, seed: int) -> None:
 # ---------------------------------------------------------------------------
 
 
-class CompileClock:
-    """Sums XLA backend compile seconds reported by JAX's monitoring."""
-
-    def __init__(self):
-        import jax
-
-        self.seconds = 0.0
-        self.count = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event: str, duration: float, **_) -> None:
-        if event.endswith("backend_compile_duration"):
-            self.seconds += duration
-            self.count += 1
-
-
 def serve_args(extra=()):
     from repro.launch import serve
 
@@ -182,13 +166,16 @@ def prompts(rng, vocab: int, n: int, head: list, tail: int = 32) -> list:
             for _ in range(n)]
 
 
-def serve_phase(cfg, full, params, args, clock: CompileClock) -> None:
+def serve_phase(cfg, full, params, args) -> None:
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from repro.launch import serve
     from repro.models import transformer as tfm
+    from repro.serving.telemetry import recorder
+
+    clock = recorder()      # XLA compiles (or cache loads) in the process
 
     eng = serve.build_engine(args, cfg, full, params)
     check(eng.paged, "engine is not on the paged compute plane")
@@ -215,14 +202,14 @@ def serve_phase(cfg, full, params, args, clock: CompileClock) -> None:
 
     def drain():
         while not eng.sched.idle:
-            n0 = clock.count
+            n0 = clock.jax_compiles
             t0 = time.perf_counter()
             r = eng.step()
             steps.append((time.perf_counter() - t0, r["prefill_tokens"],
-                          clock.count > n0))
+                          clock.jax_compiles > n0))
 
     t_serve = time.perf_counter()
-    c0 = clock.seconds
+    c0 = clock.jax_compile_s
     rids = [eng.submit(probe, max_new_tokens=args.max_new)]
     drain()                     # the probe registers the shared head
     rids += [eng.submit(p, max_new_tokens=args.max_new) for p in rest]
@@ -270,7 +257,7 @@ def serve_phase(cfg, full, params, args, clock: CompileClock) -> None:
                  f"dropped_allocs={rep['dropped_allocs']}")
     say("serve", f"smoke readings (not metrics): {len(steps)} engine steps "
                  f"in {serve_s:.2f}s wall, XLA compile "
-                 f"{clock.seconds - c0:.2f}s over the run; steady decode "
+                 f"{clock.jax_compile_s - c0:.2f}s over the run; steady decode "
                  f"step median "
                  f"{statistics.median(decode) * 1e3 if decode else float('nan'):.2f} ms "
                  f"over {len(decode)} steps; weights {weight_bytes} B; "
@@ -359,7 +346,6 @@ def main(argv=None) -> int:
 
         cache_dir = serve.configure_compile_cache()
         devices = device_phase(opts.chips, cache_dir)
-        clock = CompileClock()
 
         import jax
 
@@ -380,7 +366,7 @@ def main(argv=None) -> int:
             fleet_phase(cfg, full, params, args, devices)
         else:
             kernel_phase(cfg, args.page_tokens, opts.seed)
-            serve_phase(cfg, full, params, args, clock)
+            serve_phase(cfg, full, params, args)
     except SmokeFailure as e:
         print(f"chip_smoke: FAIL: {e}", file=sys.stderr)
         return 1

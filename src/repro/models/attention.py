@@ -449,8 +449,10 @@ def attention_sublayer(
             pos2d = jnp.broadcast_to(
                 jnp.asarray(positions, jnp.int32).reshape(1, S), (B, S))
             act = None
-        kvp = write_tokens_to_pages(cache["kv_pages"], interleave_kv(k, v),
-                                    pos2d, page_table, active=act)
+        with jax.named_scope("kv_write"):
+            kvp = write_tokens_to_pages(cache["kv_pages"],
+                                        interleave_kv(k, v), pos2d,
+                                        page_table, active=act)
         Hq, hd = q.shape[2], q.shape[3]
         out = paged_attention_rows(
             q.reshape(B * S, Hq, hd), kvp,

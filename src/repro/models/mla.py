@@ -168,8 +168,9 @@ def mla_sublayer(
             pos2d = jnp.broadcast_to(
                 jnp.asarray(positions, jnp.int32).reshape(1, S), (B, S))
             act = None
-        kvp = write_tokens_to_pages(cache["kv_pages"], kv_new, pos2d,
-                                    page_table, active=act)
+        with jax.named_scope("kv_write"):
+            kvp = write_tokens_to_pages(cache["kv_pages"], kv_new, pos2d,
+                                        page_table, active=act)
         qc = jnp.einsum("bshk,rhk->bshr", qn, p["w_uk"])
         q_f = jnp.concatenate([qc, qr], axis=-1)              # (B, S, H, r+dr)
         H = q_f.shape[2]

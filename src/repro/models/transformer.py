@@ -99,7 +99,46 @@ def apply_block(cfg: ModelConfig, spec: LayerSpec, p: dict, x, *, positions,
     SSM, both for the hybrid union (``page_tokens`` is the static page
     size the point stacks need to resolve state-page slots)."""
     aux = jnp.zeros((), jnp.float32)
-    h = rmsnorm(x, p["norm1"], cfg.norm_eps)
+    with jax.named_scope("norm"):
+        h = rmsnorm(x, p["norm1"], cfg.norm_eps)
+    with jax.named_scope(_MIXER_SCOPE.get(spec.kind, "mixer")):
+        h, new_cache = _mixer(cfg, spec, p, h, positions=positions, sh=sh,
+                              cache=cache, mode=mode, cur_pos=cur_pos,
+                              decode_active=decode_active,
+                              page_table=page_table, page_tokens=page_tokens)
+    if cfg.post_norms:
+        with jax.named_scope("norm"):
+            h = rmsnorm(h, p["post_norm1"], cfg.norm_eps)
+    x = x + h
+    if sh is not None:
+        x = sh.c(x, ("act_batch", "act_seq_res", "act_embed"))
+
+    if spec.mlp != "none":
+        with jax.named_scope("norm"):
+            h = rmsnorm(x, p["norm2"], cfg.norm_eps)
+        with jax.named_scope("mlp"):
+            if spec.mlp == "moe":
+                h, aux = moe_sublayer(cfg, p["mlp"], h, sh=sh)
+            else:
+                h = mlp(cfg, p["mlp"], h,
+                        constrain=(sh.c if sh is not None else None))
+        if cfg.post_norms:
+            with jax.named_scope("norm"):
+                h = rmsnorm(h, p["post_norm2"], cfg.norm_eps)
+        x = x + h
+        if sh is not None:
+            x = sh.c(x, ("act_batch", "act_seq_res", "act_embed"))
+    return x, new_cache, aux
+
+
+# op-metadata scope of each mixer family (jax.named_scope)
+_MIXER_SCOPE = {"attn": "attention", "mla": "attention", "ssm": "ssm",
+                "hybrid": "hybrid"}
+
+
+def _mixer(cfg: ModelConfig, spec: LayerSpec, p: dict, h, *, positions,
+           sh, cache, mode, cur_pos, decode_active, page_table, page_tokens):
+    """The block's token mixer. Returns (h, new_cache)."""
     if spec.kind == "attn":
         h, new_cache = attention_sublayer(cfg, p["mixer"], h, positions=positions,
                                           window=spec.window, sh=sh, cache=cache,
@@ -126,24 +165,7 @@ def apply_block(cfg: ModelConfig, spec: LayerSpec, p: dict, x, *, positions,
                                        page_tokens=page_tokens)
     else:
         raise ValueError(spec.kind)
-    if cfg.post_norms:
-        h = rmsnorm(h, p["post_norm1"], cfg.norm_eps)
-    x = x + h
-    if sh is not None:
-        x = sh.c(x, ("act_batch", "act_seq_res", "act_embed"))
-
-    if spec.mlp != "none":
-        h = rmsnorm(x, p["norm2"], cfg.norm_eps)
-        if spec.mlp == "moe":
-            h, aux = moe_sublayer(cfg, p["mlp"], h, sh=sh)
-        else:
-            h = mlp(cfg, p["mlp"], h, constrain=(sh.c if sh is not None else None))
-        if cfg.post_norms:
-            h = rmsnorm(h, p["post_norm2"], cfg.norm_eps)
-        x = x + h
-        if sh is not None:
-            x = sh.c(x, ("act_batch", "act_seq_res", "act_embed"))
-    return x, new_cache, aux
+    return h, new_cache
 
 
 # ---------------------------------------------------------------------------
@@ -448,16 +470,15 @@ def paged_prefill(cfg: ModelConfig, params, batch: dict, caches, page_table,
     there is no per-slot cache to build — the pool is the cache — so this
     is just ``extend`` from offset 0 with the prefix embedded.
     Returns (last_logits, caches)."""
-    x, _ = _embed_inputs(cfg, params, batch, sh)
+    with jax.named_scope("embed"):
+        x, _ = _embed_inputs(cfg, params, batch, sh)
     S_tot = x.shape[1]
     positions = jnp.arange(S_tot, dtype=jnp.int32)
     x, new_caches, _ = apply_groups(cfg, params, x, positions=positions,
                                     sh=sh, caches=caches, mode="extend",
                                     page_table=page_table,
                                     page_tokens=page_tokens)
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    logits = lm_logits(cfg, params["embed"], x[:, -1])
-    return logits, new_caches
+    return _paged_head(cfg, params, x, -1), new_caches
 
 
 def paged_extend(cfg: ModelConfig, params, caches, tokens, offset, page_table,
@@ -468,7 +489,8 @@ def paged_extend(cfg: ModelConfig, params, caches, tokens, offset, page_table,
     migrated prefix hit at zero copy cost. Point stacks read their state
     page for the slot preceding ``offset`` (the engine chunks them so a
     chunk never crosses a page boundary)."""
-    x = embed(cfg, params["embed"], tokens)
+    with jax.named_scope("embed"):
+        x = embed(cfg, params["embed"], tokens)
     if sh is not None:
         x = sh.c(x, ("act_batch", "act_seq_res", "act_embed"))
     S = x.shape[1]
@@ -477,9 +499,7 @@ def paged_extend(cfg: ModelConfig, params, caches, tokens, offset, page_table,
                                     sh=sh, caches=caches, mode="extend",
                                     page_table=page_table,
                                     page_tokens=page_tokens)
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    logits = lm_logits(cfg, params["embed"], x[:, -1])
-    return logits, new_caches
+    return _paged_head(cfg, params, x, -1), new_caches
 
 
 def paged_decode(cfg: ModelConfig, params, caches, last_tokens, cur_pos,
@@ -487,7 +507,8 @@ def paged_decode(cfg: ModelConfig, params, caches, last_tokens, cur_pos,
     """One batched decode step on the paged plane. cur_pos: (B,) absolute
     positions; rows where ``active`` is False neither write their pages
     nor advance (their page-table row may be all null pages)."""
-    x = embed(cfg, params["embed"], last_tokens)
+    with jax.named_scope("embed"):
+        x = embed(cfg, params["embed"], last_tokens)
     if sh is not None:
         x = sh.c(x, ("act_batch", None, "act_embed"))
     cp = jnp.asarray(cur_pos, jnp.int32)
@@ -497,6 +518,13 @@ def paged_decode(cfg: ModelConfig, params, caches, last_tokens, cur_pos,
                                     cur_pos=cp, decode_active=active,
                                     page_table=page_table,
                                     page_tokens=page_tokens)
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    logits = lm_logits(cfg, params["embed"], x[:, 0])
-    return logits, new_caches
+    return _paged_head(cfg, params, x, 0), new_caches
+
+
+def _paged_head(cfg: ModelConfig, params, x, at: int):
+    """Final norm over ``x`` (B, S, d), then the LM head at position
+    ``at``, the one each row samples from. Returns logits (B, V[, K])."""
+    with jax.named_scope("norm"):
+        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    with jax.named_scope("lm_head"):
+        return lm_logits(cfg, params["embed"], x[:, at])

@@ -650,7 +650,7 @@ class ClusterFrontend:
         if entry is None:
             return  # arrival never fired (cancelled before routing)
         replica, local = entry
-        self.engines[replica].sched.abandon(local, ev.time)
+        self.engines[replica].abandon(local, ev.time)
 
     def _ev_push(self, ev: Event) -> None:
         """Execute (or re-defer) one speculative replication push. The

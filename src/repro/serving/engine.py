@@ -53,6 +53,8 @@ of merit.
 """
 from __future__ import annotations
 
+import contextlib
+import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
@@ -66,6 +68,9 @@ from repro.models import transformer as tfm
 from repro.serving.kv_cache import PagedKVManager
 from repro.serving.radix import PrefixMatch
 from repro.serving.scheduler import ContinuousBatchScheduler, Request
+from repro.serving.telemetry import mark, recorder, span
+
+_NO_BUILD = contextlib.nullcontext()
 
 
 @dataclass
@@ -282,6 +287,12 @@ class ComputeBackend:
         self._prefill_jit: Dict[int, callable] = {}
         self._extend_jit: Dict[int, callable] = {}
         self.seed_copy_bytes = 0.0  # ring-path donor seeding copy traffic
+        # paged step programs built (traced + compiled or loaded) per kind,
+        # and the (kind, length, width) keys built on the current pool
+        self.builds = {k: {"count": 0, "seconds": 0.0}
+                       for k in ("first", "extend", "decode")}
+        self._built: set = set()
+        self.pool_grows = 0
         if paged:
             # paged compute plane (DESIGN.md §10): one pooled page array per
             # cache family, owned here, indexed by PagedKVManager pages via
@@ -334,7 +345,10 @@ class ComputeBackend:
 
     def _grow_pool(self) -> None:
         """Double the page pool — zeros appended on the page axis of every
-        cache-family leaf. jit'd steps retrace on the new pool shape."""
+        cache-family leaf. jit'd steps retrace on the new pool shape, so
+        every key builds again."""
+        self.pool_grows += 1
+        self._built.clear()
         grown = []
 
         def widen(a):
@@ -362,8 +376,34 @@ class ComputeBackend:
         """Copy rows [0, n) of compute page `src` into `dst` across every
         cache family — the sub-page tail seeding primitive (DESIGN.md §9):
         the only bytes a prefix hit ever copies on the paged plane."""
-        self.paged_caches = jax.tree.map(
-            lambda a: a.at[:, dst, :n].set(a[:, src, :n]), self.paged_caches)
+        with span("backend.copy_rows", rows=n):
+            self.paged_caches = jax.tree.map(
+                lambda a: a.at[:, dst, :n].set(a[:, src, :n]),
+                self.paged_caches)
+
+    def pool_pages(self) -> int:
+        """Pages in the compute-page pool (0 on the ring path)."""
+        if not self.paged:
+            return 0
+        return int(jax.tree.leaves(self.paged_caches)[0].shape[1])
+
+    def _build_scope(self, kind: str, length: int, width: int):
+        """Around a paged step program's call: a ``backend.build`` span
+        and the ``builds`` count the first time its key runs on this
+        pool (the call traces and compiles, or loads, the program)."""
+        if (kind, length, width) in self._built:
+            return _NO_BUILD
+        return self._building(kind, length, width)
+
+    @contextlib.contextmanager
+    def _building(self, kind: str, length: int, width: int):
+        t0 = time.perf_counter()
+        with span("backend.build", kind=kind, length=length, width=width):
+            yield
+        self._built.add((kind, length, width))
+        b = self.builds[kind]
+        b["count"] += 1
+        b["seconds"] += time.perf_counter() - t0
 
     def export_pages(self, ids: List[int]):
         """Host-side copy of the listed compute pages (page axis first in
@@ -470,43 +510,55 @@ class ComputeBackend:
         chunk computes in place on the pool pages listed in ``page_table``
         (the request's session pages) — no per-slot ring insert."""
         toks = np.asarray(ck.tokens, np.int32)
-        if self.paged:
-            assert page_table is not None
-            tbl = jnp.asarray(page_table, jnp.int32)[None]
-            W = int(tbl.shape[1])
-            if ck.first:
+        W = 0 if page_table is None else int(np.shape(page_table)[-1])
+        with span("backend.prefill", width=W, rows=toks.shape[0]):
+            if self.paged:
+                assert page_table is not None
+                kind = "first" if ck.first else "extend"
+                with span("backend.inputs"):
+                    tbl = jnp.asarray(page_table, jnp.int32)[None]
+                    tok_d = jnp.asarray(toks)[None]
+                    if ck.first:
+                        batch = {"tokens": tok_d}
+                        if self.cfg.frontend == "vision":
+                            batch["image_embeds"] = jnp.zeros(
+                                (1, self.cfg.n_frontend_tokens,
+                                 self.cfg.d_model),
+                                jnp.dtype(self.cfg.dtype))
+                with span("backend.launch"), \
+                        self._build_scope(kind, toks.shape[0], W):
+                    if ck.first:
+                        logits, self.paged_caches = self._paged_first_fn(
+                            toks.shape[0], W)(self.params, self.paged_caches,
+                                              batch, tbl)
+                    else:
+                        logits, self.paged_caches = self._paged_extend_fn(
+                            toks.shape[0], W)(self.params, self.paged_caches,
+                                              tok_d, ck.offset, tbl)
+            elif ck.first:
                 batch = {"tokens": jnp.asarray(toks)[None]}
                 if self.cfg.frontend == "vision":
                     batch["image_embeds"] = jnp.zeros(
                         (1, self.cfg.n_frontend_tokens, self.cfg.d_model),
                         jnp.dtype(self.cfg.dtype))
-                logits, self.paged_caches = self._paged_first_fn(
-                    toks.shape[0], W)(self.params, self.paged_caches,
-                                      batch, tbl)
+                with span("backend.launch"):
+                    logits, caches1 = self._prefill_fn(toks.shape[0])(
+                        self.params, batch)
+                self._insert_slot(ck.slot, caches1)
             else:
-                logits, self.paged_caches = self._paged_extend_fn(
-                    toks.shape[0], W)(self.params, self.paged_caches,
-                                      jnp.asarray(toks)[None], ck.offset,
-                                      tbl)
-        elif ck.first:
-            batch = {"tokens": jnp.asarray(toks)[None]}
-            if self.cfg.frontend == "vision":
-                batch["image_embeds"] = jnp.zeros(
-                    (1, self.cfg.n_frontend_tokens, self.cfg.d_model),
-                    jnp.dtype(self.cfg.dtype))
-            logits, caches1 = self._prefill_fn(toks.shape[0])(self.params, batch)
-            self._insert_slot(ck.slot, caches1)
-        else:
-            caches1 = self._extract_slot(ck.slot)
-            logits, caches1 = self._extend_fn(toks.shape[0])(
-                self.params, caches1, jnp.asarray(toks)[None], ck.offset)
-            self._insert_slot(ck.slot, caches1)
-        if not ck.last:
-            return None
-        tok = np.asarray(self.sample(logits))
-        self.last_tokens[ck.slot] = tok
-        self.positions[ck.slot] = ck.offset + toks.shape[0] - 1
-        return tok
+                caches1 = self._extract_slot(ck.slot)
+                with span("backend.launch"):
+                    logits, caches1 = self._extend_fn(toks.shape[0])(
+                        self.params, caches1, jnp.asarray(toks)[None],
+                        ck.offset)
+                self._insert_slot(ck.slot, caches1)
+            if not ck.last:
+                return None
+            with span("backend.fetch"):
+                tok = np.asarray(self.sample(logits))
+                self.last_tokens[ck.slot] = tok
+                self.positions[ck.slot] = ck.offset + toks.shape[0] - 1
+            return tok
 
     def run_decode(self, slots: List[int],
                    page_tables: Optional[np.ndarray] = None,
@@ -518,58 +570,70 @@ class ComputeBackend:
         (inactive rows all-null) and ``audit_pages`` lists compute pages
         the round must not write (other sessions' pages)."""
         B = self.ecfg.max_slots
-        act = np.zeros((B,), bool)
-        act[slots] = True
-        inactive = [s for s in range(B) if not act[s]]
-        before = None
-        if self.paged:
-            assert page_tables is not None
-            if self.ecfg.audit_decode_masking and audit_pages:
-                idx = np.asarray(audit_pages, np.int32)
-                before = [np.asarray(leaf[:, idx])
-                          for leaf in jax.tree.leaves(self.paged_caches)]
-            pos = jnp.asarray(np.maximum(self.positions + 1, 0), jnp.int32)
-            tbl = jnp.asarray(page_tables, jnp.int32)
-            logits, self.paged_caches = self._paged_decode_fn(
-                int(tbl.shape[1]))(self.params, self.paged_caches,
-                                   jnp.asarray(self.last_tokens), pos, tbl,
-                                   jnp.asarray(act))
-            if before is not None:
-                # paged variant of the clobbering guard: a decode round
-                # writes exactly one row of each active session's open
-                # page — shared (sealed) pages and other sessions' pages
-                # must come back bit-identical
-                idx = np.asarray(audit_pages, np.int32)
-                for b, leaf in zip(before,
-                                   jax.tree.leaves(self.paged_caches)):
-                    after = np.asarray(leaf[:, idx])
-                    assert np.array_equal(b, after, equal_nan=True), \
-                        "decode wrote another session's compute page " \
-                        "(paged masking regression)"
-        else:
-            if self.ecfg.audit_decode_masking and inactive:
-                before = [np.asarray(leaf[:, inactive])
-                          for leaf in jax.tree.leaves(self.caches)]
-            pos = jnp.asarray(np.maximum(self.positions + 1, 0), jnp.int32)
-            logits, self.caches = self._decode_jit(
-                self.params, self.caches, jnp.asarray(self.last_tokens), pos,
-                jnp.asarray(act))
-            if before is not None:
-                # regression guard for the PR 4 clobbering class: with the
-                # padded whole-prompt path gone, chunked prefill interleaves
-                # with decode for every stack — a decode round must not write
-                # ANY cache family (ring KV, MLA latents, conv/SSD state) of
-                # a slot it did not decode
-                for b, leaf in zip(before, jax.tree.leaves(self.caches)):
-                    after = np.asarray(leaf[:, inactive])
-                    assert np.array_equal(b, after, equal_nan=True), \
-                        "decode wrote an inactive slot's cache (active-slot " \
-                        "masking regression)"
-        next_np = np.asarray(self.sample(logits))
-        for slot in slots:
-            self.positions[slot] += 1
-            self.last_tokens[slot] = next_np[slot]
-        return next_np
+        W = 0 if page_tables is None else int(np.shape(page_tables)[-1])
+        with span("backend.decode", width=W, rows=len(slots)):
+            act = np.zeros((B,), bool)
+            act[slots] = True
+            inactive = [s for s in range(B) if not act[s]]
+            before = None
+            if self.paged:
+                assert page_tables is not None
+                if self.ecfg.audit_decode_masking and audit_pages:
+                    idx = np.asarray(audit_pages, np.int32)
+                    before = [np.asarray(leaf[:, idx])
+                              for leaf in jax.tree.leaves(self.paged_caches)]
+                with span("backend.inputs"):
+                    pos = jnp.asarray(np.maximum(self.positions + 1, 0),
+                                      jnp.int32)
+                    tbl = jnp.asarray(page_tables, jnp.int32)
+                    last = jnp.asarray(self.last_tokens)
+                    act_d = jnp.asarray(act)
+                with span("backend.launch"), \
+                        self._build_scope("decode", 1, W):
+                    logits, self.paged_caches = self._paged_decode_fn(W)(
+                        self.params, self.paged_caches, last, pos, tbl,
+                        act_d)
+                if before is not None:
+                    # paged variant of the clobbering guard: a decode round
+                    # writes exactly one row of each active session's open
+                    # page — shared (sealed) pages and other sessions'
+                    # pages must come back bit-identical
+                    idx = np.asarray(audit_pages, np.int32)
+                    for b, leaf in zip(before,
+                                       jax.tree.leaves(self.paged_caches)):
+                        after = np.asarray(leaf[:, idx])
+                        assert np.array_equal(b, after, equal_nan=True), \
+                            "decode wrote another session's compute page " \
+                            "(paged masking regression)"
+            else:
+                if self.ecfg.audit_decode_masking and inactive:
+                    before = [np.asarray(leaf[:, inactive])
+                              for leaf in jax.tree.leaves(self.caches)]
+                with span("backend.inputs"):
+                    pos = jnp.asarray(np.maximum(self.positions + 1, 0),
+                                      jnp.int32)
+                    last = jnp.asarray(self.last_tokens)
+                    act_d = jnp.asarray(act)
+                with span("backend.launch"):
+                    logits, self.caches = self._decode_jit(
+                        self.params, self.caches, last, pos, act_d)
+                if before is not None:
+                    # regression guard for the PR 4 clobbering class: with
+                    # the padded whole-prompt path gone, chunked prefill
+                    # interleaves with decode for every stack — a decode
+                    # round must not write ANY cache family (ring KV, MLA
+                    # latents, conv/SSD state) of a slot it did not decode
+                    for b, leaf in zip(before, jax.tree.leaves(self.caches)):
+                        after = np.asarray(leaf[:, inactive])
+                        assert np.array_equal(b, after, equal_nan=True), \
+                            "decode wrote an inactive slot's cache " \
+                            "(active-slot masking regression)"
+            with span("backend.fetch"):
+                next_np = np.asarray(self.sample(logits))
+                for slot in slots:
+                    self.positions[slot] += 1
+                    self.last_tokens[slot] = next_np[slot]
+            return next_np
 
     def free_slot(self, slot: int) -> None:
         self.positions[slot] = -1
@@ -781,6 +845,7 @@ class ServeEngine:
         self.outputs: Dict[int, list] = {}
         self._inflight: Dict[int, _SlotPrefill] = {}  # slot -> chunk state
         self._prep_cache: Dict[int, tuple] = {}  # rid -> (tokens, chunk, key)
+        self._submit_wall: Dict[int, float] = {}  # queued rid -> host clock
         self.tokens_generated = 0
         self.steps = 0
         self.prefill_chunks_run = 0
@@ -844,6 +909,7 @@ class ServeEngine:
         window's tail, exactly as decode does."""
         rid = len(self.outputs)
         self.outputs[rid] = []
+        self._submit_wall[rid] = mark("request.submit", rid=rid)
         submitted = self.mem.now if at is None else at
         self.sched.submit(Request(rid, prompt_tokens, max_new_tokens,
                                   submitted,
@@ -851,6 +917,12 @@ class ServeEngine:
                                   admit_after=(submitted if admit_after is None
                                                else admit_after)))
         return rid
+
+    def abandon(self, request_id: int, now: float) -> bool:
+        """Drop one queued request by id (event-driven abandonment).
+        Returns False if it already left the queue."""
+        self._submit_wall.pop(request_id, None)
+        return self.sched.abandon(request_id, now)
 
     # ------------------------------------------------------------------
     def _min_ring_len(self) -> int:
@@ -1104,8 +1176,9 @@ class ServeEngine:
         if self.ecfg.abandon_after_s is not None:
             # queued sessions older than the timeout hung up before first
             # token; sweep them before admission so they never take a slot
-            self.sched.abandon_timed_out(self.mem.now,
-                                         self.ecfg.abandon_after_s)
+            for req in self.sched.abandon_timed_out(
+                    self.mem.now, self.ecfg.abandon_after_s):
+                self._submit_wall.pop(req.request_id, None)
         prefix_len = self.backend.prefix_len()
         budget = self.ecfg.max_prefills_per_step
         for slot in sorted(self._inflight):
@@ -1119,6 +1192,11 @@ class ServeEngine:
             for slot, req in self.sched.admissions(limit=budget,
                                                    match_len=match_len,
                                                    now=self.mem.now):
+                t = self._submit_wall.pop(req.request_id, None)
+                if t is not None:
+                    recorder().record("request.queued", t,
+                                      time.perf_counter(),
+                                      rid=req.request_id)
                 st = self._admit(slot, req)
                 plan.prefill.append(st.next_chunk(slot, prefix_len))
                 budget -= 1
@@ -1454,35 +1532,70 @@ class ServeEngine:
 
     # ------------------------------------------------------------------
     def step(self) -> dict:
-        """One engine step: prefill chunks + one decode round, metered."""
-        plan = self._plan_step()
-        self.memplane.begin_step()
-        rpt = StepReport()
-        first_token_reqs: List[Request] = []
+        """One engine step: prefill chunks + one decode round, metered.
+        Each phase runs under the span that names it (DESIGN.md §3,
+        "Telemetry")."""
+        with span("engine.step", step=self.steps) as sp:
+            with span("engine.plan"):
+                plan = self._plan_step()
+            sp.attrs["chunks"] = len(plan.prefill)
+            sp.attrs["rows"] = len(plan.decode)
+            self.memplane.begin_step()
+            rpt = StepReport()
+            first_token_reqs: List[Request] = []
+            for ck in plan.prefill:
+                self._run_chunk(ck, rpt, first_token_reqs)
+            if plan.decode:
+                self._run_decode_round(plan.decode, rpt)
+            with span("mrm.finish"):
+                # advance simulated time by the modelled step latency
+                step_s, per_tier = self.memplane.finish_step()
+                self.mem.advance(step_s)
+                # the first token is out when the step that computed it
+                # completes: TTFT includes this step's modelled latency
+                for req in first_token_reqs:
+                    req.first_token_at = self.mem.now
+                self.kv.maintain()  # cold-leaf decay on the advanced clock
+            self.steps += 1
+            rpt.step_s = step_s
+            rpt.bytes_by_tier = per_tier
+            return {"step_s": step_s, "bytes": rpt.bytes,
+                    "bytes_by_tier": rpt.bytes_by_tier,
+                    "prefill_tokens": rpt.prefill_tokens,
+                    "decode_tokens": rpt.decode_tokens,
+                    "finished": rpt.finished,
+                    "active": len(self.sched.active),
+                    "queued": len(self.sched.queue)}
 
-        # --- prefill phase (whole prompts or chunks) ------------------
-        for ck in plan.prefill:
+    def _run_chunk(self, ck: PrefillChunk, rpt: StepReport,
+                   first_token_reqs: List[Request]) -> None:
+        """One prefill chunk (a whole prompt or a piece of one)."""
+        with span("engine.prefill", rid=ck.request_id, tokens=len(ck.tokens),
+                  offset=ck.offset, first=ck.first):
             st = self._inflight[ck.slot]
             if self.paged:
                 # pages must exist BEFORE compute: the kernel writes this
                 # chunk's KV into the session's own pages in place
-                self._account_chunk_kv(st, ck)
-                tok = self.backend.run_prefill_chunk(
-                    ck, page_table=self._session_table(ck.request_id))
+                with span("kv.account"):
+                    self._account_chunk_kv(st, ck)
+                    table = self._session_table(ck.request_id)
+                tok = self.backend.run_prefill_chunk(ck, page_table=table)
             else:
                 tok = self.backend.run_prefill_chunk(ck)
-            self.memplane.weight_pass()
-            if self.paged:
-                # meter the kernel's actual page-gather stream: queries at
-                # [q0, q1) — the first chunk embeds the meta prefix, so its
-                # oldest query is position 0
-                q0 = 0 if ck.first else ck.offset
-                self._meter_paged_reads(ck.request_id, q0,
-                                        ck.offset + len(ck.tokens))
+            with span("mrm.meter"):
+                self.memplane.weight_pass()
+                if self.paged:
+                    # meter the kernel's actual page-gather stream: queries
+                    # at [q0, q1) — the first chunk embeds the meta prefix,
+                    # so its oldest query is position 0
+                    q0 = 0 if ck.first else ck.offset
+                    self._meter_paged_reads(ck.request_id, q0,
+                                            ck.offset + len(ck.tokens))
             self.prefill_chunks_run += 1
             self.sched.stats.prefill_chunks += 1
             if not self.paged:
-                self._account_chunk_kv(st, ck)
+                with span("kv.account"):
+                    self._account_chunk_kv(st, ck)
             st.done += len(ck.tokens)
             st.req.prompt_pos = min(st.done, st.req.prompt_len)
             # point-snapshot stacks: the recurrent state is only capturable
@@ -1499,78 +1612,68 @@ class ServeEngine:
                 req = st.req
                 req.prefilled_at = self.mem.now
                 first_token_reqs.append(req)
-                self.outputs[req.request_id].append(int(np.asarray(tok).flat[0]))
+                self.outputs[req.request_id].append(
+                    int(np.asarray(tok).flat[0]))
                 req.generated += 1
                 self.tokens_generated += 1
                 if st.key is not None:
-                    self.kv.register_prefix(req.request_id, st.key,
-                                            payload=self._donation_fn(st, ck.slot))
+                    with span("engine.register"):
+                        self.kv.register_prefix(
+                            req.request_id, st.key,
+                            payload=self._donation_fn(st, ck.slot))
                 self.sched.mark_decoding(ck.slot)
                 del self._inflight[ck.slot]
 
-        # --- decode round ---------------------------------------------
-        if plan.decode:
+    def _run_decode_round(self, slots: List[int], rpt: StepReport) -> None:
+        """One batched decode round over ``slots``: meter every row's page
+        reads, then emit every row's token and release the rows that
+        finished (a session is metered before it is closed)."""
+        with span("engine.decode", rows=len(slots)):
             if self.paged:
-                # the new token's page must exist before the kernel writes
-                # its KV row in place
-                for slot in plan.decode:
-                    self.kv.append_tokens(
-                        self.sched.active[slot].request_id, 1)
-                self._inject_faults(plan.decode)
-                tables, audit = self._decode_tables(plan.decode)
-                next_np = self.backend.run_decode(plan.decode,
-                                                  page_tables=tables,
+                with span("kv.account"):
+                    # the new token's page must exist before the kernel
+                    # writes its KV row in place
+                    for slot in slots:
+                        self.kv.append_tokens(
+                            self.sched.active[slot].request_id, 1)
+                    self._inject_faults(slots)
+                    tables, audit = self._decode_tables(slots)
+                next_np = self.backend.run_decode(slots, page_tables=tables,
                                                   audit_pages=audit)
             else:
-                next_np = self.backend.run_decode(plan.decode)
-            self.memplane.weight_pass()
-            finished: List[int] = []
-            for slot in plan.decode:
-                req = self.sched.active[slot]
-                tok = next_np[slot]
-                self.outputs[req.request_id].append(int(np.asarray(tok).flat[0]))
-                req.generated += 1
-                self.tokens_generated += 1
-                rpt.decode_tokens += 1
-                self.sched.stats.decode_tokens += 1
-                if self.paged:
-                    # one query at the just-written position: the kernel
-                    # gathered the session's pages, not a synthetic
-                    # whole-history read
-                    p = int(self.backend.positions[slot])
-                    self._meter_paged_reads(req.request_id, p, p + 1)
-                else:
-                    self.kv.read_all(req.request_id)
-                    self.kv.append_tokens(req.request_id, 1)
-                done = (req.generated >= req.max_new_tokens or
-                        (self.cfg.n_codebooks == 1 and
-                         int(np.asarray(tok).flat[0]) == self.ecfg.eos_token))
-                if done:
-                    finished.append(slot)
-            for slot in finished:
-                req = self.sched.finish(slot, self.mem.now)
-                self.kv.close_session(req.request_id)
-                self.backend.free_slot(slot)
-                rpt.finished += 1
-
-        # --- advance simulated time by the modelled step latency ------
-        step_s, per_tier = self.memplane.finish_step()
-        self.mem.advance(step_s)
-        # the first token is out when the step that computed it completes:
-        # TTFT includes this step's modelled latency
-        for req in first_token_reqs:
-            req.first_token_at = self.mem.now
-        self.kv.maintain()   # cold-leaf decay runs on the advanced clock
-        self.steps += 1
-        rpt.step_s = step_s
-        rpt.bytes_by_tier = per_tier
-        return {"step_s": step_s, "bytes": rpt.bytes,
-                "bytes_by_tier": rpt.bytes_by_tier,
-                "prefill_tokens": rpt.prefill_tokens,
-                "decode_tokens": rpt.decode_tokens,
-                "finished": rpt.finished,
-                "active": len(self.sched.active),
-                "queued": len(self.sched.queue)}
+                next_np = self.backend.run_decode(slots)
+            with span("mrm.meter"):
+                self.memplane.weight_pass()
+                for slot in slots:
+                    rid = self.sched.active[slot].request_id
+                    if self.paged:
+                        # one query at the just-written position: the
+                        # kernel gathered the session's pages, not a
+                        # synthetic whole-history read
+                        p = int(self.backend.positions[slot])
+                        self._meter_paged_reads(rid, p, p + 1)
+                    else:
+                        self.kv.read_all(rid)
+                        self.kv.append_tokens(rid, 1)
+            with span("engine.emit"):
+                finished: List[int] = []
+                for slot in slots:
+                    req = self.sched.active[slot]
+                    tok = int(np.asarray(next_np[slot]).flat[0])
+                    self.outputs[req.request_id].append(tok)
+                    req.generated += 1
+                    self.tokens_generated += 1
+                    rpt.decode_tokens += 1
+                    self.sched.stats.decode_tokens += 1
+                    if (req.generated >= req.max_new_tokens or
+                            (self.cfg.n_codebooks == 1
+                             and tok == self.ecfg.eos_token)):
+                        finished.append(slot)
+                for slot in finished:
+                    req = self.sched.finish(slot, self.mem.now)
+                    self.kv.close_session(req.request_id)
+                    self.backend.free_slot(slot)
+                    rpt.finished += 1
 
     # ------------------------------------------------------------------
     def run_until_idle(self, max_steps: int = 10000,
@@ -1635,7 +1738,20 @@ class ServeEngine:
             "prefix": prefix,
             "latency": latency_percentiles(self.sched.latency),
             "reliability": self._reliability_report(),
+            "telemetry": self._telemetry_report(),
         }
+
+    def _telemetry_report(self) -> dict:
+        """Counters of the work that makes no token (DESIGN.md §3,
+        "Telemetry"): step programs built per kind, pool growths and size
+        on this engine; spans lost to the ring, and XLA compiles (or
+        persistent-cache loads) with their seconds, in the process."""
+        be = self.backend
+        out = {"builds": {k: dict(v) for k, v in be.builds.items()},
+               "pool_grows": be.pool_grows,
+               "pool_pages": be.pool_pages()}
+        out.update(recorder().totals())
+        return out
 
     def _reliability_report(self) -> dict:
         """The reliability plane's ledger (DESIGN.md §11): ECC profile,
