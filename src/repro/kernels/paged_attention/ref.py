@@ -58,7 +58,7 @@ def split_kv(kv):
 
 
 def write_tokens_to_pages(kv_pages, kv_new, positions, page_table,
-                          active=None):
+                          active=None, layer=None):
     """Scatter fused-KV rows into the paged plane.
 
     kv_pages: (P, ps, 2*Hkv, D) pool; kv_new: (B, S, 2*Hkv, D);
@@ -66,8 +66,11 @@ def write_tokens_to_pages(kv_pages, kv_new, positions, page_table,
     Rows whose position falls past the table width, or whose ``active``
     flag is False, are dropped (written nowhere) via an out-of-bounds
     scatter index — a mid-prefill slot's pages are never clobbered by a
-    batched decode write."""
-    P, ps = kv_pages.shape[0], kv_pages.shape[1]
+    batched decode write. With ``layer`` (a scalar index) the pool is
+    stacked over layers, (L, P, ps, 2*Hkv, D), and the rows land in that
+    layer's pages by one scatter on (layer, page, row): no layer's pool
+    is sliced out and written back."""
+    P, ps = kv_pages.shape[-4], kv_pages.shape[-3]
     positions = jnp.asarray(positions, jnp.int32)
     W = page_table.shape[1]
     slot = positions // ps                               # (B, S) table slots
@@ -77,8 +80,8 @@ def write_tokens_to_pages(kv_pages, kv_new, positions, page_table,
         ok &= jnp.asarray(active, bool).reshape(-1, 1)
     pid = jnp.take_along_axis(page_table, jnp.clip(slot, 0, W - 1), axis=1)
     pid = jnp.where(ok, pid, P)                          # OOB -> dropped
-    return kv_pages.at[pid, row].set(kv_new.astype(kv_pages.dtype),
-                                     mode="drop")
+    at = (pid, row) if layer is None else (layer, pid, row)
+    return kv_pages.at[at].set(kv_new.astype(kv_pages.dtype), mode="drop")
 
 
 # ---------------------------------------------------------------------------
@@ -89,14 +92,15 @@ def write_tokens_to_pages(kv_pages, kv_new, positions, page_table,
 def paged_attention_rows(q, kv_pages, page_table, q_pos, *, scale: float,
                          cap: Optional[float] = None,
                          window: Optional[int] = None,
-                         kv_pos_pages=None):
+                         kv_pos_pages=None, layer=None):
     """Row-flattened paged attention — the one attend everything shares.
 
     q: (R, Hq, D) query rows; kv_pages: (P, ps, 2*Hkv, D); page_table:
     (R, W) int32 per-row tables; q_pos: (R,) absolute query positions.
     Extend flattens (B, S) to R = B*S rows, batched decode is R = B rows
     of one token each — both are just rows here. Returns (R, Hq, D) in
-    the pool dtype.
+    the pool dtype. With ``layer`` the pool is stacked over layers,
+    (L, P, ps, 2*Hkv, D), and each page is gathered on (layer, page).
 
     The page loop keeps a per-row online softmax in fp32; masked pages
     (padding, future positions, outside the window) contribute exactly
@@ -108,7 +112,7 @@ def paged_attention_rows(q, kv_pages, page_table, q_pos, *, scale: float,
     if kv_pos_pages is not None:
         kv_pos_pages = jnp.asarray(kv_pos_pages, jnp.int32)
     R, Hq, D = q.shape
-    ps, H2 = kv_pages.shape[1], kv_pages.shape[2]
+    ps, H2 = kv_pages.shape[-3], kv_pages.shape[-2]
     Hkv = H2 // 2
     G = Hq // Hkv
     W = page_table.shape[1]
@@ -122,7 +126,8 @@ def paged_attention_rows(q, kv_pages, page_table, q_pos, *, scale: float,
     def body(j, carry):
         m, l, acc = carry
         pid = page_table[:, j]                           # (R,)
-        kv = kv_pages[pid]                               # (R, ps, 2Hkv, D)
+        kv = (kv_pages[pid] if layer is None
+              else kv_pages[layer, pid])                 # (R, ps, 2Hkv, D)
         k = kv[:, :, 0::2, :].astype(jnp.float32)        # (R, ps, Hkv, D)
         v = kv[:, :, 1::2, :].astype(jnp.float32)
         if kv_pos_pages is not None:

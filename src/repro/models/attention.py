@@ -419,6 +419,7 @@ def attention_sublayer(
     cur_pos=None,
     decode_active=None,   # (B,) bool: rows whose cache the decode may touch
     page_table=None,      # (B, W) int32: paged compute plane (DESIGN.md §10)
+    layer=None,           # index into a pool stacked over layers
 ) -> Tuple[jax.Array, Optional[dict]]:
     """x: (B, S, d) -> (attn_out (B, S, d), updated cache or None)."""
     B, S, d = x.shape
@@ -438,7 +439,8 @@ def attention_sublayer(
     if cache is not None and "kv_pages" in cache:
         # paged compute plane: write this step's KV straight into the
         # shared page pool and attend page-by-page — extend and decode
-        # are the same rows-form call, only the positions differ.
+        # are the same rows-form call, only the positions differ. With
+        # ``layer`` the pool is the whole stack, updated in place.
         assert page_table is not None
         if mode == "decode":
             cur = jnp.asarray(cur_pos, jnp.int32)
@@ -452,12 +454,12 @@ def attention_sublayer(
         with jax.named_scope("kv_write"):
             kvp = write_tokens_to_pages(cache["kv_pages"],
                                         interleave_kv(k, v), pos2d,
-                                        page_table, active=act)
+                                        page_table, active=act, layer=layer)
         Hq, hd = q.shape[2], q.shape[3]
         out = paged_attention_rows(
             q.reshape(B * S, Hq, hd), kvp,
             jnp.repeat(page_table, S, axis=0), pos2d.reshape(B * S),
-            scale=scale, cap=cfg.attn_softcap, window=window,
+            scale=scale, cap=cfg.attn_softcap, window=window, layer=layer,
         ).reshape(B, S, Hq, hd)
         out = jnp.einsum("bshk,hkd->bsd", out.astype(q.dtype), p["wo"])
         return out, {"kv_pages": kvp}

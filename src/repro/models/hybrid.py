@@ -43,6 +43,7 @@ def hybrid_sublayer(
     decode_active=None,
     page_table=None,
     page_tokens=None,
+    layer=None,
 ) -> Tuple[jnp.ndarray, Optional[dict]]:
     """Both branches run in every mode (incl. ``extend``: the attention
     half resumes against its ring cache positionally while the SSM half
@@ -54,18 +55,19 @@ def hybrid_sublayer(
     which is why the padded whole-prompt path had to go. ``decode_active``
     masks both halves' cache writes for inactive rows. With ``page_table``
     the union cache is paged (DESIGN.md §10): KV pages for the attention
-    half, conv/state pages for the SSM half, one table for both."""
+    half, conv/state pages for the SSM half, one table for both; ``layer``
+    indexes both pools when they are stacked over layers."""
     attn_cache = cache["attn"] if cache is not None else None
     ssm_cache = cache["ssm"] if cache is not None else None
     a_out, a_cache = attention_sublayer(
         cfg, p["attn"], x, positions=positions, window=window, sh=sh,
         cache=attn_cache, mode=mode, cur_pos=cur_pos,
-        decode_active=decode_active, page_table=page_table)
+        decode_active=decode_active, page_table=page_table, layer=layer)
     s_out, s_cache = ssm_sublayer(cfg, p["ssm"], x, sh=sh, cache=ssm_cache,
                                   mode=mode, decode_active=decode_active,
                                   positions=positions, cur_pos=cur_pos,
                                   page_table=page_table,
-                                  page_tokens=page_tokens)
+                                  page_tokens=page_tokens, layer=layer)
     out = 0.5 * (rmsnorm(a_out, p["attn_out_norm"], cfg.norm_eps)
                  + rmsnorm(s_out, p["ssm_out_norm"], cfg.norm_eps))
     new_cache = None

@@ -130,6 +130,7 @@ def mla_sublayer(
     cur_pos=None,
     decode_active=None,
     page_table=None,  # (B, W) int32: paged compute plane (DESIGN.md §10)
+    layer=None,       # index into a pool stacked over layers
 ) -> Tuple[jax.Array, Optional[dict]]:
     """Modes: ``train``/``prefill`` (full-sequence chunked attention over
     the *unpadded* layout — token i at absolute position i, so causal
@@ -170,14 +171,14 @@ def mla_sublayer(
             act = None
         with jax.named_scope("kv_write"):
             kvp = write_tokens_to_pages(cache["kv_pages"], kv_new, pos2d,
-                                        page_table, active=act)
+                                        page_table, active=act, layer=layer)
         qc = jnp.einsum("bshk,rhk->bshr", qn, p["w_uk"])
         q_f = jnp.concatenate([qc, qr], axis=-1)              # (B, S, H, r+dr)
         H = q_f.shape[2]
         o = paged_attention_rows(
             q_f.reshape(B * S, H, r + dr), kvp,
             jnp.repeat(page_table, S, axis=0), pos2d.reshape(B * S),
-            scale=scale).reshape(B, S, H, r + dr)
+            scale=scale, layer=layer).reshape(B, S, H, r + dr)
         out = jnp.einsum("bshr,rhk->bshk", o[..., :r].astype(x.dtype),
                          p["w_uv"])
         out = jnp.einsum("bshk,hkd->bsd", out, p["wo"])

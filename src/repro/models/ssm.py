@@ -221,6 +221,7 @@ def ssm_sublayer(
     cur_pos=None,
     page_table=None,
     page_tokens: Optional[int] = None,
+    layer=None,
 ) -> Tuple[jax.Array, Optional[dict]]:
     """x: (B, S, d_model) -> (out, updated cache or None).
 
@@ -244,7 +245,8 @@ def ssm_sublayer(
     ``cur_pos`` per row for decode), and the updated state is scattered
     back to the page owning that position. Null page 0's zeros are the
     empty-history init, so a cold start and a chunk resumed at a page
-    boundary run the identical recurrence."""
+    boundary run the identical recurrence. With ``layer`` the pages are
+    stacked over layers and read and written on (layer, page)."""
     from repro.models.layers import rmsnorm  # avoid cycle
 
     B, S, d = x.shape
@@ -255,6 +257,8 @@ def ssm_sublayer(
         q0 = cur_pos if mode == "decode" else positions[0]
         pid_read, pid_write, ok = _paged_state_slots(page_table, q0,
                                                      page_tokens)
+        lead = () if layer is None else (layer,)
+        at_read = lead + (pid_read,)
     zxbcdt = x @ p["in_proj"]
     z, xi, b, c, dt = _split_proj(cfg, zxbcdt)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
@@ -262,7 +266,7 @@ def ssm_sublayer(
 
     xbc = jnp.concatenate([xi, b, c], axis=-1)
     if paged:
-        conv_state = jnp.take(cache["conv_pages"], pid_read, axis=0)
+        conv_state = cache["conv_pages"][at_read]
     else:
         conv_state = cache["conv"] if cache is not None else None
     xbc, new_conv = _causal_conv(xbc, p["conv_w"], p["conv_b"], conv_state)
@@ -276,8 +280,7 @@ def ssm_sublayer(
     new_cache = None
     if mode == "decode":
         assert cache is not None
-        state = (jnp.take(cache["state_pages"], pid_read, axis=0) if paged
-                 else cache["state"])
+        state = cache["state_pages"][at_read] if paged else cache["state"]
         y1, new_state = ssd_decode(xh[:, 0], dt[:, 0], a, bg[:, 0], cg[:, 0], state)
         y = y1[:, None]
         if decode_active is not None:
@@ -293,7 +296,7 @@ def ssm_sublayer(
         # a fresh cache IS the zero state, and null page 0 IS the zero state
         # in paged mode)
         if paged:
-            init = jnp.take(cache["state_pages"], pid_read, axis=0)
+            init = cache["state_pages"][at_read]
         else:
             init = cache["state"] if cache is not None else None
         y, final_state = ssd_chunked(xh, dt, a, bg, cg, cfg.ssm_chunk,
@@ -306,12 +309,12 @@ def ssm_sublayer(
     if cache is not None:
         if paged:
             # out-of-range / null rows drop (page 0 is never written)
-            P = cache["state_pages"].shape[0]
-            pw = jnp.where(ok, pid_write, P)
+            P = cache["state_pages"].shape[len(lead)]
+            at = lead + (jnp.where(ok, pid_write, P),)
             new_cache = {
-                "conv_pages": cache["conv_pages"].at[pw].set(
+                "conv_pages": cache["conv_pages"].at[at].set(
                     new_conv.astype(cache["conv_pages"].dtype), mode="drop"),
-                "state_pages": cache["state_pages"].at[pw].set(
+                "state_pages": cache["state_pages"].at[at].set(
                     new_state, mode="drop"),
             }
         else:

@@ -92,12 +92,14 @@ def abstract_params(cfg: ModelConfig, shardings=None):
 
 def apply_block(cfg: ModelConfig, spec: LayerSpec, p: dict, x, *, positions,
                 sh=None, cache=None, mode="train", cur_pos=None,
-                decode_active=None, page_table=None, page_tokens=None):
+                decode_active=None, page_table=None, page_tokens=None,
+                layer=None):
     """Pre-norm residual block. Returns (x, new_cache, aux). With
     ``page_table`` every mixer family computes in place on pooled pages
     (DESIGN.md §10): KV pages for attention/MLA, conv+SSD state pages for
     SSM, both for the hybrid union (``page_tokens`` is the static page
-    size the point stacks need to resolve state-page slots)."""
+    size the point stacks need to resolve state-page slots; ``layer``
+    indexes a pool stacked over the group's layers)."""
     aux = jnp.zeros((), jnp.float32)
     with jax.named_scope("norm"):
         h = rmsnorm(x, p["norm1"], cfg.norm_eps)
@@ -105,7 +107,8 @@ def apply_block(cfg: ModelConfig, spec: LayerSpec, p: dict, x, *, positions,
         h, new_cache = _mixer(cfg, spec, p, h, positions=positions, sh=sh,
                               cache=cache, mode=mode, cur_pos=cur_pos,
                               decode_active=decode_active,
-                              page_table=page_table, page_tokens=page_tokens)
+                              page_table=page_table, page_tokens=page_tokens,
+                              layer=layer)
     if cfg.post_norms:
         with jax.named_scope("norm"):
             h = rmsnorm(h, p["post_norm1"], cfg.norm_eps)
@@ -137,32 +140,33 @@ _MIXER_SCOPE = {"attn": "attention", "mla": "attention", "ssm": "ssm",
 
 
 def _mixer(cfg: ModelConfig, spec: LayerSpec, p: dict, h, *, positions,
-           sh, cache, mode, cur_pos, decode_active, page_table, page_tokens):
+           sh, cache, mode, cur_pos, decode_active, page_table, page_tokens,
+           layer):
     """The block's token mixer. Returns (h, new_cache)."""
     if spec.kind == "attn":
         h, new_cache = attention_sublayer(cfg, p["mixer"], h, positions=positions,
                                           window=spec.window, sh=sh, cache=cache,
                                           mode=mode, cur_pos=cur_pos,
                                           decode_active=decode_active,
-                                          page_table=page_table)
+                                          page_table=page_table, layer=layer)
     elif spec.kind == "mla":
         h, new_cache = mla_sublayer(cfg, p["mixer"], h, positions=positions, sh=sh,
                                     cache=cache, mode=mode, cur_pos=cur_pos,
                                     decode_active=decode_active,
-                                    page_table=page_table)
+                                    page_table=page_table, layer=layer)
     elif spec.kind == "ssm":
         h, new_cache = ssm_sublayer(cfg, p["mixer"], h, sh=sh, cache=cache,
                                     mode=mode, decode_active=decode_active,
                                     positions=positions, cur_pos=cur_pos,
                                     page_table=page_table,
-                                    page_tokens=page_tokens)
+                                    page_tokens=page_tokens, layer=layer)
     elif spec.kind == "hybrid":
         h, new_cache = hybrid_sublayer(cfg, p["mixer"], h, positions=positions,
                                        window=spec.window, sh=sh, cache=cache,
                                        mode=mode, cur_pos=cur_pos,
                                        decode_active=decode_active,
                                        page_table=page_table,
-                                       page_tokens=page_tokens)
+                                       page_tokens=page_tokens, layer=layer)
     else:
         raise ValueError(spec.kind)
     return h, new_cache
@@ -276,13 +280,39 @@ def _embed_inputs(cfg: ModelConfig, params, batch: dict, sh=None):
 def apply_groups(cfg: ModelConfig, params, x, *, positions, sh=None,
                  caches=None, mode="train", cur_pos=None, decode_active=None,
                  page_table=None, page_tokens=None):
-    """Run every scan group. Returns (x, new_caches, aux_total)."""
+    """Run every scan group. Returns (x, new_caches, aux_total).
+
+    With ``page_table`` (the paged plane) each unit's pool, stacked over
+    the group's layers, rides in the scan's carry and every layer updates
+    it in place at its own index: no layer's pool is sliced out as a
+    scanned input or stacked back as an output (DESIGN.md §10)."""
     groups = cfg.scan_groups()
     aux_total = jnp.zeros((), jnp.float32)
     new_caches = []
     for gi, g in enumerate(groups):
         gp = params["groups"][gi]
         gc = caches[gi] if caches is not None else None
+
+        if page_table is not None:
+            def paged_body(carry, xs, _g=g):
+                xx, aux, pools = carry
+                params_t, layer = xs
+                outs = []
+                for u, spec in enumerate(_g.unit):
+                    xx, pool, aux_u = apply_block(
+                        cfg, spec, params_t[u], xx, positions=positions,
+                        sh=sh, cache=pools[u], mode=mode, cur_pos=cur_pos,
+                        decode_active=decode_active, page_table=page_table,
+                        page_tokens=page_tokens, layer=layer)
+                    outs.append(pool)
+                    aux = aux + aux_u
+                return (xx, aux, tuple(outs)), None
+
+            (x, aux_total, gc), _ = jax.lax.scan(
+                paged_body, (x, aux_total, gc),
+                (gp, jnp.arange(g.repeats, dtype=jnp.int32)))
+            new_caches.append(gc)
+            continue
 
         def body(carry, xs, _g=g):
             xx, aux = carry

@@ -54,6 +54,7 @@ of merit.
 from __future__ import annotations
 
 import contextlib
+import sys
 import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
@@ -71,6 +72,10 @@ from repro.serving.scheduler import ContinuousBatchScheduler, Request
 from repro.serving.telemetry import mark, recorder, span
 
 _NO_BUILD = contextlib.nullcontext()
+
+# sys.getrefcount of an object that one list alone holds, read while a
+# generator walks that list: any more means other code holds it too
+_SOLE_REFS = max(sys.getrefcount(a) for a in [object()])
 
 
 @dataclass
@@ -293,6 +298,7 @@ class ComputeBackend:
                        for k in ("first", "extend", "decode")}
         self._built: set = set()
         self.pool_grows = 0
+        self.pool_copies = 0
         if paged:
             # paged compute plane (DESIGN.md §10): one pooled page array per
             # cache family, owned here, indexed by PagedKVManager pages via
@@ -338,6 +344,18 @@ class ComputeBackend:
         return self._extend_jit[length]
 
     # -- paged compute-page pool (DESIGN.md §10) -----------------------
+    @property
+    def paged_caches(self):
+        """The compute-page pool: per group, per unit, a tree of page
+        arrays stacked over the group's layers. Each read builds a fresh
+        tree around the backend's own leaves, so code that keeps it is
+        seen holding the leaves at the next launch (``_launch``)."""
+        return jax.tree.unflatten(self._pool_def, self._pool)
+
+    @paged_caches.setter
+    def paged_caches(self, tree) -> None:
+        self._pool, self._pool_def = jax.tree.flatten(tree)
+
     @staticmethod
     def table_width(n_pages: int) -> int:
         """Power-of-2 page-table width bucket (bounds jit retraces)."""
@@ -433,33 +451,60 @@ class ComputeBackend:
             for d, a in zip(jax.tree.leaves(data),
                             jax.tree.leaves(self.paged_caches)))
 
+    @staticmethod
+    def paged_program(cfg: ModelConfig, kind: str, page_tokens: int):
+        """The jitted paged step program of ``kind`` ("first", "extend" or
+        "decode") as the backend launches it: ``(params, pool, ...) ->
+        (logits, pool)``. The pool argument is donated, so the program
+        owns it and updates it in place (DESIGN.md §10)."""
+        pt = page_tokens
+        if kind == "first":
+            fn = lambda p, c, batch, tbl: tfm.paged_prefill(
+                cfg, p, batch, c, tbl, page_tokens=pt)
+        elif kind == "extend":
+            fn = lambda p, c, t, off, tbl: tfm.paged_extend(
+                cfg, p, c, t, off, tbl, page_tokens=pt)
+        else:
+            fn = lambda p, c, t, pos, tbl, act: tfm.paged_decode(
+                cfg, p, c, t, pos, tbl, active=act, page_tokens=pt)
+        return jax.jit(fn, donate_argnums=1)
+
     def _paged_first_fn(self, length: int, W: int):
         key = (length, W)
         if key not in self._paged_first_jit:
-            cfg, pt = self.cfg, self.page_tokens
-            self._paged_first_jit[key] = jax.jit(
-                lambda p, c, batch, tbl: tfm.paged_prefill(cfg, p, batch,
-                                                           c, tbl,
-                                                           page_tokens=pt))
+            self._paged_first_jit[key] = self.paged_program(
+                self.cfg, "first", self.page_tokens)
         return self._paged_first_jit[key]
 
     def _paged_extend_fn(self, length: int, W: int):
         key = (length, W)
         if key not in self._paged_extend_jit:
-            cfg, pt = self.cfg, self.page_tokens
-            self._paged_extend_jit[key] = jax.jit(
-                lambda p, c, t, off, tbl: tfm.paged_extend(cfg, p, c, t,
-                                                           off, tbl,
-                                                           page_tokens=pt))
+            self._paged_extend_jit[key] = self.paged_program(
+                self.cfg, "extend", self.page_tokens)
         return self._paged_extend_jit[key]
 
     def _paged_decode_fn(self, W: int):
         if W not in self._paged_decode_jit:
-            cfg, pt = self.cfg, self.page_tokens
-            self._paged_decode_jit[W] = jax.jit(
-                lambda p, c, t, pos, tbl, act: tfm.paged_decode(
-                    cfg, p, c, t, pos, tbl, active=act, page_tokens=pt))
+            self._paged_decode_jit[W] = self.paged_program(
+                self.cfg, "decode", self.page_tokens)
         return self._paged_decode_jit[W]
+
+    def _launch(self, program, *args):
+        """Run a paged step program on the pool and rebind the pool to its
+        result; returns the logits. The program consumes the pool it is
+        handed. Where other code still holds a pool leaf, the leaves are
+        copied first, so that holder's arrays stay live. ``pool_copies``
+        counts the launches that leave the input pool unconsumed: copied
+        here, or not donated by the platform."""
+        leaves = self._pool
+        if any(sys.getrefcount(a) > _SOLE_REFS for a in leaves):
+            leaves = [jnp.copy(a) for a in leaves]
+        first = self._pool[0]
+        logits, self.paged_caches = program(
+            self.params, jax.tree.unflatten(self._pool_def, leaves), *args)
+        if not first.is_deleted():
+            self.pool_copies += 1
+        return logits
 
     # -- slot cache plumbing -------------------------------------------
     def _insert_slot(self, slot: int, new_caches) -> None:
@@ -528,13 +573,13 @@ class ComputeBackend:
                 with span("backend.launch"), \
                         self._build_scope(kind, toks.shape[0], W):
                     if ck.first:
-                        logits, self.paged_caches = self._paged_first_fn(
-                            toks.shape[0], W)(self.params, self.paged_caches,
-                                              batch, tbl)
+                        logits = self._launch(
+                            self._paged_first_fn(toks.shape[0], W), batch,
+                            tbl)
                     else:
-                        logits, self.paged_caches = self._paged_extend_fn(
-                            toks.shape[0], W)(self.params, self.paged_caches,
-                                              tok_d, ck.offset, tbl)
+                        logits = self._launch(
+                            self._paged_extend_fn(toks.shape[0], W), tok_d,
+                            ck.offset, tbl)
             elif ck.first:
                 batch = {"tokens": jnp.asarray(toks)[None]}
                 if self.cfg.frontend == "vision":
@@ -590,9 +635,8 @@ class ComputeBackend:
                     act_d = jnp.asarray(act)
                 with span("backend.launch"), \
                         self._build_scope("decode", 1, W):
-                    logits, self.paged_caches = self._paged_decode_fn(W)(
-                        self.params, self.paged_caches, last, pos, tbl,
-                        act_d)
+                    logits = self._launch(self._paged_decode_fn(W), last,
+                                          pos, tbl, act_d)
                 if before is not None:
                     # paged variant of the clobbering guard: a decode round
                     # writes exactly one row of each active session's open
@@ -1743,13 +1787,14 @@ class ServeEngine:
 
     def _telemetry_report(self) -> dict:
         """Counters of the work that makes no token (DESIGN.md §3,
-        "Telemetry"): step programs built per kind, pool growths and size
-        on this engine; spans lost to the ring, and XLA compiles (or
+        "Telemetry"): step programs built per kind, pool growths, size
+        and copies on this engine; spans lost to the ring, and XLA compiles (or
         persistent-cache loads) with their seconds, in the process."""
         be = self.backend
         out = {"builds": {k: dict(v) for k, v in be.builds.items()},
                "pool_grows": be.pool_grows,
-               "pool_pages": be.pool_pages()}
+               "pool_pages": be.pool_pages(),
+               "pool_copies": be.pool_copies}
         out.update(recorder().totals())
         return out
 

@@ -277,7 +277,7 @@ def test_report_carries_the_telemetry_counters(setup):
     eng = _engine(setup)
     _drain(eng, _prompts(8, 1), 2)
     tel = eng.report()["telemetry"]
-    assert set(tel) == {"builds", "pool_grows", "pool_pages",
+    assert set(tel) == {"builds", "pool_grows", "pool_pages", "pool_copies",
                         "spans_dropped", "jax_compiles", "jax_compile_s"}
     assert tel["spans_dropped"] == recorder().dropped
     assert tel["jax_compiles"] == recorder().jax_compiles

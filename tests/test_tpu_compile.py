@@ -1,13 +1,16 @@
 """Compile, for a described TPU v5e, what the chip runs: the ragged
 paged-attention kernel at qwen3-8b decode and 512-row extend geometry,
-and the served paged decode step at qwen3-8b's published widths. Nothing
-runs — the TPU compiler refuses here what it would refuse on the chip
-(unaligned slices, VMEM overruns, programs that do not fit).
+and the served paged step programs at qwen3-8b's published widths, as
+``ComputeBackend`` builds them. Nothing runs — the TPU compiler refuses
+here what it would refuse on the chip (unaligned slices, VMEM overruns,
+programs that do not fit) — and the compiled programs show whether the
+KV pool is updated in place.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this
 file."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -17,10 +20,14 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import at_depth, get_config
 from repro.kernels.paged_attention import ragged_paged_attention
 from repro.models import transformer as tfm
+from repro.serving.engine import ComputeBackend
 
 QWEN3 = get_config("qwen3-8b")
 PAGE = 32
 V5E_HBM = 16 * 10**9
+# the benchmark cell's geometry: qwen3-8b at 16 of 36 layers, 32 slots,
+# a 961-page pool, page tables 64 wide, 256-token prefill chunks
+CELL = dict(layers=16, slots=32, pages=961, width=64, chunk=256)
 
 
 @pytest.fixture(scope="module")
@@ -79,26 +86,62 @@ def test_paged_attention_kernel_compiles_for_v5e(one_chip, q_lens, kv_lens):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _served_step(sharding, kind: str, layers: int, slots: int, pages: int,
+                 width: int, chunk: int = 0):
+    """The engine's paged step program of ``kind`` for qwen3-8b at
+    published widths and ``layers`` layers, compiled for the chip.
+    Returns (compiled, cfg)."""
+    cfg = at_depth(QWEN3, layers)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: _spec(sharding, s.shape, s.dtype), tree)
+
+    params = on_chip(tfm.abstract_params(cfg))
+    pool = on_chip(jax.eval_shape(
+        lambda: tfm.init_paged_caches(cfg, pages, PAGE, jnp.bfloat16)))
+    step = ComputeBackend.paged_program(cfg, kind, PAGE)
+    if kind == "decode":
+        args = (_spec(sharding, (slots, 1), jnp.int32),
+                _spec(sharding, (slots,), jnp.int32),
+                _spec(sharding, (slots, width), jnp.int32),
+                _spec(sharding, (slots,), jnp.bool_))
+    else:
+        args = (_spec(sharding, (1, chunk), jnp.int32),
+                _spec(sharding, (), jnp.int32),
+                _spec(sharding, (1, width), jnp.int32))
+    return step.lower(params, pool, *args).compile(), cfg
+
+
+@pytest.mark.parametrize("kind", ["decode", "extend"])
+def test_served_step_updates_the_pool_in_place(one_chip, kind):
+    """At the benchmark cell's geometry the served decode step and the
+    256-token extend step own the pool: it is aliased to the output, the
+    temporaries stay under one layer's pool, and no slice, write-back or
+    copy of a layer's pool or of the whole pool is left in the program."""
+    compiled, cfg = _served_step(one_chip, kind, **CELL)
+    layer_pool = (CELL["pages"], PAGE, 2 * cfg.n_kv_heads,
+                  cfg.resolved_head_dim)
+    pool_bytes = CELL["layers"] * 2 * int(jnp.prod(jnp.asarray(layer_pool)))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // CELL["layers"]
+    dims = ",".join(map(str, layer_pool))
+    pool_shape = re.compile(rf"bf16\[({CELL['layers']},|1,)?{dims}\]")
+    moves = re.compile(r"^\s*(?:ROOT )?%\S+ = (\S+) "
+                       r"(dynamic-slice|dynamic-update-slice|copy)\(")
+    found = [m.group(0) for m in map(moves.match,
+                                     compiled.as_text().splitlines())
+             if m and pool_shape.match(m.group(1))]
+    assert not found, found
+
+
 def test_served_decode_step_fits_one_v5e(one_chip):
     """The engine's paged decode step for qwen3-8b at published widths,
     16 of 36 layers, 4 slots over a 17-page pool (what chip_smoke.py
     serves): it compiles and its weights, pool and temporaries fit one
     chip's HBM."""
-    cfg = at_depth(QWEN3, 16)
-    B, W = 4, 4
-
-    def on_chip(tree):
-        return jax.tree.map(lambda s: _spec(one_chip, s.shape, s.dtype), tree)
-
-    params = on_chip(tfm.abstract_params(cfg))
-    pool = on_chip(jax.eval_shape(
-        lambda: tfm.init_paged_caches(cfg, 17, PAGE, jnp.bfloat16)))
-    step = jax.jit(lambda p, c, t, pos, tbl, act: tfm.paged_decode(
-        cfg, p, c, t, pos, tbl, active=act, page_tokens=PAGE))
-    compiled = step.lower(
-        params, pool, _spec(one_chip, (B, 1), jnp.int32),
-        _spec(one_chip, (B,), jnp.int32), _spec(one_chip, (B, W), jnp.int32),
-        _spec(one_chip, (B,), jnp.bool_)).compile()
+    compiled, _ = _served_step(one_chip, "decode", layers=16, slots=4,
+                               pages=17, width=4)
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
