@@ -406,6 +406,16 @@ def append_to_cache(cache: dict, k1, v1, pos, active=None) -> dict:
 # ---------------------------------------------------------------------------
 
 
+def paged_pages_gathered(rows: int, width: int) -> int:
+    """KV pages one attention layer of a paged step gathers for ``rows``
+    query rows (B·S) over a ``width``-slot page table: the rows-form call
+    below (and MLA's) hands each query row its sequence's whole table
+    (``jnp.repeat(page_table, S)``) and reads every slot, padding
+    included. ``ComputeBackend`` counts on the host with this, so a change
+    of that call changes its count here."""
+    return rows * width
+
+
 def attention_sublayer(
     cfg: ModelConfig,
     p: dict,

@@ -66,6 +66,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core.simulator import MemorySystem
 from repro.models import transformer as tfm
+from repro.models.attention import paged_pages_gathered
 from repro.serving.kv_cache import PagedKVManager
 from repro.serving.radix import PrefixMatch
 from repro.serving.scheduler import ContinuousBatchScheduler, Request
@@ -299,6 +300,12 @@ class ComputeBackend:
         self._built: set = set()
         self.pool_grows = 0
         self.pool_copies = 0
+        # per paged program kind: KV pages the served attention gathers,
+        # and the pages its sequences' keys occupy (DESIGN.md §3)
+        self.kv_pages_gathered = {k: 0 for k in self.builds}
+        self.kv_pages_attended = {k: 0 for k in self.builds}
+        self._attn_layers = sum(s.kind in ("attn", "mla", "hybrid")
+                                for s in cfg.layer_specs())
         if paged:
             # paged compute plane (DESIGN.md §10): one pooled page array per
             # cache family, owned here, indexed by PagedKVManager pages via
@@ -489,6 +496,22 @@ class ComputeBackend:
                 self.cfg, "decode", self.page_tokens)
         return self._paged_decode_jit[W]
 
+    def _count_kv_pages(self, sp, kind: str, rows: int, width: int,
+                        keys) -> None:
+        """Count, on the host, the KV pages a paged launch's attention
+        gathers for ``rows`` query rows over a ``width``-slot table
+        (``paged_pages_gathered``) against the pages its sequences' keys
+        occupy, each read once (``keys``: keys per sequence after the
+        launch), over the attention layers; the launch's span ``sp``
+        carries both."""
+        L, pt = self._attn_layers, self.page_tokens
+        gathered = paged_pages_gathered(rows, width) * L
+        attended = sum(-(-int(k) // pt) for k in keys) * L
+        self.kv_pages_gathered[kind] += gathered
+        self.kv_pages_attended[kind] += attended
+        sp.attrs.update(kv_pages_gathered=gathered,
+                        kv_pages_attended=attended)
+
     def _launch(self, program, *args):
         """Run a paged step program on the pool and rebind the pool to its
         result; returns the logits. The program consumes the pool it is
@@ -556,11 +579,17 @@ class ComputeBackend:
         (the request's session pages) — no per-slot ring insert."""
         toks = np.asarray(ck.tokens, np.int32)
         W = 0 if page_table is None else int(np.shape(page_table)[-1])
-        with span("backend.prefill", width=W, rows=toks.shape[0]):
+        with span("backend.prefill", width=W, rows=toks.shape[0]) as sp:
             if self.paged:
                 assert page_table is not None
                 kind = "first" if ck.first else "extend"
                 with span("backend.inputs"):
+                    # a first chunk's query rows include the meta/frontend
+                    # prefix; the chunk's keys end where its tokens do
+                    keys = ck.offset + toks.shape[0]
+                    self._count_kv_pages(
+                        sp, kind, keys if ck.first else toks.shape[0], W,
+                        [keys])
                     tbl = jnp.asarray(page_table, jnp.int32)[None]
                     tok_d = jnp.asarray(toks)[None]
                     if ck.first:
@@ -616,7 +645,7 @@ class ComputeBackend:
         the round must not write (other sessions' pages)."""
         B = self.ecfg.max_slots
         W = 0 if page_tables is None else int(np.shape(page_tables)[-1])
-        with span("backend.decode", width=W, rows=len(slots)):
+        with span("backend.decode", width=W, rows=len(slots)) as sp:
             act = np.zeros((B,), bool)
             act[slots] = True
             inactive = [s for s in range(B) if not act[s]]
@@ -628,6 +657,11 @@ class ComputeBackend:
                     before = [np.asarray(leaf[:, idx])
                               for leaf in jax.tree.leaves(self.paged_caches)]
                 with span("backend.inputs"):
+                    # every one of the B rows gathers its table; an
+                    # active row attends keys 0..position + 1
+                    self._count_kv_pages(
+                        sp, "decode", B, W,
+                        [self.positions[s] + 2 for s in slots])
                     pos = jnp.asarray(np.maximum(self.positions + 1, 0),
                                       jnp.int32)
                     tbl = jnp.asarray(page_tables, jnp.int32)
@@ -1788,13 +1822,17 @@ class ServeEngine:
     def _telemetry_report(self) -> dict:
         """Counters of the work that makes no token (DESIGN.md §3,
         "Telemetry"): step programs built per kind, pool growths, size
-        and copies on this engine; spans lost to the ring, and XLA compiles (or
-        persistent-cache loads) with their seconds, in the process."""
+        and copies, and the KV pages the attention gathered against those
+        it attends per kind, on this engine; spans lost to the ring, and
+        XLA compiles (or persistent-cache loads) with their seconds, in
+        the process."""
         be = self.backend
         out = {"builds": {k: dict(v) for k, v in be.builds.items()},
                "pool_grows": be.pool_grows,
                "pool_pages": be.pool_pages(),
-               "pool_copies": be.pool_copies}
+               "pool_copies": be.pool_copies,
+               "kv_pages_gathered": dict(be.kv_pages_gathered),
+               "kv_pages_attended": dict(be.kv_pages_attended)}
         out.update(recorder().totals())
         return out
 

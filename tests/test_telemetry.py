@@ -278,9 +278,43 @@ def test_report_carries_the_telemetry_counters(setup):
     _drain(eng, _prompts(8, 1), 2)
     tel = eng.report()["telemetry"]
     assert set(tel) == {"builds", "pool_grows", "pool_pages", "pool_copies",
+                        "kv_pages_gathered", "kv_pages_attended",
                         "spans_dropped", "jax_compiles", "jax_compile_s"}
     assert tel["spans_dropped"] == recorder().dropped
     assert tel["jax_compiles"] == recorder().jax_compiles
+
+
+def test_kv_page_counts_match_the_hand_reckoning():
+    """A multi-head engine (Hq = Hkv, 4 attention layers, 8-token pages)
+    runs a 20-token prompt as a first chunk of 16 and an extend of 4, then
+    one decode round. The attention gathers rows × table width × layers
+    pages; the keys occupy sum(ceil(keys / 8)) × layers. Each launch's
+    span carries its own two counts."""
+    full = get_config("deepseek-7b")
+    cfg = reduced(full)
+    assert cfg.n_heads == cfg.n_kv_heads and not cfg.qk_norm
+    eng = _engine((full, cfg, init_params(cfg, jax.random.key(1))))
+    L = cfg.num_layers
+    t0 = time.perf_counter()
+    eng.submit(_prompts(10, 1, length=20)[0], 8)
+    while eng.backend.kv_pages_gathered["decode"] == 0:
+        eng.step()
+    tel = eng.report()["telemetry"]
+    # first: 16 rows, table of 2 pages; extend: 4 rows at offset 16, 3
+    # pages of keys in a table of 4; decode: B = 4 rows over a table of
+    # 4, one row of 21 keys (positions 0..20)
+    assert tel["kv_pages_gathered"] == {"first": 16 * 2 * L,
+                                        "extend": 4 * 4 * L,
+                                        "decode": 4 * 4 * L}
+    assert tel["kv_pages_attended"] == {"first": 2 * L, "extend": 3 * L,
+                                        "decode": 3 * L}
+    spans = recorder().spans(lo=t0)
+    for name, kinds in (("backend.prefill", ("first", "extend")),
+                        ("backend.decode", ("decode",))):
+        mine = [s.attrs for s in spans if s.name == name]
+        for key in ("kv_pages_gathered", "kv_pages_attended"):
+            assert sum(a[key] for a in mine) == sum(tel[key][k]
+                                                    for k in kinds)
 
 
 # ---------------------------------------------------------------------------
