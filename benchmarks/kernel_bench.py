@@ -11,8 +11,9 @@ windowed decode produce) and runs
 - the **ungrouped baseline** (``skip_blocks=False``: one full-width
   gather per sequence, nulls masked in-register — the PR 6 behavior),
 
-asserting the outputs bit-equal each other and the jnp reference (fp32)
-— identical decoded values — and metering the *achieved page-read
+asserting the outputs agree with the jnp reference (fp32) to a few ulp
+(a block of pages is summed at once where the reference scans page by
+page) — and metering the *achieved page-read
 bytes* of each grid with the kernel's host-side gather replica
 (``kernel.pages_gathered``). On sparse tables the grouped grid must read
 strictly less; smoke.sh gates that from the persisted trajectory.
@@ -76,7 +77,7 @@ def _time_call(fn, repeats: int = 3) -> float:
 
 
 def bench_case(ps: int, D: int, repeats: int = 3) -> dict:
-    """One geometry: grouped configs + ungrouped baseline, bit-equality
+    """One geometry: grouped configs + ungrouped baseline, agreement
     against the reference, achieved page-read bytes per grid."""
     import jax.numpy as jnp
 
@@ -86,7 +87,9 @@ def bench_case(ps: int, D: int, repeats: int = 3) -> dict:
 
     q, kv, tbl, cu, kvl, max_q = _sparse_case(ps, D)
     scale = 1.0 / D ** 0.5
-    ref = ragged_paged_attention_ref(q, kv, tbl, cu, kvl, scale=scale)
+    ref = np.asarray(ragged_paged_attention_ref(q, kv, tbl, cu, kvl,
+                                                scale=scale))
+    atol = 4 * float(np.spacing(np.abs(ref).max()))
     page_bytes = ps * kv.shape[2] * D * kv.dtype.itemsize
 
     def call(**kw):
@@ -97,8 +100,10 @@ def bench_case(ps: int, D: int, repeats: int = 3) -> dict:
     configs = []
     for bq, bkv, nb in CONFIGS:
         out = call(block_q=bq, block_kv=bkv, num_buffers=nb)
-        assert np.array_equal(np.asarray(out), np.asarray(ref)), \
-            f"grouped grid ps={ps} cfg=({bq},{bkv},{nb}) diverged from ref"
+        np.testing.assert_allclose(
+            np.asarray(out), ref, rtol=0, atol=atol,
+            err_msg=f"grouped grid ps={ps} cfg=({bq},{bkv},{nb}) diverged "
+                    f"from ref")
         configs.append({
             "block_q": bq, "block_kv": bkv, "num_buffers": nb,
             "time_us": _time_call(
@@ -106,8 +111,9 @@ def bench_case(ps: int, D: int, repeats: int = 3) -> dict:
                 repeats),
         })
     base = call(skip_blocks=False)
-    assert np.array_equal(np.asarray(base), np.asarray(ref)), \
-        f"ungrouped baseline ps={ps} diverged from ref"
+    np.testing.assert_allclose(
+        np.asarray(base), ref, rtol=0, atol=atol,
+        err_msg=f"ungrouped baseline ps={ps} diverged from ref")
     time_base = _time_call(lambda: call(skip_blocks=False), repeats)
 
     pages_grouped = pages_gathered(tbl, cu, kvl, page_size=ps,

@@ -173,15 +173,17 @@ def paged_attention_rows(q, kv_pages, page_table, q_pos, *, scale: float,
 def ragged_paged_attention_ref(q, kv_pages, page_table, cu_q_lens, kv_lens,
                                *, scale: float, cap: Optional[float] = None,
                                window: Optional[int] = None,
-                               q_pos=None, kv_pos_pages=None):
-    """Bit-matching jnp reference for the ragged Pallas kernel.
+                               q_pos=None, kv_pos_pages=None, layer=None):
+    """jnp reference for the ragged Pallas kernel.
 
     q: (T, Hq, D) queries of all sequences concatenated; cu_q_lens:
     (S+1,) int32 cumulative query lengths (T == cu_q_lens[-1]);
     kv_pages/page_table/kv_lens: per the module layout contract —
     ``page_table`` is (S, W), ``kv_lens`` (S,). Query i of sequence s
     sits at absolute position ``kv_lens[s] - q_len_s + i`` unless an
-    explicit ``q_pos`` (T,) is given. Decode is every q_len == 1."""
+    explicit ``q_pos`` (T,) is given. Decode is every q_len == 1. With
+    ``layer`` the pool is stacked over layers, as in
+    :func:`paged_attention_rows`."""
     T = q.shape[0]
     cu = jnp.asarray(cu_q_lens, jnp.int32)
     kv_lens = jnp.asarray(kv_lens, jnp.int32)
@@ -194,4 +196,4 @@ def ragged_paged_attention_ref(q, kv_pages, page_table, cu_q_lens, kv_lens,
     tbl = jnp.asarray(page_table, jnp.int32)[seg]        # (T, W)
     return paged_attention_rows(q, kv_pages, tbl, q_pos, scale=scale,
                                 cap=cap, window=window,
-                                kv_pos_pages=kv_pos_pages)
+                                kv_pos_pages=kv_pos_pages, layer=layer)

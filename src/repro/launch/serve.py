@@ -98,9 +98,6 @@ def build_engine(args, cfg, full, params):
                      prefix_caching=not args.no_prefix_caching,
                      tail_copy=args.tail_copy == "on",
                      paged_kernel=args.paged_kernel == "on",
-                     kernel_block_q=args.kernel_block_q,
-                     kernel_block_kv=args.kernel_block_kv,
-                     kernel_buffers=args.kernel_buffers,
                      radix_hot_threshold=args.radix_hot_threshold,
                      radix_hot_tier=args.radix_hot_tier,
                      radix_cold_ttl_s=args.radix_cold_ttl,
@@ -162,16 +159,6 @@ def parse_args(argv=None) -> argparse.Namespace:
                          "on KV pages, SSM/hybrid on pooled point-state "
                          "pages (zero-copy prefix hits, kernel-metered "
                          "tier reads; DESIGN.md §10)")
-    ap.add_argument("--kernel-block-q", type=int, default=None,
-                    help="paged-attention kernel: query rows per tile "
-                         "(None = autotuned best config for the page "
-                         "geometry; kernels/paged_attention/tune.py)")
-    ap.add_argument("--kernel-block-kv", type=int, default=None,
-                    help="paged-attention kernel: page-table slots per "
-                         "kv block (None = autotuned)")
-    ap.add_argument("--kernel-buffers", type=int, default=None,
-                    help="paged-attention kernel: DMA pipeline depth "
-                         "2-4 (None = autotuned)")
     ap.add_argument("--tail-copy", choices=("on", "off"), default="on",
                     help="sub-page tail reuse: copy the shared mid-page "
                          "tail into the borrower's page and resume prefill "

@@ -28,6 +28,7 @@ from repro.configs.base import ModelConfig
 from repro.kernels.paged_attention import (
     interleave_kv,
     paged_attention_rows,
+    ragged_paged_attention,
     write_tokens_to_pages,
 )
 from repro.models.layers import apply_rope, rmsnorm, softcap
@@ -408,11 +409,12 @@ def append_to_cache(cache: dict, k1, v1, pos, active=None) -> dict:
 
 def paged_pages_gathered(rows: int, width: int) -> int:
     """KV pages one attention layer of a paged step gathers for ``rows``
-    query rows (B·S) over a ``width``-slot page table: the rows-form call
-    below (and MLA's) hands each query row its sequence's whole table
-    (``jnp.repeat(page_table, S)``) and reads every slot, padding
-    included. ``ComputeBackend`` counts on the host with this, so a change
-    of that call changes its count here."""
+    query rows (B·S) over a ``width``-slot page table when it attends in
+    the rows form: each query row reads its sequence's whole table
+    (``jnp.repeat(page_table, S)``), padding included (MLA's call does the
+    same). The kernel path's count is ``kernel.pages_gathered``.
+    ``ComputeBackend`` counts on the host with these, so a change of
+    either call changes its count here."""
     return rows * width
 
 
@@ -430,6 +432,7 @@ def attention_sublayer(
     decode_active=None,   # (B,) bool: rows whose cache the decode may touch
     page_table=None,      # (B, W) int32: paged compute plane (DESIGN.md §10)
     layer=None,           # index into a pool stacked over layers
+    attn_kernel: bool = False,  # paged: the ragged kernel, not the rows form
 ) -> Tuple[jax.Array, Optional[dict]]:
     """x: (B, S, d) -> (attn_out (B, S, d), updated cache or None)."""
     B, S, d = x.shape
@@ -449,8 +452,12 @@ def attention_sublayer(
     if cache is not None and "kv_pages" in cache:
         # paged compute plane: write this step's KV straight into the
         # shared page pool and attend page-by-page — extend and decode
-        # are the same rows-form call, only the positions differ. With
-        # ``layer`` the pool is the whole stack, updated in place.
+        # are the same call, only the positions differ. With ``layer``
+        # the pool is the whole stack, updated in place. ``attn_kernel``
+        # attends through the ragged kernel: each of the B sequences
+        # (S query rows each) reads only the live pages of its table up
+        # to its last position; else every query row reads its whole
+        # table in the rows form.
         assert page_table is not None
         if mode == "decode":
             cur = jnp.asarray(cur_pos, jnp.int32)
@@ -466,11 +473,28 @@ def attention_sublayer(
                                         interleave_kv(k, v), pos2d,
                                         page_table, active=act, layer=layer)
         Hq, hd = q.shape[2], q.shape[3]
-        out = paged_attention_rows(
-            q.reshape(B * S, Hq, hd), kvp,
-            jnp.repeat(page_table, S, axis=0), pos2d.reshape(B * S),
-            scale=scale, cap=cfg.attn_softcap, window=window, layer=layer,
-        ).reshape(B, S, Hq, hd)
+        if attn_kernel:
+            kv_lens = pos2d[:, -1] + 1
+            if act is not None:
+                # an idle decode row attends nothing and writes nothing:
+                # its output rows are zeroed, not left as the kernel's
+                # unwritten output
+                act = jnp.asarray(act, bool)
+                kv_lens = jnp.where(act, kv_lens, 0)
+            out = ragged_paged_attention(
+                q.reshape(B * S, Hq, hd), kvp, page_table,
+                jnp.arange(B + 1, dtype=jnp.int32) * S, kv_lens,
+                scale=scale, cap=cfg.attn_softcap, window=window,
+                max_q_len=S, layer=layer).reshape(B, S, Hq, hd)
+            if act is not None:
+                out = jnp.where(act[:, None, None, None], out, 0)
+        else:
+            out = paged_attention_rows(
+                q.reshape(B * S, Hq, hd), kvp,
+                jnp.repeat(page_table, S, axis=0), pos2d.reshape(B * S),
+                scale=scale, cap=cfg.attn_softcap, window=window,
+                layer=layer,
+            ).reshape(B, S, Hq, hd)
         out = jnp.einsum("bshk,hkd->bsd", out.astype(q.dtype), p["wo"])
         return out, {"kv_pages": kvp}
     if mode == "decode":

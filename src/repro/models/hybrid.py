@@ -44,6 +44,7 @@ def hybrid_sublayer(
     page_table=None,
     page_tokens=None,
     layer=None,
+    attn_kernel: bool = False,
 ) -> Tuple[jnp.ndarray, Optional[dict]]:
     """Both branches run in every mode (incl. ``extend``: the attention
     half resumes against its ring cache positionally while the SSM half
@@ -56,13 +57,15 @@ def hybrid_sublayer(
     masks both halves' cache writes for inactive rows. With ``page_table``
     the union cache is paged (DESIGN.md §10): KV pages for the attention
     half, conv/state pages for the SSM half, one table for both; ``layer``
-    indexes both pools when they are stacked over layers."""
+    indexes both pools when they are stacked over layers, and
+    ``attn_kernel`` sends the attention half through the ragged kernel."""
     attn_cache = cache["attn"] if cache is not None else None
     ssm_cache = cache["ssm"] if cache is not None else None
     a_out, a_cache = attention_sublayer(
         cfg, p["attn"], x, positions=positions, window=window, sh=sh,
         cache=attn_cache, mode=mode, cur_pos=cur_pos,
-        decode_active=decode_active, page_table=page_table, layer=layer)
+        decode_active=decode_active, page_table=page_table, layer=layer,
+        attn_kernel=attn_kernel)
     s_out, s_cache = ssm_sublayer(cfg, p["ssm"], x, sh=sh, cache=ssm_cache,
                                   mode=mode, decode_active=decode_active,
                                   positions=positions, cur_pos=cur_pos,

@@ -93,13 +93,14 @@ def abstract_params(cfg: ModelConfig, shardings=None):
 def apply_block(cfg: ModelConfig, spec: LayerSpec, p: dict, x, *, positions,
                 sh=None, cache=None, mode="train", cur_pos=None,
                 decode_active=None, page_table=None, page_tokens=None,
-                layer=None):
+                layer=None, attn_kernel=False):
     """Pre-norm residual block. Returns (x, new_cache, aux). With
     ``page_table`` every mixer family computes in place on pooled pages
     (DESIGN.md §10): KV pages for attention/MLA, conv+SSD state pages for
     SSM, both for the hybrid union (``page_tokens`` is the static page
     size the point stacks need to resolve state-page slots; ``layer``
-    indexes a pool stacked over the group's layers)."""
+    indexes a pool stacked over the group's layers; ``attn_kernel``
+    attends KV pages through the ragged kernel)."""
     aux = jnp.zeros((), jnp.float32)
     with jax.named_scope("norm"):
         h = rmsnorm(x, p["norm1"], cfg.norm_eps)
@@ -108,7 +109,7 @@ def apply_block(cfg: ModelConfig, spec: LayerSpec, p: dict, x, *, positions,
                               cache=cache, mode=mode, cur_pos=cur_pos,
                               decode_active=decode_active,
                               page_table=page_table, page_tokens=page_tokens,
-                              layer=layer)
+                              layer=layer, attn_kernel=attn_kernel)
     if cfg.post_norms:
         with jax.named_scope("norm"):
             h = rmsnorm(h, p["post_norm1"], cfg.norm_eps)
@@ -141,14 +142,15 @@ _MIXER_SCOPE = {"attn": "attention", "mla": "attention", "ssm": "ssm",
 
 def _mixer(cfg: ModelConfig, spec: LayerSpec, p: dict, h, *, positions,
            sh, cache, mode, cur_pos, decode_active, page_table, page_tokens,
-           layer):
+           layer, attn_kernel):
     """The block's token mixer. Returns (h, new_cache)."""
     if spec.kind == "attn":
         h, new_cache = attention_sublayer(cfg, p["mixer"], h, positions=positions,
                                           window=spec.window, sh=sh, cache=cache,
                                           mode=mode, cur_pos=cur_pos,
                                           decode_active=decode_active,
-                                          page_table=page_table, layer=layer)
+                                          page_table=page_table, layer=layer,
+                                          attn_kernel=attn_kernel)
     elif spec.kind == "mla":
         h, new_cache = mla_sublayer(cfg, p["mixer"], h, positions=positions, sh=sh,
                                     cache=cache, mode=mode, cur_pos=cur_pos,
@@ -166,7 +168,8 @@ def _mixer(cfg: ModelConfig, spec: LayerSpec, p: dict, h, *, positions,
                                        mode=mode, cur_pos=cur_pos,
                                        decode_active=decode_active,
                                        page_table=page_table,
-                                       page_tokens=page_tokens, layer=layer)
+                                       page_tokens=page_tokens, layer=layer,
+                                       attn_kernel=attn_kernel)
     else:
         raise ValueError(spec.kind)
     return h, new_cache
@@ -279,7 +282,7 @@ def _embed_inputs(cfg: ModelConfig, params, batch: dict, sh=None):
 
 def apply_groups(cfg: ModelConfig, params, x, *, positions, sh=None,
                  caches=None, mode="train", cur_pos=None, decode_active=None,
-                 page_table=None, page_tokens=None):
+                 page_table=None, page_tokens=None, attn_kernel=False):
     """Run every scan group. Returns (x, new_caches, aux_total).
 
     With ``page_table`` (the paged plane) each unit's pool, stacked over
@@ -303,7 +306,8 @@ def apply_groups(cfg: ModelConfig, params, x, *, positions, sh=None,
                         cfg, spec, params_t[u], xx, positions=positions,
                         sh=sh, cache=pools[u], mode=mode, cur_pos=cur_pos,
                         decode_active=decode_active, page_table=page_table,
-                        page_tokens=page_tokens, layer=layer)
+                        page_tokens=page_tokens, layer=layer,
+                        attn_kernel=attn_kernel)
                     outs.append(pool)
                     aux = aux + aux_u
                 return (xx, aux, tuple(outs)), None
@@ -493,13 +497,14 @@ def extend(cfg: ModelConfig, params, caches, tokens, offset, sh=None):
 
 
 def paged_prefill(cfg: ModelConfig, params, batch: dict, caches, page_table,
-                  sh=None, page_tokens=None):
+                  sh=None, page_tokens=None, attn_kernel=False):
     """First chunk on the paged plane: embeds the meta/frontend prefix +
     prompt at absolute positions 0..S-1 and writes KV straight into the
     pool pages named by ``page_table`` (B, W). Unlike ring ``prefill``
     there is no per-slot cache to build — the pool is the cache — so this
     is just ``extend`` from offset 0 with the prefix embedded.
-    Returns (last_logits, caches)."""
+    Returns (last_logits, caches). ``attn_kernel`` (here and in the other
+    paged steps) attends KV pages through the ragged kernel."""
     with jax.named_scope("embed"):
         x, _ = _embed_inputs(cfg, params, batch, sh)
     S_tot = x.shape[1]
@@ -507,12 +512,13 @@ def paged_prefill(cfg: ModelConfig, params, batch: dict, caches, page_table,
     x, new_caches, _ = apply_groups(cfg, params, x, positions=positions,
                                     sh=sh, caches=caches, mode="extend",
                                     page_table=page_table,
-                                    page_tokens=page_tokens)
+                                    page_tokens=page_tokens,
+                                    attn_kernel=attn_kernel)
     return _paged_head(cfg, params, x, -1), new_caches
 
 
 def paged_extend(cfg: ModelConfig, params, caches, tokens, offset, page_table,
-                 sh=None, page_tokens=None):
+                 sh=None, page_tokens=None, attn_kernel=False):
     """Later chunks on the paged plane: ``tokens`` (B, S[, K]) at absolute
     positions ``offset + [0, S)``; earlier context is whatever the pages
     in ``page_table`` hold — including pages spliced in from a radix or
@@ -528,12 +534,14 @@ def paged_extend(cfg: ModelConfig, params, caches, tokens, offset, page_table,
     x, new_caches, _ = apply_groups(cfg, params, x, positions=positions,
                                     sh=sh, caches=caches, mode="extend",
                                     page_table=page_table,
-                                    page_tokens=page_tokens)
+                                    page_tokens=page_tokens,
+                                    attn_kernel=attn_kernel)
     return _paged_head(cfg, params, x, -1), new_caches
 
 
 def paged_decode(cfg: ModelConfig, params, caches, last_tokens, cur_pos,
-                 page_table, sh=None, active=None, page_tokens=None):
+                 page_table, sh=None, active=None, page_tokens=None,
+                 attn_kernel=False):
     """One batched decode step on the paged plane. cur_pos: (B,) absolute
     positions; rows where ``active`` is False neither write their pages
     nor advance (their page-table row may be all null pages)."""
@@ -547,7 +555,8 @@ def paged_decode(cfg: ModelConfig, params, caches, last_tokens, cur_pos,
                                     sh=sh, caches=caches, mode="decode",
                                     cur_pos=cp, decode_active=active,
                                     page_table=page_table,
-                                    page_tokens=page_tokens)
+                                    page_tokens=page_tokens,
+                                    attn_kernel=attn_kernel)
     return _paged_head(cfg, params, x, 0), new_caches
 
 

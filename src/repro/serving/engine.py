@@ -56,6 +56,7 @@ from __future__ import annotations
 import contextlib
 import sys
 import time
+from collections import Counter
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
@@ -65,6 +66,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core.simulator import MemorySystem
+from repro.kernels.paged_attention import ops as attn_kernel_ops
 from repro.models import transformer as tfm
 from repro.models.attention import paged_pages_gathered
 from repro.serving.kv_cache import PagedKVManager
@@ -130,12 +132,6 @@ class EngineConfig:
     # point-state pages (conv + SSD state at page-boundary capture
     # points) drawn from the same free-list.
     paged_kernel: bool = False
-    # paged-attention kernel block shape / DMA pipeline depth overrides
-    # (None = the autotuner's cached best config for this geometry;
-    # kernels/paged_attention/tune.py)
-    kernel_block_q: Optional[int] = None
-    kernel_block_kv: Optional[int] = None
-    kernel_buffers: Optional[int] = None
     # reliability plane (DESIGN.md §11): inject age-driven bit flips into
     # the paged KV/state pages of decoding sessions, anchored so a page
     # exactly at its programmed retention sees this RBER (None = off).
@@ -301,11 +297,14 @@ class ComputeBackend:
         self.pool_grows = 0
         self.pool_copies = 0
         # per paged program kind: KV pages the served attention gathers,
-        # and the pages its sequences' keys occupy (DESIGN.md §3)
+        # the pages its sequences' keys occupy, and the ragged-kernel
+        # calls (one per kernel layer per launch) (DESIGN.md §3)
         self.kv_pages_gathered = {k: 0 for k in self.builds}
         self.kv_pages_attended = {k: 0 for k in self.builds}
-        self._attn_layers = sum(s.kind in ("attn", "mla", "hybrid")
-                                for s in cfg.layer_specs())
+        self.attention_kernel_launches = {k: 0 for k in self.builds}
+        self.attn_kernel = False
+        self._attn_specs = [s for s in cfg.layer_specs()
+                            if s.kind in ("attn", "mla", "hybrid")]
         if paged:
             # paged compute plane (DESIGN.md §10): one pooled page array per
             # cache family, owned here, indexed by PagedKVManager pages via
@@ -317,6 +316,7 @@ class ComputeBackend:
             self.page_tokens = ecfg.page_tokens
             n0 = max(16, 1 + B * -(-ecfg.max_cache_len // ecfg.page_tokens))
             device = next(iter(jax.tree.leaves(params)[0].devices()))
+            self.attn_kernel = self.kernel_serves(cfg, device.platform)
             self.paged_caches = jax.device_put(tfm.init_paged_caches(
                 cfg, n0, ecfg.page_tokens, jnp.dtype(cfg.dtype)), device)
             self._free = list(range(n0 - 1, 0, -1))  # pop() -> lowest id
@@ -459,58 +459,102 @@ class ComputeBackend:
                             jax.tree.leaves(self.paged_caches)))
 
     @staticmethod
-    def paged_program(cfg: ModelConfig, kind: str, page_tokens: int):
+    def kernel_serves(cfg: ModelConfig, platform: str) -> bool:
+        """Whether the paged steps attend KV pages through the ragged
+        kernel (DESIGN.md §10): where the kernel says it serves this
+        page geometry on this platform (``ops.serves``: a TPU, and a
+        geometry its compiler takes); else the rows form. Latent (MLA)
+        pages always take the rows form."""
+        return any(s.kind in ("attn", "hybrid")
+                   for s in cfg.layer_specs()) and attn_kernel_ops.serves(
+            2 * cfg.n_kv_heads, cfg.resolved_head_dim, platform)
+
+    @staticmethod
+    def paged_program(cfg: ModelConfig, kind: str, page_tokens: int,
+                      attn_kernel: bool = False):
         """The jitted paged step program of ``kind`` ("first", "extend" or
         "decode") as the backend launches it: ``(params, pool, ...) ->
         (logits, pool)``. The pool argument is donated, so the program
-        owns it and updates it in place (DESIGN.md §10)."""
-        pt = page_tokens
+        owns it and updates it in place (DESIGN.md §10). ``attn_kernel``
+        (``kernel_serves``) attends through the ragged kernel."""
+        kw = dict(page_tokens=page_tokens, attn_kernel=attn_kernel)
         if kind == "first":
             fn = lambda p, c, batch, tbl: tfm.paged_prefill(
-                cfg, p, batch, c, tbl, page_tokens=pt)
+                cfg, p, batch, c, tbl, **kw)
         elif kind == "extend":
             fn = lambda p, c, t, off, tbl: tfm.paged_extend(
-                cfg, p, c, t, off, tbl, page_tokens=pt)
+                cfg, p, c, t, off, tbl, **kw)
         else:
             fn = lambda p, c, t, pos, tbl, act: tfm.paged_decode(
-                cfg, p, c, t, pos, tbl, active=act, page_tokens=pt)
+                cfg, p, c, t, pos, tbl, active=act, **kw)
         return jax.jit(fn, donate_argnums=1)
 
     def _paged_first_fn(self, length: int, W: int):
         key = (length, W)
         if key not in self._paged_first_jit:
             self._paged_first_jit[key] = self.paged_program(
-                self.cfg, "first", self.page_tokens)
+                self.cfg, "first", self.page_tokens, self.attn_kernel)
         return self._paged_first_jit[key]
 
     def _paged_extend_fn(self, length: int, W: int):
         key = (length, W)
         if key not in self._paged_extend_jit:
             self._paged_extend_jit[key] = self.paged_program(
-                self.cfg, "extend", self.page_tokens)
+                self.cfg, "extend", self.page_tokens, self.attn_kernel)
         return self._paged_extend_jit[key]
 
     def _paged_decode_fn(self, W: int):
         if W not in self._paged_decode_jit:
             self._paged_decode_jit[W] = self.paged_program(
-                self.cfg, "decode", self.page_tokens)
+                self.cfg, "decode", self.page_tokens, self.attn_kernel)
         return self._paged_decode_jit[W]
 
-    def _count_kv_pages(self, sp, kind: str, rows: int, width: int,
-                        keys) -> None:
+    def _launch_table(self, tables) -> np.ndarray:
+        """Page tables as the step programs take them. On the kernel path
+        they are padded with null slots to a multiple of
+        ``ops.TABLE_QUANTUM``, so that launches of every narrower width
+        share one program: the kernel reads no slot past a sequence's
+        keys. The rows form reads every slot, so there a table keeps its
+        power-of-2 width."""
+        tables = np.asarray(tables, np.int32)
+        if not self.attn_kernel or any(s.kind == "mla"
+                                       for s in self._attn_specs):
+            return tables
+        pad = -tables.shape[-1] % attn_kernel_ops.TABLE_QUANTUM
+        return np.pad(tables, [(0, 0)] * (tables.ndim - 1) + [(0, pad)])
+
+    def _count_kv_pages(self, sp, kind: str, tables: np.ndarray,
+                        q_len: int, keys: np.ndarray) -> None:
         """Count, on the host, the KV pages a paged launch's attention
-        gathers for ``rows`` query rows over a ``width``-slot table
-        (``paged_pages_gathered``) against the pages its sequences' keys
-        occupy, each read once (``keys``: keys per sequence after the
-        launch), over the attention layers; the launch's span ``sp``
-        carries both."""
-        L, pt = self._attn_layers, self.page_tokens
-        gathered = paged_pages_gathered(rows, width) * L
-        attended = sum(-(-int(k) // pt) for k in keys) * L
+        gathers against the pages its sequences' keys occupy, each read
+        once, over the attention layers; the launch's span ``sp`` carries
+        both, and the path that attended. ``tables`` is the launch's
+        (R, W) page table, ``q_len`` the query rows of each of its R
+        sequences, ``keys`` (R,) each sequence's keys after the launch (0
+        for an idle decode row). The rows form reads R·q_len·W pages per
+        layer (``paged_pages_gathered``); the kernel reads the grid's page
+        DMAs (``ops.launch_pages``), on every attention layer but latent
+        (MLA) ones, which keep the rows form."""
+        pt, (R, W), specs = self.page_tokens, tables.shape, self._attn_specs
+        # kernel-attended layers by window
+        kernel = Counter(s.window for s in specs
+                         if self.attn_kernel and s.kind != "mla")
+        kernel_layers = sum(kernel.values())
+        gathered = paged_pages_gathered(R * q_len, W) * (len(specs)
+                                                         - kernel_layers)
+        if kernel_layers:
+            cu = np.arange(R + 1) * q_len
+            gathered += sum(n * attn_kernel_ops.launch_pages(
+                tables, cu, keys, page_size=pt,
+                head_dim=self.cfg.resolved_head_dim, max_q_len=q_len,
+                window=w) for w, n in kernel.items())
+            self.attention_kernel_launches[kind] += kernel_layers
+        attended = int(np.sum(-(-np.asarray(keys) // pt))) * len(specs)
         self.kv_pages_gathered[kind] += gathered
         self.kv_pages_attended[kind] += attended
         sp.attrs.update(kv_pages_gathered=gathered,
-                        kv_pages_attended=attended)
+                        kv_pages_attended=attended,
+                        path="kernel" if kernel_layers else "rows")
 
     def _launch(self, program, *args):
         """Run a paged step program on the pool and rebind the pool to its
@@ -588,9 +632,12 @@ class ComputeBackend:
                     # prefix; the chunk's keys end where its tokens do
                     keys = ck.offset + toks.shape[0]
                     self._count_kv_pages(
-                        sp, kind, keys if ck.first else toks.shape[0], W,
-                        [keys])
-                    tbl = jnp.asarray(page_table, jnp.int32)[None]
+                        sp, kind, np.asarray(page_table)[None],
+                        keys if ck.first else toks.shape[0],
+                        np.array([keys]))
+                    tbl = jnp.asarray(self._launch_table(page_table),
+                                      jnp.int32)[None]
+                    W = tbl.shape[-1]
                     tok_d = jnp.asarray(toks)[None]
                     if ck.first:
                         batch = {"tokens": tok_d}
@@ -657,14 +704,15 @@ class ComputeBackend:
                     before = [np.asarray(leaf[:, idx])
                               for leaf in jax.tree.leaves(self.paged_caches)]
                 with span("backend.inputs"):
-                    # every one of the B rows gathers its table; an
-                    # active row attends keys 0..position + 1
+                    # an active row attends keys 0..position + 1
                     self._count_kv_pages(
-                        sp, "decode", B, W,
-                        [self.positions[s] + 2 for s in slots])
+                        sp, "decode", np.asarray(page_tables), 1,
+                        np.where(act, self.positions + 2, 0))
                     pos = jnp.asarray(np.maximum(self.positions + 1, 0),
                                       jnp.int32)
-                    tbl = jnp.asarray(page_tables, jnp.int32)
+                    tbl = jnp.asarray(self._launch_table(page_tables),
+                                      jnp.int32)
+                    W = tbl.shape[-1]
                     last = jnp.asarray(self.last_tokens)
                     act_d = jnp.asarray(act)
                 with span("backend.launch"), \
@@ -866,21 +914,6 @@ class ServeEngine:
         # at every page boundary — so a radix or migrated hit is a
         # page-table splice for all four families
         self.paged = bool(ecfg.paged_kernel) and tfm.supports_extend(cfg)
-        if (ecfg.kernel_block_q or ecfg.kernel_block_kv
-                or ecfg.kernel_buffers):
-            # pin the Pallas launch config for this page geometry: the
-            # explicit overrides land in the autotuner's config cache,
-            # which every ragged_paged_attention launch consults
-            from repro.kernels.paged_attention.tune import (KernelConfig,
-                                                            best_config,
-                                                            set_config)
-            base = best_config(ecfg.page_tokens, cfg.resolved_head_dim)
-            set_config(ecfg.page_tokens, cfg.resolved_head_dim,
-                       KernelConfig(
-                           block_q=ecfg.kernel_block_q or base.block_q,
-                           block_kv=ecfg.kernel_block_kv or base.block_kv,
-                           num_buffers=(ecfg.kernel_buffers
-                                        or base.num_buffers)))
         self.sched = ContinuousBatchScheduler(ecfg.max_slots,
                                               ecfg.max_prefills_per_step)
         self.backend = ComputeBackend(cfg, params, ecfg, paged=self.paged)
@@ -1832,7 +1865,9 @@ class ServeEngine:
                "pool_pages": be.pool_pages(),
                "pool_copies": be.pool_copies,
                "kv_pages_gathered": dict(be.kv_pages_gathered),
-               "kv_pages_attended": dict(be.kv_pages_attended)}
+               "kv_pages_attended": dict(be.kv_pages_attended),
+               "attention_kernel_launches": dict(
+                   be.attention_kernel_launches)}
         out.update(recorder().totals())
         return out
 

@@ -66,13 +66,14 @@ def test_pallas_matches_reference(ps, q_lens, kv_lens, cap, window):
 
 
 def test_pallas_bit_exact_fp32():
-    """fp32 interpret-mode lowering reduces in the same page order as the
-    reference scan — outputs are bit-identical, the property the serving
-    hit-vs-cold guarantee stands on."""
+    """With one page per block (``block_kv=1``) the fp32 interpret-mode
+    lowering reduces in the same page order as the reference scan —
+    outputs are bit-identical. Wider blocks sum a block's pages at once
+    (``test_pallas_matches_reference``)."""
     q, kvp, tbl, cu, kl = _case([5, 1, 9], [37, 12, 9], 16, 4, 2, 16)
     out = ragged_paged_attention(q, kvp, tbl, cu, kl, scale=0.25,
                                  max_q_len=9, backend="pallas",
-                                 interpret=True)
+                                 block_kv=1, interpret=True)
     ref = ragged_paged_attention_ref(q, kvp, tbl, cu, kl, scale=0.25)
     assert np.array_equal(np.asarray(out), np.asarray(ref))
 
@@ -103,15 +104,16 @@ def test_table_padding_bit_invariance():
 ])
 def test_grouped_grid_sparse_table_parity(ps, q_lens, kv_lens):
     """Sparse page tables (interior null slots): the grouped grid skips
-    null page blocks without a gather, the ungrouped baseline pulls and
-    masks them in-register, the reference masks by page id. The grouped
-    and ungrouped grids run the same per-page code, so they agree
-    bit-for-bit in fp32. Against the reference they agree to a few ulp:
-    the kernel's per-page score and value dots may sum their ``ps`` terms
-    in another order than the reference's page scan (the CPU backend does
-    so at ps=32), which moves the fp32 result by rounding only. The
-    host-side gather replica confirms the grouped grid reads strictly
-    fewer pages."""
+    null pages without a gather, the ungrouped baseline pulls and masks
+    them in-register, the reference masks by page id. With one page per
+    block both grids reduce page by page, so they agree bit-for-bit in
+    fp32 whatever the query tile and pipeline depth. Wider blocks agree
+    bit-for-bit across query tiles and depths at the same block width.
+    Against the reference every grid agrees to a few ulp: the kernel's
+    score and value dots may sum their terms in another order than the
+    reference's page scan, and a block sums its pages at once, which
+    moves the fp32 result by rounding only. The host-side gather replica
+    confirms the grouped grid reads strictly fewer pages."""
     from repro.kernels.paged_attention.kernel import pages_gathered
     q, kvp, tbl, cu, kl = _case(q_lens, kv_lens, ps, Hq=4, Hkv=2, D=16)
     tbl = np.array(tbl)
@@ -125,13 +127,20 @@ def test_grouped_grid_sparse_table_parity(ps, q_lens, kv_lens):
     atol = 4 * float(np.spacing(np.abs(ref).max()))
     base = np.asarray(ragged_paged_attention(
         q, kvp, tbl, cu, kl, backend="pallas", interpret=True,
-        skip_blocks=False, **kw))
+        skip_blocks=False, block_kv=1, **kw))
     np.testing.assert_allclose(base, ref, rtol=0, atol=atol)
+
+    def grouped(bq, bkv, nb):
+        return np.asarray(ragged_paged_attention(
+            q, kvp, tbl, cu, kl, backend="pallas", interpret=True,
+            block_q=bq, block_kv=bkv, num_buffers=nb, **kw))
+
+    for bq, nb in [(4, 2), (8, 3), (64, 4)]:
+        assert np.array_equal(grouped(bq, 1, nb), base)
     for bq, bkv, nb in [(4, 2, 2), (8, 4, 3), (64, 64, 4)]:
-        out = ragged_paged_attention(q, kvp, tbl, cu, kl, backend="pallas",
-                                     interpret=True, block_q=bq,
-                                     block_kv=bkv, num_buffers=nb, **kw)
-        assert np.array_equal(np.asarray(out), base)
+        out = grouped(bq, bkv, nb)
+        np.testing.assert_allclose(out, ref, rtol=0, atol=atol)
+        assert np.array_equal(grouped(2 * bq, bkv, 6 - nb), out)
     grouped = pages_gathered(tbl, cu, kl, page_size=ps,
                              max_q_len=kw["max_q_len"])
     full = pages_gathered(tbl, cu, kl, page_size=ps,
@@ -140,17 +149,18 @@ def test_grouped_grid_sparse_table_parity(ps, q_lens, kv_lens):
 
 
 def test_kernel_config_resolution():
-    """Explicit block/buffer overrides win over the tuned cache and are
-    clamped to the launch shape."""
+    """Explicit block/buffer overrides win over the tuned cache; the
+    query tile is clamped to the launch's rows and the depth to 2-4, and
+    the page block is left as it is whatever the table width."""
     from repro.kernels.paged_attention.tune import (KernelConfig,
                                                     best_config,
                                                     resolve_config,
                                                     set_config)
     set_config(64, 48, KernelConfig(block_q=32, block_kv=16, num_buffers=4))
     assert best_config(64, 48) == KernelConfig(32, 16, 4)
-    eff = resolve_config(64, 48, max_q_len=5, table_width=3)
-    assert eff == KernelConfig(block_q=5, block_kv=3, num_buffers=4)
-    eff = resolve_config(64, 48, max_q_len=100, table_width=100,
+    eff = resolve_config(64, 48, max_q_len=5)
+    assert eff == KernelConfig(block_q=5, block_kv=16, num_buffers=4)
+    eff = resolve_config(64, 48, max_q_len=100,
                          block_q=8, block_kv=2, num_buffers=9)
     assert eff == KernelConfig(block_q=8, block_kv=2, num_buffers=4)
 

@@ -27,10 +27,10 @@ def family(request):
     return cfg, init_params(cfg, jax.random.key(0))
 
 
-def _undonated(cfg, kind, page_tokens):
+def _undonated(cfg, kind, page_tokens, attn_kernel=False):
     """The backend's step program, jitted without donating the pool."""
-    return jax.jit(ComputeBackend.paged_program(cfg, kind,
-                                                page_tokens).__wrapped__)
+    return jax.jit(ComputeBackend.paged_program(
+        cfg, kind, page_tokens, attn_kernel).__wrapped__)
 
 
 def _backend(cfg, params, donate=True):

@@ -279,7 +279,8 @@ def test_report_carries_the_telemetry_counters(setup):
     tel = eng.report()["telemetry"]
     assert set(tel) == {"builds", "pool_grows", "pool_pages", "pool_copies",
                         "kv_pages_gathered", "kv_pages_attended",
-                        "spans_dropped", "jax_compiles", "jax_compile_s"}
+                        "attention_kernel_launches", "spans_dropped",
+                        "jax_compiles", "jax_compile_s"}
     assert tel["spans_dropped"] == recorder().dropped
     assert tel["jax_compiles"] == recorder().jax_compiles
 
@@ -315,6 +316,53 @@ def test_kv_page_counts_match_the_hand_reckoning():
         for key in ("kv_pages_gathered", "kv_pages_attended"):
             assert sum(a[key] for a in mine) == sum(tel[key][k]
                                                     for k in kinds)
+        assert {a["path"] for a in mine} == {"rows"}
+    assert tel["attention_kernel_launches"] == {"first": 0, "extend": 0,
+                                                "decode": 0}
+
+
+def test_kv_page_counts_on_the_kernel_path():
+    """The same engine and requests with the paged steps attending
+    through the ragged kernel (interpret mode here), as they do on the
+    chip: each launch counts the grid's page DMAs
+    (``kernel.pages_gathered``), so with every table slot live up to the
+    keys and one query tile per chunk the gathered pages equal the
+    attended ones; the idle decode rows gather nothing. One kernel call
+    per attention layer per launch; the tokens are the rows form's."""
+    from repro.kernels.paged_attention.kernel import pages_gathered
+
+    full = get_config("deepseek-7b")
+    cfg = reduced(full)
+    params = init_params(cfg, jax.random.key(1))
+    L = cfg.num_layers
+    outs = []
+    for kernel in (True, False):
+        eng = _engine((full, cfg, params))
+        eng.backend.attn_kernel = kernel
+        t0 = time.perf_counter()
+        rid = eng.submit(_prompts(10, 1, length=20)[0], 8)
+        while eng.backend.kv_pages_gathered["decode"] == 0:
+            eng.step()
+        if kernel:
+            tel = eng.report()["telemetry"]
+            spans = recorder().spans(lo=t0)
+        while not eng.sched.idle:
+            eng.step()
+        outs.append(list(eng.outputs[rid]))
+    assert tel["kv_pages_gathered"] == {"first": 2 * L, "extend": 3 * L,
+                                        "decode": 3 * L}
+    assert tel["kv_pages_gathered"] == tel["kv_pages_attended"]
+    assert tel["attention_kernel_launches"] == {"first": L, "extend": L,
+                                                "decode": L}
+    # the decode round by hand: the one live row's 3 pages of 21 keys in
+    # a 4-slot table, three idle rows
+    tbl = np.zeros((4, 4), np.int32)
+    tbl[0, :3] = [1, 2, 3]
+    assert pages_gathered(tbl, np.arange(5), [21, 0, 0, 0], page_size=8,
+                          max_q_len=1) == 3
+    assert {s.attrs["path"] for s in spans
+            if s.name in ("backend.prefill", "backend.decode")} == {"kernel"}
+    assert outs[0] == outs[1] and len(outs[0]) == 8
 
 
 # ---------------------------------------------------------------------------
@@ -359,3 +407,20 @@ def test_spans_lie_on_the_profilers_host_plane(setup, tmp_path):
         assert len(evs) == len(recs), name
         for d_mem, d_trace in zip(recs, evs):
             assert abs(d_trace - d_mem) <= 1e-3 + 0.1 * d_mem, name
+
+
+@pytest.mark.parametrize("kernel,builds", [(True, 1), (False, 2)],
+                         ids=["kernel", "rows"])
+def test_kernel_path_shares_one_program_across_table_widths(kernel, builds):
+    """Decode rounds over page tables 4 and 8 slots wide: on the kernel
+    path both run one program (tables reach it padded to the kernel's
+    width quantum, and the kernel reads no slot past a sequence's keys);
+    the rows form reads every slot, so each width builds its own."""
+    full = get_config("deepseek-7b")
+    cfg = reduced(full)
+    eng = _engine((full, cfg, init_params(cfg, jax.random.key(1))))
+    eng.backend.attn_kernel = kernel
+    for width in (4, 8):
+        eng.backend.run_decode([], page_tables=np.zeros((4, width),
+                                                        np.int32))
+    assert eng.report()["telemetry"]["builds"]["decode"]["count"] == builds
