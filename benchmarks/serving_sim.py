@@ -11,9 +11,9 @@
    system prompts, RAG fan-out) with the radix prefix tree on vs off —
    reuse must cut prefill tokens computed by >= 30% at equal (identical)
    output tokens, and the hit rate / tokens reused / TTFT land in the
-   JSON trajectory. Runs once per snapshot family (DESIGN.md §8):
-   attention (deepseek-7b), SSM point snapshots (mamba2-2.7b) and the
-   hybrid union (hymba-1.5b); stacks with a KV write stream must also cut
+   JSON trajectory. Runs once per resumption kind (DESIGN.md §8):
+   attention (deepseek-7b), SSM state pages (mamba2-2.7b) and the hybrid
+   union (hymba-1.5b); stacks with a KV write stream must also cut
    KV-tier write bytes >= 30%.
 4. Tail-reuse sweep (DESIGN.md §9): shared prefixes whose length
    straddles a page boundary, tail-copy on vs the page-aligned matcher —
@@ -26,7 +26,7 @@
    cross-replica hit rate > 0, a >= 20% fleet prefill-token cut at
    identical decoded tokens, non-zero metered interconnect traffic, and
    zero pressure-ledger imbalance. Runs for an attention stack and an SSM
-   stack (the latter transfers a *point* state snapshot over the wire).
+   stack (the latter ships recurrent-state pages over the wire).
 6. Reliability sweep (DESIGN.md §11; suite ``reliability``, trajectory in
    ``BENCH_reliability.json``): fault injection over the paged plane at a
    target RBER, three arms on identical prompts — clean (no injection),
@@ -81,15 +81,14 @@ def prefix_workloads(rng, vocab: int, n_users: int = 3, turns: int = 2,
 def prefix_reuse(arch="deepseek-7b", **workload_kw) -> dict:
     """Radix prefix reuse on shared-prefix traffic vs prefix_caching=False:
     identical decoded tokens and >= 30% fewer prefill tokens computed —
-    for *every* snapshot family (attention ring caches, MLA latent pages,
-    SSM/hybrid point snapshots; DESIGN.md §8). Stacks with a real KV
+    for *every* resumption kind (attention KV pages, MLA latent pages,
+    SSM/hybrid state pages; DESIGN.md §8). Stacks with a real KV
     write stream must also cut KV-tier write bytes >= 30%; a pure-SSM
     stack appends no per-token KV, so that axis is reported as None.
 
-    ``workload_kw`` scales :func:`prefix_workloads` — point-snapshot
-    stacks pay a one-time capture recompute per observed share boundary
-    (§8), so their savings amortize over fan-out depth where positional
-    stacks save from the second request on."""
+    ``workload_kw`` scales :func:`prefix_workloads` — point stacks
+    resume only at page boundaries (§8), so their savings grow with
+    fan-out depth where positional stacks save every shared token."""
     from repro.configs import get_config, reduced
     from repro.core.memclass import HBM3E, MRM_RRAM
     from repro.core.simulator import MemorySystem
@@ -239,106 +238,6 @@ def tail_reuse(arch="deepseek-7b", page_tokens=16, head_tokens=56,
     }
 
 
-def paged_kernel(arch="deepseek-7b", n_shares=4, head_tokens=48,
-                 ask_tokens=12) -> dict:
-    """Paged compute plane vs the ring path (DESIGN.md §10) on shared-
-    prefix fan-out traffic: the same prompts, decoded greedily in fp32,
-    with ``paged_kernel`` on vs off. The plane is universal (ISSUE 7):
-    attention/MLA serve on KV pages, SSM/hybrid on pooled point-state
-    pages — there is no ring fallback for any family. Asserts:
-
-    - prefix-hit decode on the paged plane is **bit-identical** to a
-      cold paged start, and to the ring plane whenever no sliding window
-      wraps the ring buffer (a wrapped window sums the same values in
-      rotated order — fp32 accumulation order is layout-specific there,
-      so the cross-plane comparison is decoded-token *counts* only);
-    - the paged engine really is paged (``ring_fallbacks == 0`` in the
-      result — the smoke gate for recurrent stacks);
-    - the paged plane's prefix-hit copy bytes are exactly **zero** (no
-      donor-seed cache-tree copy, no published snapshot) while the ring
-      plane pays ``seed_copy_bytes > 0`` per hit (the PR 5 comparator);
-    - the KV tier's metered read bytes equal the kernel's page-gather
-      byte count exactly (tail copies disabled for a clean identity).
-    """
-    from repro.configs import get_config, reduced
-    from repro.core.memclass import HBM3E, MRM_RRAM
-    from repro.core.simulator import MemorySystem
-    from repro.models import init_params
-    from repro.serving import EngineConfig, ServeEngine
-
-    full = get_config(arch)
-    cfg = reduced(full, dtype="float32", param_dtype="float32")
-    params = init_params(cfg, jax.random.key(0))
-    rng = np.random.default_rng(0)
-    head = list(rng.integers(2, cfg.vocab_size, head_tokens))
-    prompts = [head + list(rng.integers(2, cfg.vocab_size, ask_tokens))
-               for _ in range(n_shares)]
-
-    def run_one(paged: bool, prefix_caching: bool = True):
-        mem = MemorySystem({"mrm": (MRM_RRAM, 1 << 40),
-                            "hbm": (HBM3E, 1 << 37)})
-        eng = ServeEngine(cfg, params, mem,
-                          EngineConfig(max_slots=2, max_cache_len=96,
-                                       weight_tier="hbm", kv_tier="mrm",
-                                       eos_token=-1, chunk_tokens=16,
-                                       page_tokens=16, tail_copy=False,
-                                       paged_kernel=paged,
-                                       prefix_caching=prefix_caching,
-                                       radix_hot_threshold=2),
-                          account_cfg=full)
-        for p in prompts:   # sequential: every later prompt can hit
-            eng.submit(list(p), 6)
-            eng.run_until_idle()
-        return eng, eng.report()
-
-    eng_p, on = run_one(True)
-    eng_r, off = run_one(False)
-    eng_c, _cold = run_one(True, prefix_caching=False)
-    outs_p = {k: list(v) for k, v in eng_p.outputs.items()}
-    outs_r = {k: list(v) for k, v in eng_r.outputs.items()}
-    outs_c = {k: list(v) for k, v in eng_c.outputs.items()}
-    assert outs_p == outs_c, "paged prefix hit changed decoded tokens"
-    specs = cfg.layer_specs() if callable(cfg.layer_specs) \
-        else cfg.layer_specs
-    if not any(s.window for s in specs):
-        assert outs_p == outs_r, "paged plane changed decoded tokens"
-    assert on["tokens_generated"] == off["tokens_generated"]
-    assert eng_p.paged and eng_p.backend.paged, \
-        f"{arch}: paged_kernel=on must not fall back to the ring path"
-    assert on["prefix"]["compute_hits"] >= n_shares - 1
-    # the zero-copy-hit invariant (and the PR 5 comparator on the ring)
-    assert on["seed_copy_bytes"] == 0.0, on["seed_copy_bytes"]
-    assert on["snapshot_bytes"] == 0.0, on["snapshot_bytes"]
-    assert off["seed_copy_bytes"] > 0, off["seed_copy_bytes"]
-    # per-tier metering identity: weights stream from hbm, so every KV
-    # tier byte read is the kernel's page gather — no synthetic traffic
-    kernel_reads = on["kernel_read_bytes"]
-    mrm_reads = eng_p.mem.devices["mrm"].stats.read_bytes
-    assert kernel_reads > 0 and abs(mrm_reads - kernel_reads) < 1e-6, \
-        (mrm_reads, kernel_reads)
-    per_tier_reads = {t: d.stats.read_bytes
-                      for t, d in eng_p.mem.devices.items()}
-    return {
-        "arch": arch,
-        "requests": len(prompts),
-        "ring_fallbacks": 0,
-        "state_bytes_page": eng_p.kv.state_bytes_page,
-        "compute_hits": on["prefix"]["compute_hits"],
-        "seed_copy_bytes": on["seed_copy_bytes"],
-        "seed_copy_bytes_ring": off["seed_copy_bytes"],
-        "snapshot_bytes": on["snapshot_bytes"],
-        "snapshot_bytes_ring": off["snapshot_bytes"],
-        "kernel_read_bytes": kernel_reads,
-        "read_bytes_by_tier": per_tier_reads,  # hbm = weight stream
-        "kv_tier_read_bytes": mrm_reads,
-        "prefill_tokens_computed": on["prefill_tokens_computed"],
-        "prefill_tokens_computed_ring": off["prefill_tokens_computed"],
-        "tokens_generated": on["tokens_generated"],
-        "ttft_p50_s": on["latency"]["ttft_p50"],
-        "ttft_p50_ring_s": off["latency"]["ttft_p50"],
-    }
-
-
 def compute(arch="deepseek-7b") -> dict:
     from repro.configs import get_config, reduced
     from repro.core.memclass import HBM3E, MRM_RRAM
@@ -444,11 +343,9 @@ def fleet_reuse(arch="deepseek-7b", replicas=3, fanout=12,
     (no fleet awareness: sticky/least-loaded routing, per-replica radix
     trees) recomputes it cold on every replica it lands on.
 
-    ``seed_tail_tokens=0`` makes the seed prompt exactly the shared head —
-    the shape that exercises *point*-snapshot transfer for SSM/hybrid
-    stacks (their state snapshot is only valid at the exact boundary the
-    fan-out matches, DESIGN.md §8; a divergent seed tail would leave the
-    boundary snapshot to the first borrower instead of the seed)."""
+    ``seed_tail_tokens=0`` makes the seed prompt exactly the shared head,
+    the shape that ships SSM/hybrid state pages for the boundary the
+    fan-out matches (DESIGN.md §8)."""
     from repro.configs import get_config, reduced
     from repro.core.memclass import HBM3E, MRM_RRAM
     from repro.core.simulator import MemorySystem
@@ -527,7 +424,6 @@ def fleet_reuse(arch="deepseek-7b", replicas=3, fanout=12,
         "migrated_tokens": inter["migrated_tokens"],
         "migration_bytes": inter["migration_bytes"],
         "migration_s": inter["migration_s"],
-        "snapshot_bytes": on["snapshot_bytes"],
         "directory_entries": on["directory"]["entries"],
         "ledger_imbalance": ledger_imbalance,
         "dropped_allocs": on["dropped_allocs"],
@@ -584,7 +480,6 @@ def reliability(arch="deepseek-7b", rber=1e-3, n_shares=3, head_tokens=32,
                                        weight_tier="hbm", kv_tier="mrm",
                                        eos_token=-1, chunk_tokens=16,
                                        page_tokens=16, tail_copy=False,
-                                       paged_kernel=True,
                                        expected_session_s=session_s,
                                        inject_rber=inject, inject_seed=0),
                           account_cfg=full)
@@ -797,22 +692,10 @@ def run_replication(csv=True):
     return out
 
 
-def _persist_paged_trajectory(entry: dict) -> None:
-    """Append the paged_kernel sweep result to BENCH_paged.json at the
-    repo root — the benchmark trajectory file CI and later sessions diff
-    against (acceptance: seed_copy_bytes stays 0 while the ring
-    comparator stays > 0). The sweep is deterministic, so re-runs of the
-    same code produce identical metrics: an entry whose metric fields
-    match the last persisted entry (for the same arch) is dropped instead
-    of appended — ``at`` is tiebreak metadata, not a metric, and without
-    the dedupe every CI run grew the file by one duplicate row."""
-    _persist_trajectory("BENCH_paged.json", entry)
-
-
 def _persist_reliability_trajectory(entry: dict) -> None:
     """Append the reliability sweep result to BENCH_reliability.json —
     the CI artifact tracking decode-match / scrub / uncorrectable metrics
-    run over run (same dedupe rule as the paged trajectory)."""
+    run over run (deduped on identical metrics)."""
     _persist_trajectory("BENCH_reliability.json", entry)
 
 
@@ -863,10 +746,10 @@ def run(csv=True):
             print(f"serving_sim/{k}_fleet_tokens_per_s,{dt:.1f},{v['fleet_tokens_per_s']:.4f}")
             print(f"serving_sim/{k}_pressure_events,{dt:.1f},{v['pressure_events']}")
             print(f"serving_sim/{k}_dropped_allocs,{dt:.1f},{v['dropped_allocs']}")
-    # prefix reuse must be real compute savings for EVERY snapshot family
-    # (ISSUE 4): attention ring caches, SSM point snapshots, hybrid union
-    # the hybrid sweep runs a denser fan-out: a point-snapshot stack pays
-    # one capture recompute per observed boundary before borrowers save
+    # prefix reuse must be real compute savings for every resumption
+    # kind: attention KV pages, SSM state pages, the hybrid union; the
+    # hybrid sweep runs a denser fan-out: a point stack resumes only at
+    # page boundaries
     for key, reuse_arch, wkw in (
             ("prefix_reuse", "deepseek-7b", {}),
             ("prefix_reuse_ssm", "mamba2-2.7b", {}),
@@ -884,32 +767,6 @@ def run(csv=True):
             if reuse["kv_write_cut"] is not None:
                 print(f"serving_sim/{tag}_kv_write_cut,{dt:.1f},{reuse['kv_write_cut']:.4f}")
             print(f"serving_sim/{tag}_ttft_p50_s,{dt:.1f},{reuse['ttft_p50_s']:.6f}")
-    # paged compute plane (DESIGN.md §10), now universal (ISSUE 7):
-    # zero-copy hits, bit-identical tokens, the KV-tier read stream ==
-    # the kernel's page gathers, and zero ring fallbacks for the
-    # recurrent families; trajectory persists to BENCH_paged.json
-    for key, paged_arch in (("paged_kernel", "deepseek-7b"),
-                            ("paged_kernel_ssm", "mamba2-2.7b"),
-                            ("paged_kernel_hybrid", "hymba-1.5b")):
-        t0 = time.perf_counter()
-        paged = paged_kernel(paged_arch)
-        dt = (time.perf_counter() - t0) * 1e6
-        out[key] = paged
-        _persist_paged_trajectory(paged)
-        if csv:
-            tag = key.replace("paged_kernel", "paged")
-            print(f"serving_sim/{tag}_seed_copy_bytes,{dt:.1f},"
-                  f"{paged['seed_copy_bytes']:.0f}")
-            print(f"serving_sim/{tag}_seed_copy_bytes_ring,{dt:.1f},"
-                  f"{paged['seed_copy_bytes_ring']:.0f}")
-            print(f"serving_sim/{tag}_kernel_read_gb,{dt:.1f},"
-                  f"{paged['kernel_read_bytes'] / 1e9:.4f}")
-            print(f"serving_sim/{tag}_ring_fallbacks,{dt:.1f},"
-                  f"{paged['ring_fallbacks']}")
-            print(f"serving_sim/{tag}_compute_hits,{dt:.1f},"
-                  f"{paged['compute_hits']}")
-            print(f"serving_sim/{tag}_ttft_p50_s,{dt:.1f},"
-                  f"{paged['ttft_p50_s']:.6f}")
     # sub-page tails: boundary-straddling prefixes must beat the
     # page-aligned cut strictly (DESIGN.md §9)
     t0 = time.perf_counter()

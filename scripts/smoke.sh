@@ -22,32 +22,13 @@ rep = json.load(open("/tmp/smoke_serving.json"))
 assert not rep["failures"], rep["failures"]
 fleet = rep["suites"]["serving"]["replicas_2"]
 assert fleet["dropped_allocs"] == 0, fleet
-# prefix reuse must be real compute savings for EVERY snapshot family
-# (DESIGN.md §8): attention ring caches, SSM point snapshots, hybrid union
+# prefix reuse must be real compute savings for EVERY resumption kind
+# (DESIGN.md §8): attention KV pages, SSM state pages, hybrid union
 for key in ("prefix_reuse", "prefix_reuse_ssm", "prefix_reuse_hybrid"):
     reuse = rep["suites"]["serving"][key]
     assert reuse["prefill_cut"] >= 0.30, (key, reuse)
     if reuse["kv_write_cut"] is not None:
         assert reuse["kv_write_cut"] >= 0.30, (key, reuse)
-# paged compute plane (DESIGN.md §10), universal: for EVERY family — KV
-# pages (attention), latent pages (MLA covered by tests) and point-state
-# pages (SSM/hybrid) — a prefix hit must cost ZERO copy bytes at
-# bit-identical decoded tokens vs a cold paged start, with ZERO ring
-# fallbacks, while the ring comparator still pays seed copies per hit,
-# and the KV tier's metered reads must equal the kernel's gather bytes
-for key in ("paged_kernel", "paged_kernel_ssm", "paged_kernel_hybrid"):
-    pk = rep["suites"]["serving"][key]
-    assert pk["ring_fallbacks"] == 0, (key, pk)
-    assert pk["seed_copy_bytes"] == 0, (key, pk)
-    assert pk["snapshot_bytes"] == 0, (key, pk)
-    assert pk["seed_copy_bytes_ring"] > 0, (key, pk)
-    assert pk["compute_hits"] > 0, (key, pk)
-    assert pk["kernel_read_bytes"] > 0, (key, pk)
-    assert abs(pk["kv_tier_read_bytes"] - pk["kernel_read_bytes"]) < 1e-6, \
-        (key, pk)
-    if key != "paged_kernel":   # recurrent stacks meter state pages too
-        assert pk["state_bytes_page"] > 0, (key, pk)
-pk = rep["suites"]["serving"]["paged_kernel"]
 # sub-page tails (DESIGN.md §9): boundary-straddling prefixes must cut
 # strictly more prefill tokens than the page-aligned matcher, with the
 # tail copies actually metered — a tail-reuse regression fails the build
@@ -58,7 +39,7 @@ assert tr["tail_hits"] > 0 and tr["tail_copy_bytes"] > 0, tr
 # cut fleet prefill tokens >= 20% vs the per-replica radix baseline, with
 # real metered interconnect traffic and balanced pressure ledgers — a
 # cross-replica reuse regression fails the build here. The SSM variant
-# moves a *point* state snapshot over the wire (no KV byte stream).
+# moves recurrent-state pages over the wire (no KV byte stream).
 for key in ("fleet_reuse", "fleet_reuse_ssm"):
     fr = rep["suites"]["serving"][key]
     assert fr["prefill_cut"] >= 0.20, (key, fr)
@@ -75,9 +56,6 @@ print("prefix reuse:", {k: round(reuse[k], 4) for k in
 print("prefix reuse (ssm/hybrid):",
       {k: round(rep["suites"]["serving"][k]["prefill_cut"], 4)
        for k in ("prefix_reuse_ssm", "prefix_reuse_hybrid")})
-print("paged kernel:", {k: round(pk[k], 4) for k in
-                        ("compute_hits", "seed_copy_bytes",
-                         "seed_copy_bytes_ring", "kernel_read_bytes")})
 print("tail reuse:", {k: round(tr[k], 4) for k in
                       ("prefill_cut", "prefill_cut_page_aligned",
                        "tail_hits", "tail_tokens_copied")})

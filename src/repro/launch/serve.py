@@ -9,14 +9,14 @@ of the weights and its page pool on ``jax.devices()[i % devices]``.
 --shared-prefix-tokens K makes the generated traffic share a K-token
 prompt head, exercising radix prefix reuse end to end;
 --migrate-prefixes additionally lets the directory *move* a hot prefix
-(pages + compute snapshot) to a less-loaded replica at --interconnect-gbps
-instead of queueing every match on its owner.
+(its pages, compute pages included) to a less-loaded replica at
+--interconnect-gbps instead of queueing every match on its owner.
 
 Every architecture in the pool serves — attention, MLA, SSM and hybrid —
-including chunked prefill and prefix *compute* reuse (positional ring
-snapshots vs page-boundary point snapshots of recurrent state;
-DESIGN.md §8). Try --arch mamba2-2.7b or --arch hymba-1.5b with
---shared-prefix-tokens 32.
+on the paged compute plane, including chunked prefill and prefix
+*compute* reuse (shared KV pages, or the recurrent state page at the last
+page boundary; DESIGN.md §8). Try --arch mamba2-2.7b or --arch hymba-1.5b
+with --shared-prefix-tokens 32.
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-8b \
       --requests 8 --max-new 16 --kv-tier mrm_rram --weight-tier mrm_rram \
@@ -97,7 +97,6 @@ def build_engine(args, cfg, full, params):
                      kv_spill_tier=args.spill_tier,
                      prefix_caching=not args.no_prefix_caching,
                      tail_copy=args.tail_copy == "on",
-                     paged_kernel=args.paged_kernel == "on",
                      radix_hot_threshold=args.radix_hot_threshold,
                      radix_hot_tier=args.radix_hot_tier,
                      radix_cold_ttl_s=args.radix_cold_ttl,
@@ -153,12 +152,6 @@ def parse_args(argv=None) -> argparse.Namespace:
     ap.add_argument("--no-prefix-caching", action="store_true",
                     help="disable the radix prefix tree (cold baseline; "
                          "the prompt layout is unpadded either way)")
-    ap.add_argument("--paged-kernel", choices=("on", "off"), default="on",
-                    help="run extend+decode in place on the paged compute "
-                         "plane — universal across families: attention/MLA "
-                         "on KV pages, SSM/hybrid on pooled point-state "
-                         "pages (zero-copy prefix hits, kernel-metered "
-                         "tier reads; DESIGN.md §10)")
     ap.add_argument("--tail-copy", choices=("on", "off"), default="on",
                     help="sub-page tail reuse: copy the shared mid-page "
                          "tail into the borrower's page and resume prefill "

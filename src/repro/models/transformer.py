@@ -455,17 +455,16 @@ def supports_extend(cfg: ModelConfig) -> bool:
 
 
 def snapshot_kind(cfg: ModelConfig) -> str:
-    """How a published prefix compute snapshot of this stack may be
-    reused (DESIGN.md §8):
+    """How a prefix hit on this stack resumes (DESIGN.md §8):
 
-    - ``"positional"`` — the cache is a position-masked ring (attention
-      KV, MLA compressed latents): one snapshot serves *any* shorter
-      page-aligned match boundary, because entries beyond the boundary
-      stay masked (``cache_pos <= cur``) until overwritten.
+    - ``"positional"`` — the cache is position-addressed (attention KV,
+      MLA compressed latents): a borrower resumes at *any* boundary of
+      the shared pages, a sub-page tail included, because keys beyond
+      the boundary stay masked until overwritten.
     - ``"point"`` — the cache integrates the whole prefix (SSM conv
       left-context + SSD state, and therefore the hybrid attention+SSM
-      union): a snapshot is valid only at the *exact* token boundary it
-      was captured at.
+      union): a borrower resumes only at a page boundary, from the state
+      page holding the recurrent state there.
     """
     if any(spec.kind in ("ssm", "hybrid") for spec in cfg.layer_specs()):
         return "point"

@@ -1,7 +1,7 @@
 from repro.serving.cluster import ClusterFrontend, PrefixDirectory
 from repro.serving.engine import (ComputeBackend, EngineConfig, MemoryPlane,
-                                  PrefillChunk, ServeEngine, SnapshotHandle,
-                                  StepPlan, StepReport, choose_hot_tier,
+                                  PrefillChunk, ServeEngine, StepPlan,
+                                  StepReport, choose_hot_tier,
                                   latency_percentiles)
 from repro.serving.directory import DirectoryShard, ShardedDirectory
 from repro.serving.events import (Event, EventKind, EventQueue, EventTrace,
@@ -19,7 +19,7 @@ __all__ = ["EngineConfig", "ServeEngine", "ComputeBackend", "MemoryPlane",
            "PressureStats", "RadixStats", "LifecycleStats",
            "RetentionLifecycle", "ContinuousBatchScheduler",
            "Request", "ClusterFrontend", "PrefixDirectory", "RadixKVIndex",
-           "RadixNode", "PrefixMatch", "SnapshotHandle", "choose_hot_tier",
+           "RadixNode", "PrefixMatch", "choose_hot_tier",
            "latency_percentiles", "Event", "EventKind", "EventQueue",
            "EventTrace", "NonQuiescentError", "FleetConfig", "FleetRequest",
            "FleetSim", "latency_slo", "Fabric", "ShardedDirectory",

@@ -19,7 +19,7 @@ recomputing them cold.
   consults the directory: the least-loaded owner of the longest
   registered prefix wins while it has headroom; when every owner is
   overloaded (load gap above ``migrate_load_gap`` vs the least-loaded
-  replica) the donor's pages and compute snapshot are *pulled* into the
+  replica) the donor's pages, compute pages included, are *pulled* into the
   target replica as a metered inter-replica transfer — bytes charged at
   ``interconnect_gbps`` into the simulated clock, page writes metered
   against the receiving tiers, retention re-programmed on arrival through
@@ -43,8 +43,8 @@ recomputing them cold.
   with no directory match go to their sticky replica;
 - **least-loaded routing** — keyless, matchless requests go to the
   replica with the fewest queued+resident requests; ties break on the KV
-  tier's physical occupancy (live session pages, directory-owned
-  radix-resident prefixes and metered snapshots all count, so a replica
+  tier's physical occupancy (live session pages and directory-owned
+  radix-resident prefixes all count, so a replica
   stuffed with pinned shared prefixes is not treated as empty);
 - **two clock disciplines** (DESIGN.md §12) — ``clock_mode="lockstep"``
   is the PR 3–8 compatibility driver: a cluster round lasts as long as
@@ -240,7 +240,7 @@ class ClusterFrontend:
         self.radix_routed = 0      # requests placed by prefix affinity
         self.migrations = 0        # cross-replica prefix transfers
         self.migrated_tokens = 0   # tokens newly backed on a receiver
-        self.migration_bytes = 0.0  # KV + snapshot bytes over the wire
+        self.migration_bytes = 0.0  # KV + state-page bytes over the wire
         self.migration_s = 0.0      # interconnect transfer time charged
         self.migration_queue_wait_s = 0.0  # time spent queued on a busy link
         self.migrations_queued = 0  # transfers that found their link busy
@@ -314,9 +314,9 @@ class ClusterFrontend:
     def _load_key(self, i: int) -> tuple:
         """Replica load for routing: queue+resident first, then the KV
         tier's *physical* occupancy (allocator utilization: live session
-        pages, directory-owned radix-resident prefixes AND metered
-        snapshots all occupy it) — so a replica stuffed with pinned hot
-        prefixes loses ties to an equally-queued replica with free KV —
+        pages AND directory-owned radix-resident prefixes occupy it) — so
+        a replica stuffed with pinned hot prefixes loses ties to an
+        equally-queued replica with free KV —
         then index for determinism. O(1) per replica: no session or tree
         walk on the routing path."""
         e = self.engines[i]
@@ -326,8 +326,8 @@ class ClusterFrontend:
     # -- the directory protocol: route first, migrate on miss ----------
     def _migrate(self, donor: int, target: int, key,
                  speculative: bool = False) -> int:
-        """Pull the donor's published prefix (pages + compute snapshot)
-        into the target replica as a metered inter-replica transfer.
+        """Pull the donor's published prefix (its pages, compute pages
+        included) into the target replica as a metered inter-replica transfer.
         ``speculative`` marks a predictive replication push: same wire
         physics and tier metering, separate ledger, and no request is
         gated on its delivery. Returns the tokens now matched on the
@@ -336,16 +336,17 @@ class ClusterFrontend:
         if exp is None:
             return 0
         e = self.engines[target]
-        imp = e.import_prefix(exp["tokens"], caches=exp["caches"],
-                              hot=exp["hot"], hits=exp["hits"],
-                              snap_kind=exp["snap_kind"],
-                              snap_tokens=exp["snap_tokens"],
+        imp = e.import_prefix(exp["tokens"], hot=exp["hot"],
+                              hits=exp["hits"],
                               page_data=exp.get("page_data"),
                               page_tokens=exp.get("page_tokens"))
         if imp["total_tokens"] == 0:
             return 0
+        # the wire carries each new page's KV rows and, on point stacks,
+        # its recurrent-state page
         moved = (imp["new_tokens"] * e.kv.kv_bytes_token
-                 + imp["snapshot_bytes"])
+                 + imp["new_tokens"] // e.kv.page_tokens
+                 * e.kv.state_bytes_page)
         if moved > 0:
             # shared-fabric admission control: the transfer starts when
             # the donor's up-link, the receiver's down-link AND a core
@@ -783,7 +784,6 @@ class ClusterFrontend:
                                            for r in reps),
             "prefill_tokens_skipped": sum(r["prefill_tokens_skipped"]
                                           for r in reps),
-            "snapshot_bytes": sum(r["snapshot_bytes"] for r in reps),
             "radix_routed": self.radix_routed,
             "directory": {
                 "entries": self.directory.n_entries(),

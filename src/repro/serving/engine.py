@@ -4,10 +4,10 @@ byte routed through the MRM memory control plane.
 The engine is an orchestrator over two subsystems that talk through an
 explicit :class:`StepPlan` / :class:`StepReport` interface:
 
-- :class:`ComputeBackend` — the JAX compute path: per-slot ring caches,
-  per-length jit first-chunk prefill, chunked-prefill continuation
-  (``extend``), and one batched decode step per engine step with per-slot
-  positions.
+- :class:`ComputeBackend` — the JAX compute path, the paged compute plane
+  (DESIGN.md §10): one pool of compute pages per cache family, owned by
+  the backend and indexed through page tables, on which the jitted first-
+  chunk, extend and batched decode step programs compute in place.
 - :class:`MemoryPlane`  — the MRM control plane: weights live in a region
   of the chosen tier (written once at deploy, read wholesale every model
   pass — §2.2); KV pages go through :class:`PagedKVManager` (DCM retention
@@ -15,35 +15,31 @@ explicit :class:`StepPlan` / :class:`StepReport` interface:
   eviction/spill/recompute policy); refresh/migrate/drop deadlines are
   serviced as simulation time advances.
 
-Prefix reuse is *real* in both planes (DESIGN.md §6): at admission the
-prompt is matched against the radix prefix tree; on a hit the matched
-page-aligned tokens are attached in the memory plane (no KV writes) AND
-skipped in the compute plane — the slot's caches are seeded from the
-donor's published cache snapshot and prefill continues via ``extend`` from
-the seeded boundary. A hit therefore cuts prefill chunks, metered KV
-writes, and step latency together. **Every** prompt runs *unpadded* on the
-one chunked path (DESIGN.md §5): token ``i`` sits at position
-``prefix_len + i`` for every request whatever the flags, so shared
-prefixes are position-aligned across prompt lengths (multi-turn chat,
-shared system prompts, RAG fan-out all match) — "whole-prompt" prefill is
-simply the maximal first chunk of the same path. A match may also end
-mid-page: with ``tail_copy`` the sub-page tail is copied into the
-borrower's own page (metered read + write, DESIGN.md §9) and extend
-resumes from the exact token boundary.
+Every memory-plane page owns one compute page for its life, so prefix
+reuse is *real* in both planes (DESIGN.md §6): at admission the prompt is
+matched against the radix prefix tree; on a hit the matched page-aligned
+tokens are attached in the memory plane (no KV writes) and their compute
+pages are spliced into the request's page table (no copy), and prefill
+continues via extend from the boundary. A hit therefore cuts prefill
+chunks, metered KV writes, and step latency together. **Every** prompt
+runs *unpadded* on the one chunked path (DESIGN.md §5): token ``i`` sits
+at position ``prefix_len + i`` for every request whatever the flags, so
+shared prefixes are position-aligned across prompt lengths (multi-turn
+chat, shared system prompts, RAG fan-out all match) — "whole-prompt"
+prefill is simply the maximal first chunk of the same path. A match may
+also end mid-page: with ``tail_copy`` the sub-page tail's rows are copied
+into the borrower's own page (metered read + write, DESIGN.md §9) and
+extend resumes from the exact token boundary.
 
 Compute reuse covers every mixer family (DESIGN.md §8): attention and MLA
-snapshots are *positional* (ring caches masked by stored positions — one
-snapshot serves any shorter page-aligned boundary), SSM and hybrid
-snapshots are *point* captures of the recurrent state, taken mid-prefill
-at page-aligned boundaries (the prompt's last page boundary, plus the
-request's own match boundary when sharing was observed there) and valid
-only at exactly the boundary they were captured at.
+stacks resume on the shared KV pages at any boundary; SSM and hybrid
+stacks resume on the state page at the last page boundary the match
+covers, which holds the recurrent state there.
 
-Chunked prefill: prompts longer than ``chunk_tokens`` (or, with
-``chunk_tokens=None``, longer than the smallest per-layer ring) are fed to
-the model in pieces interleaved with decode rounds, bounding inter-token
-latency for resident sessions and admitting prompts beyond
-``max_cache_len`` — the ring caches keep the attention window's tail.
+Chunked prefill: prompts longer than ``chunk_tokens`` (or than the
+clamp in ``ServeEngine._chunk_plan``) are fed to the model in pieces
+interleaved with decode rounds, bounding inter-token latency for
+resident sessions.
 
 Step time (simulation) is modelled per tier from the bytes each phase
 actually moved and each tier's read/write bandwidth (tiers progress in
@@ -122,16 +118,11 @@ class EngineConfig:
     # back to short retention (metered) before leaf eviction may reach it
     demote_on_pressure: bool = False
     # regression guard (the PR 4 clobbering class): verify after every
-    # decode round that no cache family of an inactive slot was written
+    # decode round that no other session's compute page was written
     audit_decode_masking: bool = False
-    # paged compute plane (DESIGN.md §10): run extend and decode directly
-    # on the pages PagedKVManager owns — a radix or migrated prefix hit
-    # is a page-table splice (zero copy bytes) and tier reads meter the
-    # kernel's actual per-page gather stream. Universal across mixer
-    # families: attention/MLA compute on KV pages, SSM/hybrid on pooled
-    # point-state pages (conv + SSD state at page-boundary capture
-    # points) drawn from the same free-list.
-    paged_kernel: bool = False
+    # the paged compute plane (DESIGN.md §10) is the only compute path;
+    # the benchmark's configs still name this key, and False is refused
+    paged_kernel: bool = True
     # reliability plane (DESIGN.md §11): inject age-driven bit flips into
     # the paged KV/state pages of decoding sessions, anchored so a page
     # exactly at its programmed retention sees this RBER (None = off).
@@ -186,74 +177,26 @@ class StepReport:
 
 
 @dataclass
-class SnapshotHandle:
-    """A donor slot's cache snapshot with its metered backing region.
-
-    The compute-plane arrays used to be held as unmetered Python-side JAX
-    arrays (ROADMAP: snapshot memory accounting); they are now carved from
-    the KV tier budget — a metered region write at publication (actual
-    array bytes, compute scale: the acct-scale KV bytes already live in
-    the paged manager, metering both would double-count the same state),
-    released when the owning radix node leaves the tree. The manager
-    releases via duck-typed ``release()`` so it stays payload-agnostic.
-
-    ``kind``/``tokens`` are the per-architecture validity contract
-    (DESIGN.md §8): a ``"positional"`` snapshot (attention KV, MLA latent
-    cache) covers every page-aligned boundary up to ``tokens`` because
-    stale entries stay position-masked; a ``"point"`` snapshot (SSM
-    recurrent state, hybrid union) is valid *only* at exactly ``tokens``
-    absolute positions (incl. the meta/frontend prefix)."""
-    caches: object
-    nbytes: float
-    mem: MemorySystem
-    region_id: Optional[int]
-    kind: str = "positional"
-    tokens: int = 0
-
-    def release(self) -> None:
-        if self.region_id is not None:
-            self.mem.release_region(self.region_id)
-            self.region_id = None
-
-    @property
-    def live(self) -> bool:
-        return self.region_id is not None
-
-
-@dataclass
 class _SlotPrefill:
     """Continuation state of a (possibly radix-shortened) chunked prefill:
-    how far into the prompt the slot's caches already reach — a prefix hit
-    starts `done` at the seeded boundary (which, with sub-page tail reuse,
-    need not be page-aligned) instead of 0.
-
-    For point-snapshot stacks (SSM/hybrid, DESIGN.md §8) the prefill also
-    carries up to two page-aligned *capture points* (prompt-index space):
-    ``snap_match_at`` — the observed-share boundary (this request's own
-    match), whose snapshot is attached to the matched radix node as soon
-    as the prefill crosses it — and ``snap_end_at`` — the speculative
-    last page boundary of the prompt, published with the prompt's
-    registration. ``next_chunk`` splits chunks at these points so the
-    recurrent state is capturable exactly there."""
+    how far into the prompt the slot's pages already reach — a prefix hit
+    starts `done` at the reused boundary (which, with sub-page tail reuse,
+    need not be page-aligned) instead of 0."""
     req: Request
     tokens: np.ndarray            # prompt tokens (always unpadded)
     chunk: int
     key: Optional[np.ndarray]     # radix key: prefix_len sentinels + tokens
     match: Optional[PrefixMatch]
-    done: int = 0   # tokens of `tokens` already in the slot's caches
-    grid: Optional[int] = None            # point stacks: page-aligned chunking
-    snap_match_at: Optional[int] = None   # point capture: match boundary
-    snap_end_at: Optional[int] = None     # point capture: last page boundary
-    point_caches: object = None           # the end-boundary capture
+    done: int = 0   # tokens of `tokens` already in the slot's pages
+    grid: Optional[int] = None    # point stacks: page-aligned chunking
 
     def next_chunk(self, slot: int, prefix_len: int) -> PrefillChunk:
         end = min(self.done + self.chunk, len(self.tokens))
         if self.grid:
-            # point-snapshot stacks chunk on the position-space page grid:
+            # point stacks chunk on the position-space page grid:
             # recurrent-state arithmetic depends on the chunk partition, so
-            # every engine must cut prompts identically (seeded resumption
-            # stays bit-equal to a cold run) and every capture boundary
-            # lands exactly on a chunk end (DESIGN.md §8)
+            # every engine must cut prompts identically (resumption from a
+            # state page stays bit-equal to a cold run) (DESIGN.md §8)
             nxt = ((prefix_len + self.done) // self.grid + 1) * self.grid \
                 - prefix_len
             end = min(end, max(nxt, self.done + 1))
@@ -270,25 +213,22 @@ class _SlotPrefill:
 
 
 class ComputeBackend:
-    """Real-model compute over fixed decode slots: per-length jit
-    first-chunk prefill (prompts are never padded — the compile cache is
+    """Real-model compute over fixed decode slots on the paged compute
+    plane (DESIGN.md §10): owns the compute-page pool, one pooled page
+    array per cache family, and the per-slot positions/tokens; runs the
+    jitted first-chunk prefill (prompts are never padded — programs are
     keyed by the exact chunk length), chunked-prefill continuation
-    (extend), batched decode. Owns the dense ring caches and per-slot
-    positions/tokens; knows nothing about tiers, pages or retention."""
+    (extend) and batched decode in place on the pages each launch's page
+    table lists. Knows nothing about tiers or retention."""
 
-    def __init__(self, cfg: ModelConfig, params, ecfg: EngineConfig,
-                 paged: bool = False):
+    def __init__(self, cfg: ModelConfig, params, ecfg: EngineConfig):
         self.cfg = cfg
         self.ecfg = ecfg
         self.params = params
-        self.paged = paged
         B = ecfg.max_slots
         self.positions = np.full((B,), -1, np.int64)  # last written position
         self.last_tokens = np.zeros((B, 1) if cfg.n_codebooks == 1
                                     else (B, 1, cfg.n_codebooks), np.int32)
-        self._prefill_jit: Dict[int, callable] = {}
-        self._extend_jit: Dict[int, callable] = {}
-        self.seed_copy_bytes = 0.0  # ring-path donor seeding copy traffic
         # paged step programs built (traced + compiled or loaded) per kind,
         # and the (kind, length, width) keys built on the current pool
         self.builds = {k: {"count": 0, "seconds": 0.0}
@@ -302,53 +242,22 @@ class ComputeBackend:
         self.kv_pages_gathered = {k: 0 for k in self.builds}
         self.kv_pages_attended = {k: 0 for k in self.builds}
         self.attention_kernel_launches = {k: 0 for k in self.builds}
-        self.attn_kernel = False
         self._attn_specs = [s for s in cfg.layer_specs()
                             if s.kind in ("attn", "mla", "hybrid")]
-        if paged:
-            # paged compute plane (DESIGN.md §10): one pooled page array per
-            # cache family, owned here, indexed by PagedKVManager pages via
-            # Page.compute_page. Page 0 is the reserved null page (gathered
-            # for padded table slots; auto-masked by slot-derived positions).
-            # The pool lives on the weights' device, so replicas placed on
-            # different devices compute there
-            self.caches = None
-            self.page_tokens = ecfg.page_tokens
-            n0 = max(16, 1 + B * -(-ecfg.max_cache_len // ecfg.page_tokens))
-            device = next(iter(jax.tree.leaves(params)[0].devices()))
-            self.attn_kernel = self.kernel_serves(cfg, device.platform)
-            self.paged_caches = jax.device_put(tfm.init_paged_caches(
-                cfg, n0, ecfg.page_tokens, jnp.dtype(cfg.dtype)), device)
-            self._free = list(range(n0 - 1, 0, -1))  # pop() -> lowest id
-            self._paged_first_jit: Dict[tuple, callable] = {}
-            self._paged_extend_jit: Dict[tuple, callable] = {}
-            self._paged_decode_jit: Dict[int, callable] = {}
-        else:
-            self.caches = tfm.init_caches(cfg, B, ecfg.max_cache_len,
-                                          jnp.dtype(cfg.dtype))
-            self._decode_jit = jax.jit(
-                lambda p, c, t, pos, act: tfm.decode(cfg, p, c, t, pos,
-                                                     active=act))
-
-    # -- per-length jit caches -----------------------------------------
-    def _prefill_fn(self, length: int):
-        if length not in self._prefill_jit:
-            cfg, ecfg = self.cfg, self.ecfg
-
-            def fn(p, batch):
-                return tfm.prefill(cfg, p, batch,
-                                   max_cache_len=ecfg.max_cache_len)
-
-            self._prefill_jit[length] = jax.jit(fn)
-        return self._prefill_jit[length]
-
-    def _extend_fn(self, length: int):
-        if length not in self._extend_jit:
-            cfg = self.cfg
-            # offset is a traced argument: one executable per chunk length
-            self._extend_jit[length] = jax.jit(
-                lambda p, c, t, off: tfm.extend(cfg, p, c, t, off))
-        return self._extend_jit[length]
+        # Page 0 is the reserved null page (gathered for padded table
+        # slots; auto-masked by slot-derived positions). The pool lives on
+        # the weights' device, so replicas placed on different devices
+        # compute there
+        self.page_tokens = ecfg.page_tokens
+        n0 = max(16, 1 + B * -(-ecfg.max_cache_len // ecfg.page_tokens))
+        device = next(iter(jax.tree.leaves(params)[0].devices()))
+        self.attn_kernel = self.kernel_serves(cfg, device.platform)
+        self.paged_caches = jax.device_put(tfm.init_paged_caches(
+            cfg, n0, ecfg.page_tokens, jnp.dtype(cfg.dtype)), device)
+        self._free = list(range(n0 - 1, 0, -1))  # pop() -> lowest id
+        self._paged_first_jit: Dict[tuple, callable] = {}
+        self._paged_extend_jit: Dict[tuple, callable] = {}
+        self._paged_decode_jit: Dict[int, callable] = {}
 
     # -- paged compute-page pool (DESIGN.md §10) -----------------------
     @property
@@ -407,9 +316,7 @@ class ComputeBackend:
                 self.paged_caches)
 
     def pool_pages(self) -> int:
-        """Pages in the compute-page pool (0 on the ring path)."""
-        if not self.paged:
-            return 0
+        """Pages in the compute-page pool."""
         return int(jax.tree.leaves(self.paged_caches)[0].shape[1])
 
     def _build_scope(self, kind: str, length: int, width: int):
@@ -573,36 +480,6 @@ class ComputeBackend:
             self.pool_copies += 1
         return logits
 
-    # -- slot cache plumbing -------------------------------------------
-    def _insert_slot(self, slot: int, new_caches) -> None:
-        """Copy a B=1 cache tree into decode-slot `slot`."""
-        self.caches = jax.tree.map(
-            lambda dst, src: dst.at[:, slot].set(src[:, 0]),
-            self.caches, new_caches)
-
-    def _extract_slot(self, slot: int):
-        """View decode-slot `slot` as a B=1 cache tree (for extend)."""
-        return jax.tree.map(lambda a: a[:, slot:slot + 1], self.caches)
-
-    def snapshot_slot(self, slot: int):
-        """Immutable B=1 snapshot of a slot's ring caches (jax arrays are
-        immutable, so the sliced tree is a stable donor handle)."""
-        return self._extract_slot(slot)
-
-    def seed_slot(self, slot: int, snapshot) -> None:
-        """Seed a slot's ring caches from a donor snapshot (prefix hit).
-        Donor entries beyond the matched prefix are harmless: masking is
-        position-based (`cache_pos <= cur`), so stale positions stay masked
-        until this request overwrites them via extend/decode.
-
-        This is the ring path's per-hit copy cost — every hit rewrites a
-        full per-slot cache tree. The paged plane replaces it with a
-        page-table splice (zero copy bytes); ``seed_copy_bytes`` is the
-        comparator the paged_kernel benchmark sweeps against."""
-        self.seed_copy_bytes += float(sum(
-            a.size * a.dtype.itemsize for a in jax.tree.leaves(snapshot)))
-        self._insert_slot(slot, snapshot)
-
     def prefix_len(self) -> int:
         return self.cfg.n_meta_tokens + (self.cfg.n_frontend_tokens
                                          if self.cfg.frontend == "vision" else 0)
@@ -615,64 +492,40 @@ class ComputeBackend:
 
     # -- StepPlan execution --------------------------------------------
     def run_prefill_chunk(self, ck: PrefillChunk,
-                          page_table: Optional[np.ndarray] = None
-                          ) -> Optional[np.ndarray]:
-        """Execute one prefill chunk. Returns the sampled next token when
-        the chunk completes the prompt, else None. On the paged plane the
-        chunk computes in place on the pool pages listed in ``page_table``
-        (the request's session pages) — no per-slot ring insert."""
+                          page_table: np.ndarray) -> Optional[np.ndarray]:
+        """Execute one prefill chunk in place on the pool pages listed in
+        ``page_table`` (the request's session pages). Returns the sampled
+        next token when the chunk completes the prompt, else None."""
         toks = np.asarray(ck.tokens, np.int32)
-        W = 0 if page_table is None else int(np.shape(page_table)[-1])
+        W = int(np.shape(page_table)[-1])
         with span("backend.prefill", width=W, rows=toks.shape[0]) as sp:
-            if self.paged:
-                assert page_table is not None
-                kind = "first" if ck.first else "extend"
-                with span("backend.inputs"):
-                    # a first chunk's query rows include the meta/frontend
-                    # prefix; the chunk's keys end where its tokens do
-                    keys = ck.offset + toks.shape[0]
-                    self._count_kv_pages(
-                        sp, kind, np.asarray(page_table)[None],
-                        keys if ck.first else toks.shape[0],
-                        np.array([keys]))
-                    tbl = jnp.asarray(self._launch_table(page_table),
-                                      jnp.int32)[None]
-                    W = tbl.shape[-1]
-                    tok_d = jnp.asarray(toks)[None]
-                    if ck.first:
-                        batch = {"tokens": tok_d}
-                        if self.cfg.frontend == "vision":
-                            batch["image_embeds"] = jnp.zeros(
-                                (1, self.cfg.n_frontend_tokens,
-                                 self.cfg.d_model),
-                                jnp.dtype(self.cfg.dtype))
-                with span("backend.launch"), \
-                        self._build_scope(kind, toks.shape[0], W):
-                    if ck.first:
-                        logits = self._launch(
-                            self._paged_first_fn(toks.shape[0], W), batch,
-                            tbl)
-                    else:
-                        logits = self._launch(
-                            self._paged_extend_fn(toks.shape[0], W), tok_d,
-                            ck.offset, tbl)
-            elif ck.first:
-                batch = {"tokens": jnp.asarray(toks)[None]}
-                if self.cfg.frontend == "vision":
-                    batch["image_embeds"] = jnp.zeros(
-                        (1, self.cfg.n_frontend_tokens, self.cfg.d_model),
-                        jnp.dtype(self.cfg.dtype))
-                with span("backend.launch"):
-                    logits, caches1 = self._prefill_fn(toks.shape[0])(
-                        self.params, batch)
-                self._insert_slot(ck.slot, caches1)
-            else:
-                caches1 = self._extract_slot(ck.slot)
-                with span("backend.launch"):
-                    logits, caches1 = self._extend_fn(toks.shape[0])(
-                        self.params, caches1, jnp.asarray(toks)[None],
-                        ck.offset)
-                self._insert_slot(ck.slot, caches1)
+            kind = "first" if ck.first else "extend"
+            with span("backend.inputs"):
+                # a first chunk's query rows include the meta/frontend
+                # prefix; the chunk's keys end where its tokens do
+                keys = ck.offset + toks.shape[0]
+                self._count_kv_pages(
+                    sp, kind, np.asarray(page_table)[None],
+                    keys if ck.first else toks.shape[0], np.array([keys]))
+                tbl = jnp.asarray(self._launch_table(page_table),
+                                  jnp.int32)[None]
+                W = tbl.shape[-1]
+                tok_d = jnp.asarray(toks)[None]
+                if ck.first:
+                    batch = {"tokens": tok_d}
+                    if self.cfg.frontend == "vision":
+                        batch["image_embeds"] = jnp.zeros(
+                            (1, self.cfg.n_frontend_tokens,
+                             self.cfg.d_model), jnp.dtype(self.cfg.dtype))
+            with span("backend.launch"), \
+                    self._build_scope(kind, toks.shape[0], W):
+                if ck.first:
+                    logits = self._launch(
+                        self._paged_first_fn(toks.shape[0], W), batch, tbl)
+                else:
+                    logits = self._launch(
+                        self._paged_extend_fn(toks.shape[0], W), tok_d,
+                        ck.offset, tbl)
             if not ck.last:
                 return None
             with span("backend.fetch"):
@@ -681,79 +534,49 @@ class ComputeBackend:
                 self.positions[ck.slot] = ck.offset + toks.shape[0] - 1
             return tok
 
-    def run_decode(self, slots: List[int],
-                   page_tables: Optional[np.ndarray] = None,
+    def run_decode(self, slots: List[int], page_tables: np.ndarray,
                    audit_pages: Optional[List[int]] = None) -> np.ndarray:
-        """One batched decode round over `slots` (other rows' caches are
-        left untouched via the active mask — a mid-prefill slot must not be
-        clobbered). Returns the sampled tokens for all B rows. On the
-        paged plane ``page_tables`` is the (B, W) compute-page table
-        (inactive rows all-null) and ``audit_pages`` lists compute pages
-        the round must not write (other sessions' pages)."""
+        """One batched decode round over `slots` on the (B, W) compute-page
+        table ``page_tables`` (inactive rows all-null; the active mask
+        keeps a mid-prefill slot's pages unwritten). ``audit_pages`` lists
+        compute pages the round must not write (other sessions' pages).
+        Returns the sampled tokens for all B rows."""
         B = self.ecfg.max_slots
-        W = 0 if page_tables is None else int(np.shape(page_tables)[-1])
+        W = int(np.shape(page_tables)[-1])
         with span("backend.decode", width=W, rows=len(slots)) as sp:
             act = np.zeros((B,), bool)
             act[slots] = True
-            inactive = [s for s in range(B) if not act[s]]
             before = None
-            if self.paged:
-                assert page_tables is not None
-                if self.ecfg.audit_decode_masking and audit_pages:
-                    idx = np.asarray(audit_pages, np.int32)
-                    before = [np.asarray(leaf[:, idx])
-                              for leaf in jax.tree.leaves(self.paged_caches)]
-                with span("backend.inputs"):
-                    # an active row attends keys 0..position + 1
-                    self._count_kv_pages(
-                        sp, "decode", np.asarray(page_tables), 1,
-                        np.where(act, self.positions + 2, 0))
-                    pos = jnp.asarray(np.maximum(self.positions + 1, 0),
-                                      jnp.int32)
-                    tbl = jnp.asarray(self._launch_table(page_tables),
-                                      jnp.int32)
-                    W = tbl.shape[-1]
-                    last = jnp.asarray(self.last_tokens)
-                    act_d = jnp.asarray(act)
-                with span("backend.launch"), \
-                        self._build_scope("decode", 1, W):
-                    logits = self._launch(self._paged_decode_fn(W), last,
-                                          pos, tbl, act_d)
-                if before is not None:
-                    # paged variant of the clobbering guard: a decode round
-                    # writes exactly one row of each active session's open
-                    # page — shared (sealed) pages and other sessions'
-                    # pages must come back bit-identical
-                    idx = np.asarray(audit_pages, np.int32)
-                    for b, leaf in zip(before,
-                                       jax.tree.leaves(self.paged_caches)):
-                        after = np.asarray(leaf[:, idx])
-                        assert np.array_equal(b, after, equal_nan=True), \
-                            "decode wrote another session's compute page " \
-                            "(paged masking regression)"
-            else:
-                if self.ecfg.audit_decode_masking and inactive:
-                    before = [np.asarray(leaf[:, inactive])
-                              for leaf in jax.tree.leaves(self.caches)]
-                with span("backend.inputs"):
-                    pos = jnp.asarray(np.maximum(self.positions + 1, 0),
-                                      jnp.int32)
-                    last = jnp.asarray(self.last_tokens)
-                    act_d = jnp.asarray(act)
-                with span("backend.launch"):
-                    logits, self.caches = self._decode_jit(
-                        self.params, self.caches, last, pos, act_d)
-                if before is not None:
-                    # regression guard for the PR 4 clobbering class: with
-                    # the padded whole-prompt path gone, chunked prefill
-                    # interleaves with decode for every stack — a decode
-                    # round must not write ANY cache family (ring KV, MLA
-                    # latents, conv/SSD state) of a slot it did not decode
-                    for b, leaf in zip(before, jax.tree.leaves(self.caches)):
-                        after = np.asarray(leaf[:, inactive])
-                        assert np.array_equal(b, after, equal_nan=True), \
-                            "decode wrote an inactive slot's cache " \
-                            "(active-slot masking regression)"
+            if self.ecfg.audit_decode_masking and audit_pages:
+                idx = np.asarray(audit_pages, np.int32)
+                before = [np.asarray(leaf[:, idx])
+                          for leaf in jax.tree.leaves(self.paged_caches)]
+            with span("backend.inputs"):
+                # an active row attends keys 0..position + 1
+                self._count_kv_pages(
+                    sp, "decode", np.asarray(page_tables), 1,
+                    np.where(act, self.positions + 2, 0))
+                pos = jnp.asarray(np.maximum(self.positions + 1, 0),
+                                  jnp.int32)
+                tbl = jnp.asarray(self._launch_table(page_tables), jnp.int32)
+                W = tbl.shape[-1]
+                last = jnp.asarray(self.last_tokens)
+                act_d = jnp.asarray(act)
+            with span("backend.launch"), self._build_scope("decode", 1, W):
+                logits = self._launch(self._paged_decode_fn(W), last, pos,
+                                      tbl, act_d)
+            if before is not None:
+                # the clobbering guard: a decode round writes exactly one
+                # row of each active session's open page — shared (sealed)
+                # pages and other sessions' pages must come back
+                # bit-identical
+                idx = np.asarray(audit_pages, np.int32)
+                for b, leaf in zip(before,
+                                   jax.tree.leaves(self.paged_caches)):
+                    after = np.asarray(leaf[:, idx])
+                    assert np.array_equal(b, after, equal_nan=True), \
+                        "decode wrote another session's compute page " \
+                        "(paged masking regression)"
             with span("backend.fetch"):
                 next_np = np.asarray(self.sample(logits))
                 for slot in slots:
@@ -805,7 +628,7 @@ class MemoryPlane:
     (``acct_cfg``) is decoupled from the compute scale."""
 
     def __init__(self, acct_cfg: ModelConfig, mem: MemorySystem,
-                 ecfg: EngineConfig, paged: bool = False):
+                 ecfg: EngineConfig):
         self.cfg = acct_cfg
         self.mem = mem
         self.ecfg = ecfg
@@ -816,10 +639,8 @@ class MemoryPlane:
             raise ValueError(f"radix_hot_tier {hot_tier!r} is not a tier "
                              f"({sorted(mem.devices)})")
         self.hot_tier = hot_tier
-        # point-state pages ride on KV pages only on the paged plane; the
-        # ring path meters recurrent state through the engine's
-        # SnapshotHandle regions instead (charging both would double-count)
-        state_bp = float(acct_cfg.state_bytes_per_page()) if paged else 0.0
+        # point stacks' recurrent-state pages ride on their KV pages
+        state_bp = float(acct_cfg.state_bytes_per_page())
         self.kv = PagedKVManager(acct_cfg, mem, ecfg.kv_tier,
                                  ecfg.page_tokens, ecfg.expected_session_s,
                                  spill_tier=ecfg.kv_spill_tier,
@@ -882,17 +703,18 @@ class ServeEngine:
 
     Invariants the tests rely on:
 
-    - **Hit/cold equivalence** — a prefix hit (seeded slot + extend from
-      the boundary) and a cross-replica migrated hit decode bit-identically
-      (fp32) to a cold start; at least one prompt token always computes.
-    - **Snapshot accounting** — every published compute snapshot is a
-      metered region in the KV tier (``SnapshotHandle``), released when
-      its radix node leaves the tree; ``live_snapshot_bytes`` never leaks.
-    - **Point-capture validity** — a ``kind="point"`` snapshot is only
-      published at a page-aligned boundary the slot's caches exactly
-      reached, and only seeded when the borrower's match covers it
-      (DESIGN.md §8).
+    - **Hit/cold equivalence** — a prefix hit (shared pages spliced into
+      the page table + extend from the boundary) and a cross-replica
+      migrated hit decode bit-identically (fp32) to a cold start; at least
+      one prompt token always computes.
+    - **Zero-copy hits** — a hit copies no compute state but the rows of
+      a sub-page tail (DESIGN.md §9); the KV tier's metered reads are the
+      pages the step programs gather (``kernel_read_bytes``).
     """
+
+    # always on the paged plane: kept for callers that check it, until
+    # the configs' ``paged_kernel`` key goes (ROADMAP D2)
+    paged = True
 
     def __init__(self, cfg: ModelConfig, params, mem: MemorySystem,
                  ecfg: EngineConfig, account_cfg: Optional[ModelConfig] = None):
@@ -905,47 +727,42 @@ class ServeEngine:
         self.params = params
         self.mem = mem
         self.ecfg = ecfg
-        # how this stack's prefix snapshots may be reused (DESIGN.md §8):
+        if not ecfg.paged_kernel:
+            raise ValueError("paged_kernel=False: the paged compute plane "
+                             "is the only compute path (DESIGN.md §10)")
+        # how this stack resumes on a prefix hit (DESIGN.md §8):
         # "positional" (attention/MLA) or "point" (SSM/hybrid)
         self.snapshot_kind = tfm.snapshot_kind(cfg)
-        # paged compute plane (DESIGN.md §10), universal across mixer
-        # families: positional stacks compute on KV pages, point stacks
-        # (SSM/hybrid) on pooled state pages capturing the recurrent state
-        # at every page boundary — so a radix or migrated hit is a
-        # page-table splice for all four families
-        self.paged = bool(ecfg.paged_kernel) and tfm.supports_extend(cfg)
         self.sched = ContinuousBatchScheduler(ecfg.max_slots,
                                               ecfg.max_prefills_per_step)
-        self.backend = ComputeBackend(cfg, params, ecfg, paged=self.paged)
-        self.memplane = MemoryPlane(self.acct_cfg, mem, ecfg,
-                                    paged=self.paged)
-        self.kernel_read_bytes = 0.0   # paged: metered kernel page gathers
-        if self.paged:
-            # every memory-plane page owns one compute page for its life —
-            # a radix hit shares the Page object, hence the compute page:
-            # zero copy bytes
-            self.memplane.kv.on_page_alloc = self._on_page_alloc
-            self.memplane.kv.on_page_release = self._on_page_release
-            # per-layer (kv_bytes_per_token, window, state_bytes) at the
-            # accounting scale: the analytic model of the kernel's per-page
-            # read stream — positional layers gather token rows, point
-            # layers additionally pull one state-page snapshot per step
-            self._acct_layers = []
-            state_lb = float(self.acct_cfg.ssm_state_bytes_layer())
-            for spec in self.acct_cfg.layer_specs():
-                if spec.kind == "mla":
-                    lb, sb = (self.acct_cfg.kv_lora_rank
-                              + self.acct_cfg.qk_rope_dim) * 2, 0.0
-                elif spec.kind == "attn":
-                    lb, sb = (2 * self.acct_cfg.n_kv_heads
-                              * self.acct_cfg.resolved_head_dim * 2), 0.0
-                elif spec.kind == "hybrid":
-                    lb = (2 * self.acct_cfg.n_kv_heads
-                          * self.acct_cfg.resolved_head_dim * 2)
-                    sb = state_lb
-                else:                      # ssm: no KV token stream
-                    lb, sb = 0.0, state_lb
-                self._acct_layers.append((float(lb), spec.window, sb))
+        self.backend = ComputeBackend(cfg, params, ecfg)
+        self.memplane = MemoryPlane(self.acct_cfg, mem, ecfg)
+        self.kernel_read_bytes = 0.0   # metered page gathers
+        # every memory-plane page owns one compute page for its life — a
+        # radix hit shares the Page object, hence the compute page: zero
+        # copy bytes
+        self.memplane.kv.on_page_alloc = self._on_page_alloc
+        self.memplane.kv.on_page_release = self._on_page_release
+        # per-layer (kv_bytes_per_token, window, state_bytes) at the
+        # accounting scale: the analytic model of the step programs'
+        # per-page read stream — positional layers gather token rows,
+        # point layers additionally pull one state page per step
+        self._acct_layers = []
+        state_lb = float(self.acct_cfg.ssm_state_bytes_layer())
+        for spec in self.acct_cfg.layer_specs():
+            if spec.kind == "mla":
+                lb, sb = (self.acct_cfg.kv_lora_rank
+                          + self.acct_cfg.qk_rope_dim) * 2, 0.0
+            elif spec.kind == "attn":
+                lb, sb = (2 * self.acct_cfg.n_kv_heads
+                          * self.acct_cfg.resolved_head_dim * 2), 0.0
+            elif spec.kind == "hybrid":
+                lb = (2 * self.acct_cfg.n_kv_heads
+                      * self.acct_cfg.resolved_head_dim * 2)
+                sb = state_lb
+            else:                      # ssm: no KV token stream
+                lb, sb = 0.0, state_lb
+            self._acct_layers.append((float(lb), spec.window, sb))
         # fault injection (DESIGN.md §11): age-driven flips over the paged
         # compute plane, sampled against each page's tracked region
         self.faults = None
@@ -962,9 +779,7 @@ class ServeEngine:
         self.prefill_chunks_run = 0
         self.prefill_tokens_computed = 0   # tokens that ran through the model
         self.prefill_tokens_skipped = 0    # tokens a radix hit skipped
-        self.prefix_compute_hits = 0       # admissions seeded from a donor
-        self.snapshots_published = 0       # metered donor snapshots created
-        self._snap_spec = None             # cached foreign-snapshot template
+        self.prefix_compute_hits = 0       # admissions resumed past a hit
 
     # -- legacy surface (kept stable for callers/tests) ----------------
     @property
@@ -982,10 +797,6 @@ class ServeEngine:
     @property
     def weight_region(self):
         return self.memplane.weight_region
-
-    @property
-    def caches(self):
-        return self.backend.caches
 
     @property
     def positions(self):
@@ -1014,10 +825,8 @@ class ServeEngine:
         waits for them, so its TTFT pays queue wait + transfer.
 
         Any prompt length is admissible: there is one unpadded chunked
-        path (DESIGN.md §5), and a prompt longer than the smallest
-        per-layer ring is simply split into ring-bounded chunks even with
-        ``chunk_tokens=None`` — the ring caches keep the attention
-        window's tail, exactly as decode does."""
+        path (DESIGN.md §5), and ``_chunk_plan`` splits a long prompt into
+        chunks even with ``chunk_tokens=None``."""
         rid = len(self.outputs)
         self.outputs[rid] = []
         self._submit_wall[rid] = mark("request.submit", rid=rid)
@@ -1102,142 +911,42 @@ class ServeEngine:
         key = self.radix_key_for(prompt_tokens)
         return 0 if key is None else self.kv.match_len(key)
 
-    def _point_snapshot_for(self, node, max_tokens: int
-                            ) -> Optional[SnapshotHandle]:
-        """Deepest live *point* snapshot usable at a match ending at
-        ``node`` with ``max_tokens`` matched positions: a handle on the
-        node's ancestor path or in its subtree is sound iff its boundary
-        ``tokens`` is covered both by the borrower's match (the state
-        integrates only tokens the borrower shares) and by the holder's
-        own root path (the tree vouches for exactly that run — a
-        registration truncated by unsealed/dropped pages may sit above
-        its snapshot's boundary). The deepest such handle skips the most
-        compute. Tree traversal lives with the tree
-        (:meth:`RadixKVIndex.payload_candidates`)."""
-        best = None
-        for h, depth in self.kv.radix.payload_candidates(node):
-            if (isinstance(h, SnapshotHandle) and h.live and h.kind == "point"
-                    and h.tokens <= min(max_tokens, depth)
-                    and (best is None or h.tokens > best.tokens)):
-                best = h
-        return best
-
     def _compute_reuse(self, match: PrefixMatch, toks: np.ndarray) -> tuple:
-        """(tokens of the prompt the compute plane may skip, the snapshot
-        to seed from, sub-page tail tokens used). Requires a donor
-        snapshot valid at a boundary covering the whole meta/frontend
-        region (extend cannot restart mid-meta). At least one token always
-        runs — the last position's logits seed the first sampled token.
+        """(tokens of the prompt the compute plane may skip, sub-page tail
+        tokens used). The matched pages are the compute state: the hit is
+        a page-table splice, and only a sub-page tail copies (page rows,
+        DESIGN.md §9). At least one token always runs — the last
+        position's logits seed the first sampled token.
 
-        Positional stacks (attention/MLA) seed from a payload whose token
-        history covers the *resumption point*: with a sub-page tail
-        (DESIGN.md §9) that must be a payload in the tail child's subtree
-        (every prompt below it shares the tail run), so extend resumes
-        from the exact token boundary ``match.tokens + tail``; otherwise
-        the nearest payload at or below the match serves the page-aligned
-        boundary — stale entries beyond it stay masked. Point stacks
-        (SSM/hybrid) seed only from a snapshot captured at an
-        exactly-shared page-aligned boundary (DESIGN.md §8) — the deepest
-        one at or under the match length wins; a mid-page boundary never
-        has a capture, so tails stay memory-plane-only there (i.e.
-        unused)."""
+        Point stacks (SSM/hybrid) have no mid-page state, so tails stay
+        off and resumption is clamped down to the last page boundary: the
+        state page there holds the exact boundary state (DESIGN.md §8)."""
         plen = self.backend.prefix_len()
         L = toks.shape[0]
-        if match.tokens == 0 or not tfm.supports_extend(self.cfg):
-            return 0, None, 0
-        if self.paged:
-            # paged plane: the matched pages ARE the compute state — no
-            # donor snapshot exists or is needed. The hit is a page-table
-            # splice; only a sub-page tail copies (page rows, DESIGN.md §9).
-            # Point stacks have no mid-page state snapshot, so tails stay
-            # off and resumption is clamped DOWN to the last page boundary
-            # (the state page there holds the exact boundary state)
-            tail = (self.kv.tail_available(match)
-                    if self.ecfg.tail_copy
-                    and self.snapshot_kind == "positional" else 0)
-            reuse = max(0, min(match.tokens + tail - plen, L - 1))
-            tail = max(0, min(tail, reuse - (match.tokens - plen)))
-            if self.snapshot_kind == "point":
-                pt = self.ecfg.page_tokens
-                reuse = max(0, ((plen + reuse) // pt) * pt - plen)
-            return (reuse, None, tail) if reuse else (0, None, 0)
-        if self.snapshot_kind == "positional":
-            payload, tail = None, 0
-            avail = self.kv.tail_available(match)
-            if self.ecfg.tail_copy and avail:
-                p = self.kv.radix.subtree_payload(match.tail_node)
-                if (isinstance(p, SnapshotHandle) and p.live
-                        and p.tokens >= match.tokens + avail):
-                    payload, tail = p, avail
-            if payload is None:
-                payload = match.payload
-            if payload is None:
-                return 0, None, 0
-            reuse = max(0, min(match.tokens + tail - plen, L - 1))
-            # the one-token-always-computes clamp may land the resumption
-            # point back inside the tail; only the tokens actually skipped
-            # past the page boundary are worth copying in the memory plane
-            tail = max(0, min(tail, reuse - (match.tokens - plen)))
-            return (reuse, payload, tail) if reuse else (0, None, 0)
-        snap = self._point_snapshot_for(match.node,
-                                        min(match.tokens, plen + L - 1))
-        if snap is None or snap.tokens <= plen:
-            return 0, None, 0
-        return snap.tokens - plen, snap, 0
-
-    def _plan_point_captures(self, st: _SlotPrefill, reuse: int) -> None:
-        """Decide where a point-snapshot stack captures its recurrent
-        state (page-aligned absolute boundaries, DESIGN.md §8): at this
-        request's own match boundary — sharing *observed* there, so the
-        next borrower skips what this one had to recompute — and
-        speculatively at the prompt's last page boundary (serves multi-
-        turn/RAG traffic that extends this prompt). Boundaries the seeded
-        prefix already covers, or that an attention ring could not replay
-        from, are skipped."""
-        plen = self.backend.prefix_len()
-        pt = self.ecfg.page_tokens
-        end_b = ((plen + len(st.tokens)) // pt) * pt
-        match_b = st.match.tokens if st.match is not None else 0
-        if (match_b > plen and match_b - plen > reuse
-                and match_b - plen <= len(st.tokens) - 1
-                and self._point_boundary_ok(match_b)):
-            st.snap_match_at = match_b - plen
-        # the end capture is skipped only when the match capture already
-        # covers that exact boundary — NOT whenever the boundaries merely
-        # coincide: a full-prompt page-aligned match (match capture
-        # ineligible, at least one token must compute) with no usable
-        # snapshot would otherwise never acquire one
-        if (end_b > plen and end_b - plen > reuse
-                and (end_b - plen) != st.snap_match_at
-                and self._point_boundary_ok(end_b)):
-            st.snap_end_at = end_b - plen
-
-    def _point_boundary_ok(self, boundary: int) -> bool:
-        """A point capture at absolute position ``boundary`` is replayable
-        iff every attention ring in the stack still holds what a resumed
-        borrower would attend to: a full window (ring == window) always
-        does; a global or window-truncated ring must hold all of
-        [0, boundary)."""
-        from repro.models.attention import cache_len_for
-        for spec in self.cfg.layer_specs():
-            if spec.kind == "ssm":
-                continue
-            ring = cache_len_for(spec.window, self.ecfg.max_cache_len)
-            if spec.window is not None and spec.window <= ring:
-                continue
-            if boundary > ring:
-                return False
-        return True
+        if match.tokens == 0:
+            return 0, 0
+        tail = (self.kv.tail_available(match)
+                if self.ecfg.tail_copy
+                and self.snapshot_kind == "positional" else 0)
+        reuse = max(0, min(match.tokens + tail - plen, L - 1))
+        # the one-token-always-computes clamp may land the resumption
+        # point back inside the tail; only the tokens actually skipped
+        # past the page boundary are worth copying
+        tail = max(0, min(tail, reuse - (match.tokens - plen)))
+        if self.snapshot_kind == "point":
+            pt = self.ecfg.page_tokens
+            reuse = max(0, ((plen + reuse) // pt) * pt - plen)
+        return (reuse, tail) if reuse else (0, 0)
 
     def _admit(self, slot: int, req: Request) -> _SlotPrefill:
         ecfg = self.ecfg
         toks, chunk, key = self._prep(req)
         self._prep_cache.pop(req.request_id, None)
         match = None
-        reuse, snap, tail = 0, None, 0
+        reuse, tail = 0, 0
         if ecfg.prefix_caching:
             match = self.kv.match_prefix(key)
-            reuse, snap, tail = self._compute_reuse(match, toks)
+            reuse, tail = self._compute_reuse(match, toks)
         # point stacks always chunk on the position-space page grid — the
         # partition, not just the tokens, determines the recurrent
         # state's rounding, so warm/cold/migrated runs must all cut
@@ -1245,19 +954,9 @@ class ServeEngine:
         grid = ecfg.page_tokens if self.snapshot_kind == "point" else None
         st = _SlotPrefill(req=req, tokens=toks, chunk=chunk,
                           key=key, match=match, done=reuse, grid=grid)
-        if ecfg.prefix_caching and key is not None \
-                and self.snapshot_kind == "point" and not self.paged:
-            # ring path only: the paged plane captures point state in its
-            # page pool at EVERY page boundary as a side effect of compute
-            # (state pages, DESIGN.md §10) — no snapshot planning needed
-            self._plan_point_captures(st, reuse)
         if reuse:
-            # the hit is real in the compute plane: on the ring path, seed
-            # the slot's caches from the donor snapshot (a full cache-tree
-            # copy); on the paged plane there is nothing to copy — the
-            # matched pages are spliced into the session's table below
-            if snap is not None:
-                self.backend.seed_slot(slot, snap.caches)
+            # the hit is real in the compute plane: the matched pages are
+            # spliced into the session's table below, nothing is copied
             self.prefix_compute_hits += 1
             self.prefill_tokens_skipped += reuse
             req.prompt_pos = min(reuse, req.prompt_len)
@@ -1265,10 +964,10 @@ class ServeEngine:
         # nodes cannot be evicted between planning and execution; the
         # compute-vetted tail is copied into the session's own page there
         self.kv.open_session(req.request_id, match=match, tail_tokens=tail)
-        if self.paged and tail:
+        if tail:
             # the memory plane just copied the tail into the session's own
             # open page; mirror it on the compute plane — the ONLY copy a
-            # paged hit performs, and only for mid-page resumption
+            # hit performs, and only for mid-page resumption
             src = match.tail_node.pages[0].compute_page
             dst = self.kv.sessions[req.request_id].pages[-1].compute_page
             if src is not None and dst is not None:
@@ -1318,101 +1017,13 @@ class ServeEngine:
         _, _, key = self._prep(req)
         return self.kv.match_len(key)
 
-    # -- compute-plane snapshots & cross-replica migration -------------
-    @staticmethod
-    def _tree_nbytes(caches) -> float:
-        return float(sum(a.size * a.dtype.itemsize
-                         for a in jax.tree.leaves(caches)))
-
-    def _publish_snapshot(self, caches, kind: str = "positional",
-                          tokens: int = 0) -> Optional[SnapshotHandle]:
-        """Carve a donor cache snapshot out of the KV tier budget (metered
-        write). If the tier has no headroom the snapshot is not published
-        — the prefix still shares pages, it just cannot donate compute.
-        Never a pressure-ledger event: a snapshot is an optional
-        acceleration, not required state. ``kind``/``tokens`` record the
-        per-architecture validity contract (DESIGN.md §8)."""
-        nbytes = self._tree_nbytes(caches)
-        rid = self.mem.write_region(self.ecfg.kv_tier, "kv:snapshot", nbytes,
-                                    expected_lifetime_s=self.ecfg.expected_session_s)
-        if rid is None:
-            return None
-        self.snapshots_published += 1
-        return SnapshotHandle(caches, nbytes, self.mem, rid,
-                              kind=kind, tokens=tokens)
-
-    def _donation_fn(self, st: _SlotPrefill, slot: int):
-        """The payload factory a finished prompt registers with its prefix
-        (resolved by the manager only if the deepest node's payload slot
-        is free, so a metered snapshot region is never written for
-        nothing).
-
-        Positional stacks donate the slot's final ring caches — valid for
-        any shorter page-aligned borrower via position masking — unless
-        the prompt overflowed the smallest ring and wrapped it (the early
-        positions a shorter borrower needs are gone; pages still publish
-        for memory-plane reuse). Point stacks donate the state captured at
-        the prompt's last page boundary, when the prefill passed through
-        one (DESIGN.md §8)."""
-        plen = self.backend.prefix_len()
-        if self.paged:
-            # paged plane: the registered pages are compute-ready as-is —
-            # a donor snapshot would duplicate state the tree already owns
-            # (satellite of DESIGN.md §10: snapshot_bytes stays 0)
-            return None
-        if self.snapshot_kind == "positional":
-            if not (tfm.supports_extend(self.cfg)
-                    and plen + len(st.tokens) <= self._min_ring_len()):
-                return None
-            return lambda: self._publish_snapshot(
-                self.backend.snapshot_slot(slot), kind="positional",
-                tokens=plen + len(st.tokens))
-        if st.point_caches is None or st.snap_end_at is None:
-            return None
-        caches, tokens = st.point_caches, plen + st.snap_end_at
-        return lambda: self._publish_snapshot(caches, kind="point",
-                                              tokens=tokens)
-
-    def _attach_match_snapshot(self, st: _SlotPrefill, slot: int) -> None:
-        """Observed-share capture (point stacks): this request matched a
-        prefix in the memory plane but no point snapshot existed at its
-        boundary, so it had to recompute the shared run — capture the
-        state now that its prefill crossed exactly that boundary and hang
-        it off the matched node (pinned by this session, so it cannot have
-        been evicted), turning the *next* borrower's match into a real
-        compute skip."""
-        node = st.match.node if st.match is not None else None
-        if node is None or node.parent is None or node.payload is not None:
-            return
-        handle = self._publish_snapshot(
-            self.backend.snapshot_slot(slot), kind="point",
-            tokens=self.backend.prefix_len() + st.done)
-        if handle is not None:
-            node.payload = handle
-
-    def _snapshot_compatible(self, caches) -> bool:
-        """A foreign snapshot is seedable only when its tree matches this
-        backend's per-slot cache template exactly (identical replicas).
-        The template spec (structure + leaf shapes/dtypes) is derived once
-        from the resident caches — no per-import slot materialization."""
-        if self._snap_spec is None:
-            self._snap_spec = (
-                jax.tree.structure(self.backend.caches),
-                [((a.shape[0], 1) + a.shape[2:], a.dtype)
-                 for a in jax.tree.leaves(self.backend.caches)])
-        structure, leaves = self._snap_spec
-        if structure != jax.tree.structure(caches):
-            return False
-        return all(a.shape == shape and a.dtype == dtype
-                   for a, (shape, dtype) in zip(jax.tree.leaves(caches),
-                                                leaves))
-
+    # -- cross-replica migration ----------------------------------------
     def export_prefix(self, key_tokens) -> Optional[dict]:
         """Donor half of a cross-replica prefix migration: match the
         longest published prefix of ``key_tokens`` (position-space), read
-        its pages and covering snapshot out of this replica's tiers
-        (metered reads — the transfer is not free for the donor), and
-        return the page metadata + compute snapshot for the receiver."""
+        its pages out of this replica's tiers (metered reads — the
+        transfer is not free for the donor), and return the page metadata
+        and the matched compute pages for the receiver."""
         if not self.ecfg.prefix_caching:
             return None
         # non-bumping walk: a migration probe is not local reuse — it must
@@ -1424,99 +1035,48 @@ class ServeEngine:
             return None
         kv_bytes = 0.0
         for p in m.pages:
-            # paged point stacks: the page's region also carries its
-            # recurrent-state snapshot, which the transfer ships too
+            # point stacks: the page's region also carries its
+            # recurrent-state page, which the transfer ships too
             nb = p.n_tokens * self.kv.kv_bytes_token + self.kv.state_bytes_page
             if p.region_id is not None and nb > 0:
                 self.mem.read_region(p.region_id, nb, sequential=True)
             kv_bytes += nb
-        # per-kind snapshot resolution (DESIGN.md §8): positional — the
-        # nearest payload below the match covers it via position masking;
-        # point — the deepest snapshot at a boundary the match covers
-        if self.snapshot_kind == "point":
-            handle = self._point_snapshot_for(m.node, m.tokens)
-        else:
-            handle = (m.payload if isinstance(m.payload, SnapshotHandle)
-                      and m.payload.live else None)
-        caches, snap_bytes, skind, stok = None, 0.0, "positional", 0
-        if handle is not None:
-            self.mem.read_region(handle.region_id, handle.nbytes)
-            caches, snap_bytes = handle.caches, handle.nbytes
-            skind, stok = handle.kind, handle.tokens
         out = {"tokens": np.asarray(key_tokens)[:m.tokens],
                "n_tokens": m.tokens, "kv_bytes": kv_bytes,
-               "caches": caches, "snapshot_bytes": snap_bytes,
-               "snap_kind": skind, "snap_tokens": stok,
                "hot": m.node.hot, "hits": m.node.hits}
-        if self.paged:
-            # paged plane: the pages themselves are the compute state — no
-            # snapshot exists; ship the matched compute pages instead. The
-            # page-read metering above already charged the transfer.
-            ids = [p.compute_page for p in m.pages]
-            if all(i is not None for i in ids):
-                out["page_data"] = self.backend.export_pages(ids)
-                out["page_tokens"] = self.kv.page_tokens
+        ids = [p.compute_page for p in m.pages]
+        if all(i is not None for i in ids):
+            out["page_data"] = self.backend.export_pages(ids)
+            out["page_tokens"] = self.kv.page_tokens
         return out
 
-    def import_prefix(self, tokens, caches=None, hot: bool = False,
-                      hits: int = 0, snap_kind: str = "positional",
-                      snap_tokens: int = 0, page_data=None,
+    def import_prefix(self, tokens, hot: bool = False, hits: int = 0,
+                      page_data=None,
                       page_tokens: Optional[int] = None) -> dict:
         """Receiver half: adopt the pages (metered writes into this
         replica's tiers; a donor-hot prefix lands in the hot tier with
-        long retention — placement re-solved on arrival) and re-publish
-        the donor's compute snapshot under a locally-metered handle. A
-        *point* snapshot is only republished when the adoption kept every
-        token up to its boundary — a truncated adoption cannot vouch for
-        tokens beyond what was grafted (DESIGN.md §8).
-
-        Paged receivers take ``page_data``/``page_tokens`` instead of a
-        snapshot: the donor's compute pages are written straight into the
-        pool pages the adoption allocated — a later local hit on the
-        grafted prefix is then a zero-copy page-table splice. Data that
-        does not match this replica's page geometry or cache families is
-        rejected *before* adoption (a graft this engine cannot compute on
-        would poison later hits)."""
-        if self.paged:
-            if (page_data is None or page_tokens != self.kv.page_tokens
-                    or not self.backend.pages_compatible(page_data)):
-                return {"new_tokens": 0, "total_tokens": 0,
-                        "snapshot_bytes": 0.0}
-            new_tokens, total, node = self.kv.adopt_prefix(tokens, hot=hot,
-                                                           hits=hits)
-            inserted = self.kv._last_adopt_pages
-            if inserted:
-                # the graft kept pages [dup, total) — slice the donor data
-                # to the pages actually inserted and write them in place
-                pt = self.kv.page_tokens
-                dup_pages = (total - new_tokens) // pt
-                ids = [p.compute_page for p in inserted]
-                data = jax.tree.map(
-                    lambda a: a[:, dup_pages:dup_pages + len(ids)],
-                    page_data)
-                self.backend.import_pages(ids, data)
-            return {"new_tokens": new_tokens, "total_tokens": total,
-                    "snapshot_bytes": 0.0}
-        new_tokens, total, node = self.kv.adopt_prefix(tokens, hot=hot,
-                                                       hits=hits)
-        snap_bytes = 0.0
-        if (node is not None and node.payload is None and caches is not None
-                and tfm.supports_extend(self.cfg)
-                and (snap_kind != "point" or 0 < snap_tokens <= total)
-                and self._snapshot_compatible(caches)):
-            handle = self._publish_snapshot(caches, kind=snap_kind,
-                                            tokens=snap_tokens)
-            if handle is not None:
-                node.payload = handle
-                snap_bytes = handle.nbytes
-        return {"new_tokens": new_tokens, "total_tokens": total,
-                "snapshot_bytes": snap_bytes}
-
-    def live_snapshot_bytes(self) -> float:
-        """Bytes of metered donor snapshots currently resident in the KV
-        tier (the engine-report ``snapshot_bytes`` figure)."""
-        return sum(n.payload.nbytes for n in self.kv.radix.nodes()
-                   if isinstance(n.payload, SnapshotHandle) and n.payload.live)
+        long retention — placement re-solved on arrival) and write the
+        donor's compute pages ``page_data`` straight into the pool pages
+        the adoption allocated — a later local hit on the grafted prefix
+        is then a zero-copy page-table splice. Data that does not match
+        this replica's page geometry or cache families is rejected
+        *before* adoption (a graft this engine cannot compute on would
+        poison later hits)."""
+        if (page_data is None or page_tokens != self.kv.page_tokens
+                or not self.backend.pages_compatible(page_data)):
+            return {"new_tokens": 0, "total_tokens": 0}
+        new_tokens, total, _ = self.kv.adopt_prefix(tokens, hot=hot,
+                                                    hits=hits)
+        inserted = self.kv._last_adopt_pages
+        if inserted:
+            # the graft kept pages [dup, total) — slice the donor data to
+            # the pages actually inserted and write them in place
+            dup_pages = (total - new_tokens) // self.kv.page_tokens
+            ids = [p.compute_page for p in inserted]
+            data = jax.tree.map(
+                lambda a: a[:, dup_pages:dup_pages + len(ids)], page_data)
+            self.backend.import_pages(ids, data)
+        return {"new_tokens": new_tokens, "total_tokens": total}
 
     # -- paged compute plane (DESIGN.md §10) ---------------------------
     def _on_page_alloc(self, page) -> None:
@@ -1572,8 +1132,7 @@ class ServeEngine:
         boundary state when q0 opens a page, else the open page's running
         state (nothing for an empty history: that read is null page 0).
         Bytes are charged at the accounting scale per layer, against each
-        page's actual tier — replacing the ring path's synthetic
-        whole-history read_all."""
+        page's actual tier."""
         pages = self.kv.sessions[rid].pages
         pt = self.kv.page_tokens
         hi = -(-q1 // pt)  # pages the kernel gathers: [lo_layer, hi)
@@ -1596,9 +1155,8 @@ class ServeEngine:
         (corrected + re-armed, metered through the lifecycle); otherwise
         sampled flips land in the compute page in place, so corruption
         propagates through the real decode math."""
-        if self.faults is None or not self.paged:
+        if self.faults is None:
             return
-        import jax
         protected = getattr(self.mem, "ecc_profile", "off") != "off"
         for slot in slots:
             rid = self.sched.active[slot].request_id
@@ -1633,7 +1191,7 @@ class ServeEngine:
                     self.backend.import_pages(
                         [pid], jax.tree.unflatten(treedef, out_leaves))
 
-    def _account_chunk_kv(self, st: _SlotPrefill, ck: PrefillChunk) -> None:
+    def _account_chunk_kv(self, ck: PrefillChunk) -> None:
         """This chunk's tokens enter the paged KV — unless a shared prefix
         already holds them (prefix reuse is counted once at open)."""
         target = ck.offset + len(ck.tokens)  # kv tokens incl. meta/frontend
@@ -1684,39 +1242,24 @@ class ServeEngine:
         with span("engine.prefill", rid=ck.request_id, tokens=len(ck.tokens),
                   offset=ck.offset, first=ck.first):
             st = self._inflight[ck.slot]
-            if self.paged:
-                # pages must exist BEFORE compute: the kernel writes this
-                # chunk's KV into the session's own pages in place
-                with span("kv.account"):
-                    self._account_chunk_kv(st, ck)
-                    table = self._session_table(ck.request_id)
-                tok = self.backend.run_prefill_chunk(ck, page_table=table)
-            else:
-                tok = self.backend.run_prefill_chunk(ck)
+            # pages must exist BEFORE compute: the step program writes this
+            # chunk's KV into the session's own pages in place
+            with span("kv.account"):
+                self._account_chunk_kv(ck)
+                table = self._session_table(ck.request_id)
+            tok = self.backend.run_prefill_chunk(ck, page_table=table)
             with span("mrm.meter"):
                 self.memplane.weight_pass()
-                if self.paged:
-                    # meter the kernel's actual page-gather stream: queries
-                    # at [q0, q1) — the first chunk embeds the meta prefix,
-                    # so its oldest query is position 0
-                    q0 = 0 if ck.first else ck.offset
-                    self._meter_paged_reads(ck.request_id, q0,
-                                            ck.offset + len(ck.tokens))
+                # meter the page-gather stream: queries at [q0, q1) — the
+                # first chunk embeds the meta prefix, so its oldest query
+                # is position 0
+                q0 = 0 if ck.first else ck.offset
+                self._meter_paged_reads(ck.request_id, q0,
+                                        ck.offset + len(ck.tokens))
             self.prefill_chunks_run += 1
             self.sched.stats.prefill_chunks += 1
-            if not self.paged:
-                with span("kv.account"):
-                    self._account_chunk_kv(st, ck)
             st.done += len(ck.tokens)
             st.req.prompt_pos = min(st.done, st.req.prompt_len)
-            # point-snapshot stacks: the recurrent state is only capturable
-            # at the boundary itself (chunks were split to land here)
-            if st.snap_match_at is not None and st.done == st.snap_match_at:
-                self._attach_match_snapshot(st, ck.slot)
-                st.snap_match_at = None
-            if (st.snap_end_at is not None and st.done == st.snap_end_at
-                    and st.point_caches is None):
-                st.point_caches = self.backend.snapshot_slot(ck.slot)
             rpt.prefill_tokens += len(ck.tokens)
             self.prefill_tokens_computed += len(ck.tokens)
             if ck.last:
@@ -1729,9 +1272,7 @@ class ServeEngine:
                 self.tokens_generated += 1
                 if st.key is not None:
                     with span("engine.register"):
-                        self.kv.register_prefix(
-                            req.request_id, st.key,
-                            payload=self._donation_fn(st, ck.slot))
+                        self.kv.register_prefix(req.request_id, st.key)
                 self.sched.mark_decoding(ck.slot)
                 del self._inflight[ck.slot]
 
@@ -1740,32 +1281,23 @@ class ServeEngine:
         reads, then emit every row's token and release the rows that
         finished (a session is metered before it is closed)."""
         with span("engine.decode", rows=len(slots)):
-            if self.paged:
-                with span("kv.account"):
-                    # the new token's page must exist before the kernel
-                    # writes its KV row in place
-                    for slot in slots:
-                        self.kv.append_tokens(
-                            self.sched.active[slot].request_id, 1)
-                    self._inject_faults(slots)
-                    tables, audit = self._decode_tables(slots)
-                next_np = self.backend.run_decode(slots, page_tables=tables,
-                                                  audit_pages=audit)
-            else:
-                next_np = self.backend.run_decode(slots)
+            with span("kv.account"):
+                # the new token's page must exist before the step program
+                # writes its KV row in place
+                for slot in slots:
+                    self.kv.append_tokens(
+                        self.sched.active[slot].request_id, 1)
+                self._inject_faults(slots)
+                tables, audit = self._decode_tables(slots)
+            next_np = self.backend.run_decode(slots, page_tables=tables,
+                                              audit_pages=audit)
             with span("mrm.meter"):
                 self.memplane.weight_pass()
                 for slot in slots:
-                    rid = self.sched.active[slot].request_id
-                    if self.paged:
-                        # one query at the just-written position: the
-                        # kernel gathered the session's pages, not a
-                        # synthetic whole-history read
-                        p = int(self.backend.positions[slot])
-                        self._meter_paged_reads(rid, p, p + 1)
-                    else:
-                        self.kv.read_all(rid)
-                        self.kv.append_tokens(rid, 1)
+                    # one query at the just-written position
+                    p = int(self.backend.positions[slot])
+                    self._meter_paged_reads(
+                        self.sched.active[slot].request_id, p, p + 1)
             with span("engine.emit"):
                 finished: List[int] = []
                 for slot in slots:
@@ -1815,18 +1347,14 @@ class ServeEngine:
         reads = sum(d.stats.read_bytes for d in self.mem.devices.values())
         writes = sum(d.stats.write_bytes for d in self.mem.devices.values())
         steady_writes = max(writes - self.weight_bytes, 1e-9)
-        snapshot_bytes = self.live_snapshot_bytes()
         prefix = self.kv.prefix_report()
         prefix["compute_hits"] = self.prefix_compute_hits
         prefix["tokens_skipped_compute"] = self.prefill_tokens_skipped
         prefix["snapshot_kind"] = self.snapshot_kind
         prefix["hot_tier"] = self.memplane.hot_tier
-        prefix["snapshots_published"] = self.snapshots_published
-        prefix["snapshot_bytes"] = snapshot_bytes
         return {
             "steps": self.steps,
             "kernel_read_bytes": self.kernel_read_bytes,
-            "seed_copy_bytes": self.backend.seed_copy_bytes,
             "tokens_generated": self.tokens_generated,
             "finished": self.sched.stats.finished,
             "abandoned": self.sched.stats.abandoned,
@@ -1838,7 +1366,6 @@ class ServeEngine:
             "steady_rw_ratio": reads / steady_writes,
             "memory": rep,
             "kv_live_pages": self.kv.live_pages(),
-            "snapshot_bytes": snapshot_bytes,
             "dropped_allocs": self.kv.dropped_allocs,
             "pressure": self.kv.pressure_report(),
             "prefill_chunks": self.prefill_chunks_run,

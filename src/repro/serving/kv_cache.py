@@ -7,7 +7,8 @@ argument). Page *placement and lifetime* go through `repro.core`:
 
 - allocation -> MemorySystem.write_region with a DCM retention programmed
   from the session's expected remaining lifetime;
-- every decode step reads all live pages sequentially (instrumented);
+- every step reads the pages its step program gathers, sequentially
+  (``read_pages``, metered by the engine);
 - each appended token accumulates into the open page; page-full -> sealed,
   and the open page region is rewritten (append-only write pattern);
 - session end -> regions released (soft state dropped, per §4).
@@ -26,8 +27,9 @@ page-aligned boundary are *copied* out of the holder's page into the
 borrower's own fresh open page — metered as a sequential read plus the
 ordinary page write, strictly cheaper than recomputing those tokens under
 the per-tier latency model (a recompute also streams the weights). The
-engine decides when the copy is worthwhile (it needs a compute snapshot
-whose history covers the tail); the manager owns the byte movement.
+engine decides when the copy is worthwhile (positional stacks only: a
+point stack's recurrent state has no mid-page resumption point); the
+manager owns the byte movement.
 
 Retention is programmed from *observed reuse* (paper §4), with every
 transition routed through one
@@ -53,14 +55,15 @@ drop:
 Every failed allocation ends in exactly one recorded resolution; silent
 ``dropped_allocs`` only remain under the legacy ``policy="none"``.
 
-The JAX compute path keeps its own dense ring caches (models/attention.py);
+Each page may own one compute page of the engine's paged compute plane
+(``Page.compute_page``, set through ``on_page_alloc``/``on_page_release``);
 this manager is the memory control plane that decides *where those bytes
 live* and meters the device traffic.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from repro.configs.base import ModelConfig
 from repro.core.simulator import MemorySystem
@@ -179,11 +182,9 @@ class PagedKVManager:
         self.high_watermark = high_watermark
         self.tail_copy = tail_copy
         self.kv_bytes_token = cfg.kv_bytes_per_token()
-        # paged point stacks (DESIGN.md §10) pin one recurrent-state
-        # snapshot per page alongside the KV token stream, so every page
-        # region is sized (and its writes metered) with those bytes. Zero
-        # on the ring path — there state lives in the engine's metered
-        # SnapshotHandle regions and would be double-counted here.
+        # point stacks (DESIGN.md §10) pin one recurrent-state snapshot
+        # per page alongside the KV token stream, so every page region is
+        # sized (and its writes metered) with those bytes.
         self.state_bytes_page = float(state_bytes_page)
         self.page_bytes = (self.kv_bytes_token * page_tokens
                            + self.state_bytes_page)
@@ -247,8 +248,8 @@ class PagedKVManager:
         """Sub-page tail tokens the memory plane can actually serve for
         this match: the holder's page must be resident (not dropped, live
         region — unless the stack has no KV byte stream at all). The
-        engine combines this with compute-side validity (a snapshot whose
-        history covers the tail) before asking for the copy."""
+        engine combines this with compute-side validity (positional
+        stacks only) before asking for the copy."""
         if (not self.tail_copy or match is None or match.tail_node is None
                 or match.tokens == 0 or not match.tail_node.pages):
             return 0
@@ -315,17 +316,13 @@ class PagedKVManager:
         self.tail_copy_bytes += 2.0 * nbytes
         self.prefix_tokens_reused += tail_tokens
 
-    def register_prefix(self, session_id: int, tokens: Sequence,
-                        payload: Any = None) -> int:
+    def register_prefix(self, session_id: int, tokens: Sequence) -> int:
         """Publish this session's sealed leading pages into the radix tree
         under the token path (call after the prompt's KV is appended).
         ``tokens[i*page_tokens:(i+1)*page_tokens]`` must be what the i-th
         page covers. The session's pin moves to the deepest node so its
-        freshly published prefix cannot be evicted under it. ``payload``
-        may be the compute handle itself or a zero-arg factory for one
-        (resolved only if the deepest node's payload slot is free — the
-        engine's snapshots carry metered regions that must not be written
-        for nothing). Returns the number of newly inserted pages."""
+        freshly published prefix cannot be evicted under it. Returns the
+        number of newly inserted pages."""
         s = self.sessions[session_id]
         run: List[Page] = []
         for p in s.pages:
@@ -335,21 +332,9 @@ class PagedKVManager:
                 break
         n = min(len(run), len(tokens) // self.page_tokens)
         if n == 0:
-            if not callable(payload):
-                self._release_payload_obj(payload)
             return 0
         _, inserted, node = self.radix.insert(
             tokens[:n * self.page_tokens], run[:n], self.mem.now)
-        if payload is not None and node is not self.radix.root \
-                and node.payload is None:
-            # a callable payload is a factory: resolved only when the node
-            # actually takes it, so a metered snapshot region is never
-            # written just to be released (occupied payload slot)
-            obj = payload() if callable(payload) else payload
-            if obj is not None:
-                node.payload = obj
-        elif not callable(payload):
-            self._release_payload_obj(payload)
         for p in inserted:
             p.refcount += 1  # the tree holds its own reference
         if node is not self.radix.root:
@@ -439,19 +424,10 @@ class PagedKVManager:
         if self.on_prefix_insert is not None:
             self.on_prefix_insert(tokens)
 
-    @staticmethod
-    def _release_payload_obj(payload: Any) -> None:
-        """Compute-plane payloads may carry a metered backing region (the
-        engine's SnapshotHandle); release it when the payload dies."""
-        if payload is not None and hasattr(payload, "release"):
-            payload.release()
-
     def _on_leaf_removed(self, victim: RadixNode) -> None:
-        """A leaf left the tree (pressure eviction or cold decay): release
-        its metered compute snapshot and invalidate fleet-directory
-        ownership of the token run it covered."""
-        self._release_payload_obj(victim.payload)
-        victim.payload = None
+        """A leaf left the tree (pressure eviction or cold decay):
+        invalidate fleet-directory ownership of the token run it
+        covered."""
         if self.on_prefix_evict is not None and victim.evicted_path is not None:
             self.on_prefix_evict(victim.evicted_path, victim.n_tokens)
 
@@ -641,30 +617,14 @@ class PagedKVManager:
             page.tier = tier
             page.dropped = False
 
-    def read_all(self, session_id: int) -> float:
-        """One decode step reads the whole cache sequentially (paper §2.2).
-        Returns bytes read (recomputed pages included once re-materialized)."""
-        s = self.sessions[session_id]
-        total = 0.0
-        for page in s.pages:
-            if page.dropped:
-                self._rematerialize(s, page)
-            if page.region_id is not None:
-                self.mem.read_region(page.region_id,
-                                     page.n_tokens * self.kv_bytes_token,
-                                     sequential=True)
-                total += page.n_tokens * self.kv_bytes_token
-        return total
-
     def read_pages(self, session_id: int, page_bytes: Sequence[float]) -> float:
         """Meter the paged kernel's actual per-page read stream
         (DESIGN.md §10): ``page_bytes[i]`` is the byte count the kernel's
         DMA pulled from the session's i-th page this step — computed by
         the engine from the layer stack and each layer's window, so tier
         traffic is charged for exactly what the gather touched (zero for
-        pages outside every window) instead of a synthetic whole-cache
-        read. Dropped pages the kernel touched are re-materialized first.
-        Returns total bytes metered."""
+        pages outside every window). Dropped pages the kernel touched are
+        re-materialized first. Returns total bytes metered."""
         s = self.sessions[session_id]
         total = 0.0
         for page, nbytes in zip(s.pages, page_bytes):

@@ -4,9 +4,9 @@ The paper's core claim is that inference soft state is write-once/read-many
 and the *system* should manage its retention and placement (§2.2, §4);
 shared prompt prefixes are the purest instance.  This module is the one
 prefix abstraction every serving layer shares (DESIGN.md §6): the
-`PagedKVManager` hangs its shared pages off the tree, the engine hangs its
-compute-plane cache snapshots off it (the `payload` slot), the scheduler
-scores admissions by `match_len`, and the cluster frontend routes by it.
+`PagedKVManager` hangs its shared pages off the tree (and with them the
+compute pages the engine resumes on), the scheduler scores admissions by
+`match_len`, and the cluster frontend routes by it.
 
 Shape (after the sglang RadixCache design, adapted to page granularity):
 
@@ -21,9 +21,7 @@ Shape (after the sglang RadixCache design, adapted to page granularity):
   least-recently-accessed goes first, and freeing a leaf may expose its
   parent as the next candidate;
 - `hits` counts how often a node's tokens were reused — the observed-reuse
-  signal the manager's retention programming (DCM §4) keys off;
-- `payload` is an opaque compute-plane handle (the engine stores the donor
-  slot's ring-cache snapshot here so a hit can skip prefill compute).
+  signal the manager's retention programming (DCM §4) keys off.
 
 The tree never touches the memory simulator: page lifetime side effects
 (refcounts, region release) belong to the caller.
@@ -55,7 +53,7 @@ def _tok_key(t):
 
 class RadixNode:
     __slots__ = ("key", "pages", "children", "parent", "lock_ref",
-                 "last_access", "hits", "payload", "hot", "migrated",
+                 "last_access", "hits", "hot", "migrated",
                  "evicted_path")
 
     def __init__(self, key: tuple, pages: List[Any],
@@ -67,7 +65,6 @@ class RadixNode:
         self.lock_ref = 0                   # live sessions pinning this path
         self.last_access = now
         self.hits = 0                       # reuse count (retention signal)
-        self.payload: Any = None            # opaque compute-plane handle
         self.hot = False                    # promoted to long retention
         self.migrated = False               # grafted from another replica
         self.evicted_path: Optional[tuple] = None  # full key at eviction
@@ -91,7 +88,6 @@ class PrefixMatch:
     tokens: int = 0                      # matched token count (page-aligned)
     pages: List[Any] = field(default_factory=list)
     node: Optional[RadixNode] = None     # deepest matched node (lock target)
-    payload: Any = None                  # nearest compute handle covering it
     tail_tokens: int = 0                 # sub-page tail beyond the boundary
     tail_node: Optional[RadixNode] = None  # child holding the tail's page
 
@@ -129,7 +125,7 @@ class RadixKVIndex:
 
     def _split(self, node: RadixNode, n_pages: int, now: float) -> RadixNode:
         """Split `node` so its first `n_pages` pages become a new parent;
-        the remainder stays on `node` (payload/hits travel with the deep
+        the remainder stays on `node` (its hits travel with the deep
         half — they describe the full original run)."""
         pt = self.page_tokens
         head = RadixNode(node.key[:n_pages * pt], node.pages[:n_pages],
@@ -191,7 +187,6 @@ class RadixKVIndex:
                 n.last_access = now
             if m.tokens and bump_hits:
                 n.hits += 1
-        m.payload = self._nearest_payload(m.node)
         if with_tail and limit > m.tokens:
             m.tail_tokens, m.tail_node = self._tail_of(m.node, tokens,
                                                        m.tokens, limit)
@@ -235,49 +230,6 @@ class RadixKVIndex:
             node = child
         return matched
 
-    def _nearest_payload(self, node: RadixNode) -> Any:
-        """A compute handle valid for a match ending at `node`: any payload
-        at or below it (every descendant's prompt starts with this path)."""
-        stack = [node]
-        while stack:
-            n = stack.pop()
-            if n.payload is not None:
-                return n.payload
-            stack.extend(n.children.values())
-        return None
-
-    def subtree_payload(self, node: Optional[RadixNode]) -> Any:
-        """Public form of the nearest-payload walk rooted at ``node``.
-        The engine's sub-page tail reuse needs a payload whose token
-        history agrees *through the tail* — any payload in the tail
-        child's subtree qualifies, because every prompt below it starts
-        with that child's first page (DESIGN.md §9)."""
-        return None if node is None else self._nearest_payload(node)
-
-    def payload_candidates(self, node: RadixNode) -> Iterator[Tuple[Any, int]]:
-        """Yield ``(payload, holder_root_path_tokens)`` for every payload
-        on ``node``'s root path and in its subtree. The holder's root-path
-        length is the run the tree vouches for — callers filter on it (the
-        engine's per-family snapshot resolution, DESIGN.md §8) so the
-        tree-structure knowledge stays in this module."""
-        depth = 0
-        n = node
-        while n is not None:
-            depth += n.n_tokens
-            n = n.parent
-        d, n = depth, node
-        while n is not None:                # the path itself, deepest first
-            if n.payload is not None:
-                yield n.payload, d
-            d -= n.n_tokens
-            n = n.parent
-        stack = [(node, depth)]             # the subtree below
-        while stack:
-            n, d = stack.pop()
-            if n is not node and n.payload is not None:
-                yield n.payload, d
-            stack.extend((c, d + c.n_tokens) for c in n.children.values())
-
     @staticmethod
     def _path(node: RadixNode) -> List[RadixNode]:
         out = []
@@ -299,8 +251,8 @@ class RadixKVIndex:
         return out
 
     # -- insertion ------------------------------------------------------
-    def insert(self, tokens: Sequence, pages: List[Any], now: float,
-               payload: Any = None) -> Tuple[int, List[Any], RadixNode]:
+    def insert(self, tokens: Sequence, pages: List[Any],
+               now: float) -> Tuple[int, List[Any], RadixNode]:
         """Insert the page-aligned prefix `tokens` (``pages[i]`` covers
         tokens ``[i*pt, (i+1)*pt)``). Existing nodes keep their pages —
         duplicates from a concurrent cold start are NOT swapped in.
@@ -332,18 +284,16 @@ class RadixKVIndex:
             node.children[_page_key(tokens, done, pt)] = new
             inserted = list(new.pages)
             node = new
-        if payload is not None and node is not self.root and node.payload is None:
-            node.payload = payload
         return dup, inserted, node
 
     def graft(self, tokens: Sequence, pages: List[Any], now: float,
-              payload: Any = None, hits: int = 0,
+              hits: int = 0,
               hot: bool = False) -> Tuple[int, List[Any], RadixNode]:
         """Graft an externally-built path (cross-replica migration): insert
         it and stamp the reuse state it arrived with — the donor's observed
         hit count and hot flag travel with the data, so a migrated-hot
         prefix keeps its retention signal on the receiving replica."""
-        dup, inserted, node = self.insert(tokens, pages, now, payload=payload)
+        dup, inserted, node = self.insert(tokens, pages, now)
         if inserted and node is not self.root:
             node.hits = max(node.hits, hits)
             node.hot = node.hot or hot

@@ -2,9 +2,9 @@
 
 Covers the directory protocol (ownership registered on insert, dropped on
 leaf eviction), the migration path (pages and refcounts conserved on both
-replicas' ledgers, interconnect traffic metered), the migrated-hit vs
-cold-start decode equivalence guarantee, snapshot memory accounting, and
-the load-tiebreak fix (directory-owned hot-prefix bytes count as load).
+replicas' ledgers, interconnect traffic metered as the pages' bytes), the
+migrated-hit vs cold-start decode equivalence guarantee, and the
+load-tiebreak fix (directory-owned hot-prefix bytes count as load).
 """
 import jax
 import numpy as np
@@ -14,7 +14,7 @@ from repro.configs import get_config, reduced
 from repro.core.memclass import HBM3E, MRM_RRAM
 from repro.core.simulator import MemorySystem
 from repro.serving import (ClusterFrontend, EngineConfig, PrefixDirectory,
-                           ServeEngine, SnapshotHandle)
+                           ServeEngine)
 
 
 # ---------------------------------------------------------------------------
@@ -137,6 +137,34 @@ def test_migration_conserves_pages_and_refcounts(cluster_setup):
     assert fe.directory.lookup(key) == (0, None)
 
 
+def test_migration_moves_exactly_the_new_tokens_kv_bytes(cluster_setup):
+    """A migration's wire bytes are the KV bytes of the tokens newly
+    backed on the receiver, nothing more: attention pages carry no state,
+    and the engine publishes no compute snapshots beside its pages. The
+    donor reads the same bytes out of its tiers."""
+    full, cfg, params = cluster_setup
+    engines = [_mk_engine(full, cfg, params) for _ in range(2)]
+    fe = ClusterFrontend(engines, migrate_prefixes=True, migrate_load_gap=-1)
+    prompt = list(range(2, 66))               # 64 tokens = 4 pages
+    r0 = fe.submit(list(prompt), 4, session_key="a")
+    fe.run_until_idle()
+    home = fe.replica_of(r0)
+    other = 1 - home
+    kvb = engines[other].kv.kv_bytes_token
+    assert kvb > 0 and engines[other].kv.state_bytes_page == 0
+    reads0 = engines[home].mem.devices["mrm"].stats.read_bytes
+    r1 = fe.submit(list(prompt) + [400], 4, session_key="b")
+    assert fe.replica_of(r1) == other
+    assert fe.migrations == 1 and fe.migrated_tokens == 64
+    assert fe.migration_bytes == 64 * kvb
+    assert fe.migration_s == pytest.approx(
+        fe.migration_bytes / (fe.interconnect_gbps * 1e9))
+    donor_reads = engines[home].mem.devices["mrm"].stats.read_bytes - reads0
+    assert donor_reads == pytest.approx(fe.migration_bytes)
+    fe.run_until_idle()
+    assert fe.report()["interconnect"]["migration_bytes"] == 64 * kvb
+
+
 @pytest.fixture(scope="module")
 def f32_setup():
     full = get_config("deepseek-7b")
@@ -146,8 +174,8 @@ def f32_setup():
 
 
 def test_migrated_hit_decodes_identically_to_cold_start(f32_setup):
-    """Acceptance: a migrated hit (receiver seeded from the donor's
-    transferred snapshot, prefill extended from the boundary) decodes the
+    """Acceptance: a migrated hit (receiver computing on the donor's
+    transferred pages, prefill extended from the boundary) decodes the
     exact tokens a never-saw-the-prefix cold engine decodes."""
     full, cfg, params = f32_setup
     rng = np.random.default_rng(17)
@@ -190,25 +218,6 @@ def test_load_tiebreak_counts_directory_owned_prefix_bytes(cluster_setup):
     assert fe.route() == 0                     # bytes gone -> index order
 
 
-def test_snapshot_bytes_metered_against_kv_tier(cluster_setup):
-    """ROADMAP satellite: donor ring-cache snapshots are carved from the
-    KV tier budget (metered region write), reported as snapshot_bytes,
-    and released when their radix node leaves the tree."""
-    full, cfg, params = cluster_setup
-    eng = _mk_engine(full, cfg, params)
-    util0 = eng.mem.devices["mrm"].alloc.utilization
-    eng.submit(list(range(2, 66)), 4)
-    eng.run_until_idle()
-    rep = eng.report()
-    assert rep["snapshot_bytes"] > 0
-    assert rep["prefix"]["snapshots_published"] >= 1
-    assert eng.mem.devices["mrm"].alloc.utilization > util0
-    # eviction releases the snapshot region with the node
-    eng.kv.evict_prefixes()
-    assert eng.live_snapshot_bytes() == 0
-    assert eng.mem.devices["mrm"].alloc.utilization == 0.0
-
-
 def test_adopt_prefix_partial_under_pressure_keeps_ledger_balanced():
     """Adoption into a nearly-full tier truncates at a page boundary
     (optional transfer: no unresolved pressure events), and what was
@@ -229,17 +238,6 @@ def test_adopt_prefix_partial_under_pressure_keeps_ledger_balanced():
     assert new2 == 0 and total2 == new_tok
     kv.evict_prefixes()
     assert kv.radix.n_nodes() == 0
-    assert mem.devices["mrm"].alloc.utilization == 0.0
-
-
-def test_snapshot_handle_release_is_idempotent():
-    mem = MemorySystem({"mrm": (MRM_RRAM, 1 << 30)})
-    rid = mem.write_region("mrm", "snap", 1024, expected_lifetime_s=1.0)
-    h = SnapshotHandle(caches=None, nbytes=1024.0, mem=mem, region_id=rid)
-    assert h.live
-    h.release()
-    assert not h.live
-    h.release()                                # no double-free
     assert mem.devices["mrm"].alloc.utilization == 0.0
 
 
@@ -415,4 +413,3 @@ def test_fleet_report_interconnect_and_directory_sections(cluster_setup):
     assert rep["directory"]["entries"] > 0
     assert rep["directory"]["registrations"] > 0
     assert rep["prefix_hits_migrated"] >= 1
-    assert rep["snapshot_bytes"] >= 0

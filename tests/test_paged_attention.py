@@ -266,31 +266,22 @@ def _run(eng, prompts, max_new=6):
 
 
 def test_paged_hit_bit_equal_zero_copy_metered(arch_setup):
-    """The PR's acceptance bar, per positional family (GQA + MLA):
-    prefix-hit decode on the paged plane is bit-identical (fp32) to both
-    the ring path and a cold start, with copy bytes == 0 (no donor-seed
-    copy, no snapshot), and the KV tier's read stream equals the
-    kernel's analytically-metered page gathers exactly."""
+    """Per positional family (GQA + MLA): prefix-hit decode on the
+    paged plane is bit-identical (fp32) to a cold start, and the KV
+    tier's read stream equals the kernel's analytically-metered page
+    gathers exactly."""
     full, cfg, params = arch_setup
     rng = np.random.default_rng(5)
     base = rng.integers(2, 400, 40)
     prompts = [base, np.concatenate([base[:32], rng.integers(2, 400, 9)])]
 
-    ring = _mk_engine(full, cfg, params, paged_kernel=False)
-    rep_ring = _run(ring, prompts)
-    paged = _mk_engine(full, cfg, params, paged_kernel=True)
+    paged = _mk_engine(full, cfg, params)
     rep = _run(paged, prompts)
-    cold = _mk_engine(full, cfg, params, paged_kernel=True,
-                      prefix_caching=False)
+    cold = _mk_engine(full, cfg, params, prefix_caching=False)
     _run(cold, prompts)
 
-    assert _outputs(ring) == _outputs(paged) == _outputs(cold)
+    assert _outputs(paged) == _outputs(cold)
     assert rep["prefix"]["compute_hits"] >= 1
-    # zero-copy hit: the ring path pays a full cache-tree copy per hit,
-    # the paged path splices the page table
-    assert rep_ring["seed_copy_bytes"] > 0
-    assert rep["seed_copy_bytes"] == 0.0
-    assert rep["snapshot_bytes"] == 0.0 and rep_ring["snapshot_bytes"] > 0
     # metering: the KV tier read exactly what the kernel gathered (plus
     # the read half of any sub-page tail copy)
     assert rep["kernel_read_bytes"] > 0
@@ -302,7 +293,7 @@ def test_paged_hit_bit_equal_zero_copy_metered(arch_setup):
 def test_paged_subpage_tail_bit_equal():
     """Sub-page tail reuse on the paged plane: the ONLY bytes a hit ever
     copies are the tail rows of one page (copy_page_rows), and outputs
-    stay bit-identical to ring and cold runs."""
+    stay bit-identical to a cold run."""
     full = get_config("deepseek-7b")
     cfg = reduced(full, dtype="float32", param_dtype="float32")
     params = init_params(cfg, jax.random.key(0))
@@ -310,53 +301,43 @@ def test_paged_subpage_tail_bit_equal():
     head = list(rng.integers(2, 400, 55))   # straddles a 16-token page
     prompts = [head + list(rng.integers(2, 400, 9)) for _ in range(3)]
 
-    ring = _mk_engine(full, cfg, params, paged_kernel=False)
-    _run(ring, prompts)
-    paged = _mk_engine(full, cfg, params, paged_kernel=True)
-    rep = _run(paged, prompts)
-    cold = _mk_engine(full, cfg, params, paged_kernel=True,
-                      prefix_caching=False)
+    paged = _mk_engine(full, cfg, params)
+    _run(paged, prompts)
+    cold = _mk_engine(full, cfg, params, prefix_caching=False)
     _run(cold, prompts)
 
-    assert _outputs(ring) == _outputs(paged) == _outputs(cold)
+    assert _outputs(paged) == _outputs(cold)
     assert paged.kv.tail_hits > 0
-    assert rep["seed_copy_bytes"] == 0.0
     assert paged.prefill_tokens_computed < cold.prefill_tokens_computed
 
 
 def test_paged_migration_splices_pages(arch_setup):
-    """Cross-replica migration on the paged plane ships page data, not
-    snapshots: the receiver writes the donor's compute pages into its
-    pool, and a local hit on the grafted prefix decodes identically to
-    the donor — still zero copy bytes at admission."""
+    """Cross-replica migration on the paged plane ships page data: the
+    receiver writes the donor's compute pages into its pool, and a local
+    hit on the grafted prefix decodes identically to the donor."""
     full, cfg, params = arch_setup
     rng = np.random.default_rng(11)
     p = rng.integers(2, 400, 36)
-    donor = _mk_engine(full, cfg, params, paged_kernel=True)
+    donor = _mk_engine(full, cfg, params)
     _run(donor, [p], max_new=4)
-    recv = _mk_engine(full, cfg, params, paged_kernel=True)
+    recv = _mk_engine(full, cfg, params)
 
     key = donor.radix_key_for(list(p))
     exp = donor.export_prefix(key)
     assert exp is not None and exp.get("page_data") is not None
-    assert exp["snapshot_bytes"] == 0.0
-    imp = recv.import_prefix(exp["tokens"], caches=exp["caches"],
-                             hot=exp["hot"], hits=exp["hits"],
-                             snap_kind=exp["snap_kind"],
-                             snap_tokens=exp["snap_tokens"],
-                             page_data=exp["page_data"],
+    imp = recv.import_prefix(exp["tokens"], hot=exp["hot"],
+                             hits=exp["hits"], page_data=exp["page_data"],
                              page_tokens=exp["page_tokens"])
-    assert imp["total_tokens"] > 0 and imp["snapshot_bytes"] == 0.0
+    assert imp["total_tokens"] > 0
 
     rep = _run(recv, [p], max_new=4)
     d_out, r_out = list(donor.outputs[0]), list(recv.outputs[0])
     assert d_out == r_out
     assert rep["prefix"]["compute_hits"] == 1
-    assert rep["seed_copy_bytes"] == 0.0
 
     # geometry mismatch is rejected BEFORE adoption (a graft this engine
     # cannot compute on would poison later hits)
-    recv2 = _mk_engine(full, cfg, params, paged_kernel=True)
+    recv2 = _mk_engine(full, cfg, params)
     bad = recv2.import_prefix(exp["tokens"], page_data=exp["page_data"],
                               page_tokens=exp["page_tokens"] * 2)
     assert bad["total_tokens"] == 0
@@ -365,11 +346,10 @@ def test_paged_migration_splices_pages(arch_setup):
 
 @pytest.mark.parametrize("arch", ["mamba2-2.7b", "hymba-1.5b"])
 def test_paged_point_stack_hit_bit_equal(arch):
-    """paged_kernel=True is universal: SSM and hybrid stacks serve on
+    """The paged plane is universal: SSM and hybrid stacks serve on
     pooled point-state pages (conv + recurrent state captured at page
-    boundaries) — no ring fallback exists any more — and a prefix hit
-    resumes from a sealed page's state bit-identically (fp32) to the
-    ring path and a cold start, with zero seed-copy bytes."""
+    boundaries), and a prefix hit resumes from a sealed page's state
+    bit-identically (fp32) to a cold start."""
     full = get_config(arch)
     cfg = reduced(full, dtype="float32", param_dtype="float32")
     params = init_params(cfg, jax.random.key(0))
@@ -377,22 +357,20 @@ def test_paged_point_stack_hit_bit_equal(arch):
     base = rng.integers(2, 400, 40)
     prompts = [base, np.concatenate([base[:32], rng.integers(2, 400, 9)])]
 
-    ring = _mk_engine(full, cfg, params, paged_kernel=False)
-    _run(ring, prompts)
-    paged = _mk_engine(full, cfg, params, paged_kernel=True)
-    assert paged.paged is True and paged.backend.paged is True
+    paged = _mk_engine(full, cfg, params)
     rep = _run(paged, prompts)
-    cold = _mk_engine(full, cfg, params, paged_kernel=True,
-                      prefix_caching=False)
+    cold = _mk_engine(full, cfg, params, prefix_caching=False)
     _run(cold, prompts)
 
-    assert _outputs(ring) == _outputs(paged) == _outputs(cold)
+    assert _outputs(paged) == _outputs(cold)
     assert rep["prefix"]["compute_hits"] >= 1
-    assert rep["seed_copy_bytes"] == 0.0
-    assert rep["snapshot_bytes"] == 0.0
     # recurrent-state pages ride the same accounting: page reads carry
-    # the per-page state snapshot bytes
+    # the per-page state snapshot bytes, and the KV tier read exactly
+    # what the step programs gathered (point stacks copy no tails)
     assert paged.kv.state_bytes_page > 0
+    assert rep["kernel_read_bytes"] > 0 and paged.kv.tail_copy_bytes == 0
+    assert paged.mem.devices["mrm"].stats.read_bytes == pytest.approx(
+        rep["kernel_read_bytes"])
     assert paged.prefill_tokens_computed < cold.prefill_tokens_computed
 
 
@@ -407,30 +385,25 @@ def test_paged_state_page_migration(arch):
     params = init_params(cfg, jax.random.key(0))
     rng = np.random.default_rng(17)
     p = rng.integers(2, 400, 32)
-    donor = _mk_engine(full, cfg, params, paged_kernel=True)
+    donor = _mk_engine(full, cfg, params)
     _run(donor, [p], max_new=4)
 
     key = donor.radix_key_for(list(p))
     exp = donor.export_prefix(key)
     assert exp is not None and exp.get("page_data") is not None
-    assert exp["snapshot_bytes"] == 0.0
 
-    recv = _mk_engine(full, cfg, params, paged_kernel=True)
-    imp = recv.import_prefix(exp["tokens"], caches=exp["caches"],
-                             hot=exp["hot"], hits=exp["hits"],
-                             snap_kind=exp["snap_kind"],
-                             snap_tokens=exp["snap_tokens"],
-                             page_data=exp["page_data"],
+    recv = _mk_engine(full, cfg, params)
+    imp = recv.import_prefix(exp["tokens"], hot=exp["hot"],
+                             hits=exp["hits"], page_data=exp["page_data"],
                              page_tokens=exp["page_tokens"])
-    assert imp["total_tokens"] > 0 and imp["snapshot_bytes"] == 0.0
+    assert imp["total_tokens"] > 0
     rep = _run(recv, [p], max_new=4)
     assert list(donor.outputs[0]) == list(recv.outputs[0])
     assert rep["prefix"]["compute_hits"] == 1
-    assert rep["seed_copy_bytes"] == 0.0
 
     # page-size mismatch: state captured at foreign page boundaries is
     # meaningless here
-    recv2 = _mk_engine(full, cfg, params, paged_kernel=True)
+    recv2 = _mk_engine(full, cfg, params)
     bad = recv2.import_prefix(exp["tokens"], page_data=exp["page_data"],
                               page_tokens=exp["page_tokens"] * 2)
     assert bad["total_tokens"] == 0
@@ -453,7 +426,7 @@ def test_paged_pool_growth_and_row_copy():
     params = init_params(cfg, jax.random.key(0))
     ecfg = EngineConfig(max_slots=2, max_cache_len=96, page_tokens=16,
                         weight_tier="hbm", kv_tier="mrm")
-    b = ComputeBackend(cfg, params, ecfg, paged=True)
+    b = ComputeBackend(cfg, params, ecfg)
     pool0 = jax.tree.leaves(b.paged_caches)[0].shape[1]
     ids = [b.alloc_page() for _ in range(pool0 + 3)]   # forces a doubling
     assert len(set(ids)) == len(ids) and 0 not in ids

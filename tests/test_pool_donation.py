@@ -36,8 +36,8 @@ def _undonated(cfg, kind, page_tokens, attn_kernel=False):
 def _backend(cfg, params, donate=True):
     """A paged backend whose sampled logits are kept, in order."""
     ecfg = EngineConfig(max_slots=2, max_cache_len=64, page_tokens=PT,
-                        weight_tier="hbm", kv_tier="mrm", paged_kernel=True)
-    be = ComputeBackend(cfg, params, ecfg, paged=True)
+                        weight_tier="hbm", kv_tier="mrm")
+    be = ComputeBackend(cfg, params, ecfg)
     if not donate:
         be.paged_program = _undonated
     logits, sample = [], be.sample

@@ -2,17 +2,16 @@
 
 The radix tree shares KV *pages* identically for every architecture; these
 tests pin down the harder guarantee — that a prefix hit skips prefill
-*compute* — for each snapshot family:
+*compute* — for each resumption kind:
 
-- attention (deepseek-7b) and MLA (deepseek-v2-lite-16b): *positional*
-  snapshots — ring caches masked by stored positions, one donor snapshot
-  serves any shorter page-aligned boundary;
-- SSM (mamba2-2.7b) and hybrid (hymba-1.5b): *point* snapshots — the
-  recurrent state integrates the whole prefix, so a snapshot is valid only
-  at the exact page boundary it was captured at, and the first borrower at
-  a new boundary recomputes once while capturing for the next.
+- attention (deepseek-7b) and MLA (deepseek-v2-lite-16b): *positional* —
+  the shared KV/latent pages are position-addressed, so a borrower resumes
+  at any boundary of them;
+- SSM (mamba2-2.7b) and hybrid (hymba-1.5b): *point* — the recurrent
+  state integrates the whole prefix, so a borrower resumes only at a page
+  boundary, from the state page holding the state there.
 
-Everything runs fp32: the extend/seeded paths are mathematically identical
+Everything runs fp32: the extend/resumed paths are mathematically identical
 to the cold prefill, and point stacks chunk on the position-space page
 grid so the recurrent state's accumulation order is identical too — greedy
 decode must match bit-for-bit.
@@ -136,11 +135,9 @@ def test_chunked_engine_bit_equal_to_whole_prompt_engine(arch_setup):
 def test_prefix_hit_decodes_identically_to_cold_start(arch_setup):
     """Shared-prefix traffic served with the radix tree on decodes exactly
     what a prefix_caching=False engine decodes, and compute was actually
-    skipped. Point stacks (SSM/hybrid) skip from the *second* borrower —
-    the first recomputes the shared run once while capturing the state at
-    the observed boundary (DESIGN.md §8)."""
+    skipped. Point stacks (SSM/hybrid) resume on the state page at the
+    last page boundary the match covers (DESIGN.md §8)."""
     arch, full, cfg, params = arch_setup
-    kind = EXPECTED_KIND[arch]
     rng = np.random.default_rng(21)
     shared = list(rng.integers(2, 400, 48))
     prompts = [shared + list(rng.integers(2, 400, 8)) for _ in range(4)]
@@ -152,17 +149,6 @@ def test_prefix_hit_decodes_identically_to_cold_start(arch_setup):
     assert warm.kv.prefix_hits >= 2          # pages shared either way
     assert warm.prefill_tokens_skipped > 0   # compute shortened overall
     assert warm.prefix_compute_hits >= 1
-    if kind == "point":
-        # a point capture exists at a page-aligned boundary the borrowers
-        # share (either the donor's own last page boundary, or the
-        # observed-share capture the first borrower left behind)
-        from repro.serving import SnapshotHandle
-        plen = warm.backend.prefix_len()
-        point_bounds = {n.payload.tokens for n in warm.kv.radix.nodes()
-                        if isinstance(n.payload, SnapshotHandle)
-                        and n.payload.live and n.payload.kind == "point"}
-        match_b = ((plen + len(shared)) // 16) * 16
-        assert match_b in point_bounds, (match_b, point_bounds)
 
     cold = _mk_engine(full, cfg, params, prefix_caching=False)
     for p in prompts:
@@ -208,7 +194,7 @@ def test_tail_hit_bit_equal_to_cold_start(arch_setup):
         assert warm.prefill_tokens_computed \
             < page_aligned.prefill_tokens_computed
     else:
-        # point stacks: the flag is on but no mid-page snapshot exists,
+        # point stacks: the flag is on but no mid-page state exists,
         # so no copy may happen (a copy without compute reuse would
         # waste bus bytes and double-account the boundary)
         assert warm.kv.tail_hits == 0
@@ -220,8 +206,8 @@ def test_decode_audit_all_families_interleaved(arch_setup):
     """Regression guard for the PR 4 clobbering class: with the padded
     whole-prompt path deleted, chunked prefill interleaves with decode on
     every path — the engine's decode-masking audit verifies per step that
-    no cache family (ring KV, MLA latents, conv/SSD state) of an inactive
-    slot is written. The audit raising would fail this test."""
+    no compute page (KV, MLA latents, conv/SSD state) of a session outside
+    the round is written. The audit raising would fail this test."""
     arch, full, cfg, params = arch_setup
     eng = _mk_engine(full, cfg, params, max_prefills_per_step=1,
                      audit_decode_masking=True)
@@ -255,8 +241,8 @@ def test_migrated_hit_decodes_identically_non_attention(non_attn_setup):
     arch, full, cfg, params = non_attn_setup
     rng = np.random.default_rng(17)
     shared = list(rng.integers(2, 400, 48))
-    # the seed prompt IS the shared head: its end-boundary snapshot then
-    # sits exactly where the fan-out matches — required for point stacks
+    # the seed prompt IS the shared head: its last page boundary then
+    # sits exactly where the fan-out matches
     prompts = [list(shared)] + \
         [shared + list(rng.integers(2, 400, 8)) for _ in range(3)]
 

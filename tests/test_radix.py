@@ -212,7 +212,9 @@ def test_manager_never_evicts_pinned_prefix():
     s1 = kv.sessions[1]
     assert all(p.refcount >= 1 for p in s1.pages)
     assert all(p.region_id is not None for p in s1.pages)
-    assert kv.read_all(1) == s1.tokens * kv.kv_bytes_token
+    kvb = kv.kv_bytes_token
+    assert kv.read_pages(1, [p.n_tokens * kvb for p in s1.pages]) \
+        == s1.tokens * kvb
     kv.close_session(1)
     kv.evict_prefixes()
     assert kv.live_pages() == 0 and kv.radix.n_nodes() == 0

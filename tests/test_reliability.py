@@ -418,8 +418,7 @@ def test_engine_reports_reliability_and_injects():
         cfg, params, mem,
         EngineConfig(max_slots=1, max_cache_len=64, weight_tier="hbm",
                      kv_tier="mrm", eos_token=-1, page_tokens=16,
-                     chunk_tokens=16, paged_kernel=True,
-                     inject_rber=1e-3, inject_seed=0),
+                     chunk_tokens=16, inject_rber=1e-3, inject_seed=0),
         account_cfg=full)
     rng = np.random.default_rng(0)
     eng.submit(list(rng.integers(2, cfg.vocab_size, 24)), max_new_tokens=4)

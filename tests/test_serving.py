@@ -44,12 +44,21 @@ def test_paged_kv_token_accounting(appends):
 
 
 def test_paged_kv_read_all_bytes():
+    """``read_pages`` meters exactly the bytes it is given, page by page,
+    against each page's own region: reading every page's tokens reads
+    the session's whole KV, and a page given 0 bytes is not read."""
     cfg = get_config("qwen3-8b")
-    kv = PagedKVManager(cfg, _mem(), "mrm", page_tokens=64)
+    mem = _mem()
+    kv = PagedKVManager(cfg, mem, "mrm", page_tokens=64)
     kv.open_session(1)
-    kv.append_tokens(1, 100)
-    got = kv.read_all(1)
-    assert got == 100 * cfg.kv_bytes_per_token()
+    kv.append_tokens(1, 100)              # one sealed page + 36 open
+    kvb = cfg.kv_bytes_per_token()
+    pages = kv.sessions[1].pages
+    got = kv.read_pages(1, [p.n_tokens * kvb for p in pages])
+    assert got == 100 * kvb
+    assert mem.devices["mrm"].stats.read_bytes == got
+    assert kv.read_pages(1, [0.0, 7.0 * kvb]) == 7.0 * kvb
+    assert mem.devices["mrm"].stats.read_bytes == 107 * kvb
 
 
 # ---------------------------------------------------------------------------
@@ -177,7 +186,11 @@ def test_prefix_caching_shares_pages():
     assert s2.tokens == 128 and s2.shared_prefix_pages == 2
     # shared pages survive the first session's close, die with eviction
     kv.close_session(0)
-    assert kv.read_all(1) == 200 * cfg.kv_bytes_per_token()
+    s1 = kv.sessions[1]
+    assert s1.tokens == 200 and sum(p.n_tokens for p in s1.pages) == 200
+    kvb = cfg.kv_bytes_per_token()
+    assert kv.read_pages(1, [p.n_tokens * kvb for p in s1.pages]) \
+        == 200 * kvb
     kv.close_session(1)
     kv.close_session(2)
     assert kv.evict_prefixes() > 0        # leaf-LRU-evict the whole tree
@@ -340,8 +353,8 @@ def test_chunked_prefill_interleaves_decode(small_engine_setup):
 
 
 def test_prefix_hit_decodes_identically_to_cold_start(f32_engine_setup):
-    """Acceptance: a radix prefix hit (slot caches seeded from the donor
-    snapshot, prefill extended from the match boundary) must decode the
+    """Acceptance: a radix prefix hit (the shared pages spliced into the
+    page table, prefill extended from the match boundary) must decode the
     exact tokens a cold start decodes — on the same engine (hit vs its own
     cold donor) and vs a fresh engine that never saw the prefix."""
     full, cfg, params = f32_engine_setup
@@ -372,14 +385,13 @@ def test_prefix_hit_decodes_identically_to_cold_start(f32_engine_setup):
 
 
 def test_wrapped_donor_never_donates_compute(f32_engine_setup):
-    """A donor prompt that overflowed the smallest ring wrapped it — its
-    snapshot lost the early positions a shorter borrower needs, so it must
-    publish pages only (memory reuse), never a compute snapshot. The
-    borrower prefills in full and decodes exactly like a cold start."""
+    """A donor prompt longer than ``max_cache_len`` donates like any
+    other: the paged plane keeps every page of it, so the borrower skips
+    the shared head's prefill and decodes exactly like a cold start."""
     full, cfg, params = f32_engine_setup
     rng = np.random.default_rng(23)
     head = list(rng.integers(2, 400, 32))
-    long_donor = head + list(rng.integers(2, 400, 108))  # 140 > ring (96)
+    long_donor = head + list(rng.integers(2, 400, 108))  # 140 > 96
     borrower = head + list(rng.integers(2, 400, 16))     # 48, shares 32
 
     eng, _ = _run_engine(full, cfg, params, 16, [], max_new=6, page_tokens=16)
@@ -388,14 +400,25 @@ def test_wrapped_donor_never_donates_compute(f32_engine_setup):
     assert eng.kv.radix.n_nodes() > 0       # pages published...
     eng.submit(list(borrower), 6)
     eng.run_until_idle()
-    assert eng.kv.prefix_hits >= 1          # ...and memory reuse happened
-    assert eng.prefill_tokens_skipped == 0  # ...but no compute donation
+    assert eng.kv.prefix_hits >= 1          # ...memory reuse happened...
+    assert eng.prefill_tokens_skipped > 0   # ...and compute was donated
 
     cold, _ = _run_engine(full, cfg, params, 16,
                           [long_donor, borrower], max_new=6,
                           page_tokens=16, prefix_caching=False)
     assert {k: list(v) for k, v in eng.outputs.items()} == \
            {k: list(v) for k, v in cold.outputs.items()}
+
+
+def test_engine_refuses_the_ring_compute_path(small_engine_setup):
+    """The paged plane is the only compute path: an engine asked for
+    ``paged_kernel=False`` refuses to start instead of serving on it."""
+    full, cfg, params = small_engine_setup
+    assert EngineConfig().paged_kernel is True
+    with pytest.raises(ValueError, match="only compute path"):
+        ServeEngine(cfg, params, _mem(),
+                    EngineConfig(max_slots=2, paged_kernel=False),
+                    account_cfg=full)
 
 
 def test_radix_reuse_cuts_prefill_and_kv_writes(f32_engine_setup):
@@ -807,7 +830,8 @@ def test_sampler_never_emits_vocab_padding():
     from repro.serving.engine import ComputeBackend
     cfg = reduced(get_config("qwen3-8b"), vocab_size=500, vocab_pad_to=64)
     assert cfg.padded_vocab == 512
-    backend = ComputeBackend(cfg, None, EngineConfig(max_slots=2))
+    backend = ComputeBackend(cfg, init_params(cfg, jax.random.key(0)),
+                             EngineConfig(max_slots=2))
     logits = np.zeros((2, cfg.padded_vocab), np.float32)
     logits[:, 3] = 1.0
     logits[:, 510] = 9.0                        # a padding column wins
@@ -838,7 +862,7 @@ def test_replicas_sharing_a_device_share_one_weight_copy(small_engine_setup):
         dev = jax.devices()[i % len(jax.devices())]
         assert {d for x in jax.tree.leaves(p) for d in x.devices()} == {dev}
     eng = ServeEngine(cfg, placed[0], _mem(),
-                      EngineConfig(max_slots=2, paged_kernel=True),
+                      EngineConfig(max_slots=2),
                       account_cfg=full)
     assert {d for x in jax.tree.leaves(eng.backend.paged_caches)
             for d in x.devices()} == {jax.devices()[0]}

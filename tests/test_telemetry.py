@@ -126,7 +126,7 @@ def setup():
 def _engine(setup, **kw):
     full, cfg, params = setup
     mem = MemorySystem({"mrm": (MRM_RRAM, 8 << 30), "hbm": (HBM3E, 8 << 30)})
-    ecfg = dict(max_slots=4, paged_kernel=True, page_tokens=8,
+    ecfg = dict(max_slots=4, page_tokens=8,
                 max_cache_len=64, chunk_tokens=16, eos_token=-1)
     ecfg.update(kw)
     return ServeEngine(cfg, params, mem, EngineConfig(**ecfg),
